@@ -41,31 +41,32 @@ import os as _os
 def _setup_compilation_cache() -> None:
     """Enable JAX's persistent compilation cache for every consumer.
 
-    The EC kernels are large HLO graphs; without a disk cache every node
-    start, test run, bench, and dryrun re-pays XLA compilation. Configured
-    here (package import) so all entry points share one cache. Override the
-    location with FBTPU_JAX_CACHE_DIR; disable with FBTPU_JAX_CACHE_DIR=off.
+    The EC kernels are large programs; without a disk cache every daemon
+    start, test run and bench re-pays their compilation. Configured here
+    (package import) so all entry points share one cache. Its place is
+    decided OUTSIDE the code: where JAX_COMPILATION_CACHE_DIR is set, JAX
+    reads it itself and no directory is set here; otherwise the cache
+    lives at the fixed path <checkout>/.jax_cache (the path is part of the
+    cache key — a directory that moves never hits).
     """
-    d = _os.environ.get("FBTPU_JAX_CACHE_DIR")
-    if d == "off":
-        return
-    try:
-        import jax
+    import jax
 
-        if d is None:
-            d = _os.path.join(
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            _os.path.join(
                 _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
-                ".jax_cache",
-            )
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        try:
-            jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-        except Exception:
-            pass  # option renamed/absent in other jax versions
-    except Exception:
-        pass  # cache is an optimization; never block import on it
+                ".jax_cache"))
+    # A Pallas kernel is serialized into its program WITH its MLIR
+    # locations, and by default those carry the Python call stack: the
+    # cache key of every program holding a kernel then depends on who
+    # called it, and what one entry point compiled (a warm-up, the smoke's
+    # kernel stage) never hits for another (a daemon). One frame per
+    # location keeps the key a function of the program alone.
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
 
 
 _setup_compilation_cache()

@@ -323,17 +323,27 @@ class NodeDaemon:
         signal.signal(signal.SIGTERM, self._on_terminate)
         signal.signal(signal.SIGINT, self._on_terminate)
         signal.signal(signal.SIGHUP, self._on_hup)
+        from ..crypto.suite import DeviceUnavailable
         try:
             self.start()
-        except DaemonError as exc:
+        except (DaemonError, DeviceUnavailable) as exc:
             LOG.error(badge("DAEMON", "boot-refused", error=str(exc)))
             return 3
         except Exception:
             LOG.exception(badge("DAEMON", "boot-failed"))
             return 1
+        rc = 0
         try:
             while not self._stop.wait(timeout=1.0):
-                pass
+                fault = self.node.health.snapshot()["faults"].get(
+                    "crypto.device")
+                if fault is not None:
+                    # a kernel that fails to compile or run never heals:
+                    # stop serving and say so in the exit code
+                    LOG.critical(badge("DAEMON", "crypto-device-failed",
+                                       reason=fault["reason"][:300]))
+                    rc = 4
+                    break
         finally:
             self.shutdown()
-        return 0
+        return rc

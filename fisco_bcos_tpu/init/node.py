@@ -255,6 +255,11 @@ class Node:
         self.health = Health(registry=self.metrics_view,
                              label=cfg.group_id)
         self.health.on_change.append(self._on_health_change)
+        # a device-path kernel that fails to compile or run is fatal for
+        # this node wherever the call came from (ingest lane, crypto lane,
+        # scheduler roots): the lanes survive by rejecting the batch, the
+        # health plane says why the chain stopped
+        self.suite.on_device_error.append(self._on_device_error)
         if cfg.failpoints:
             from ..utils import failpoints as _fp
             _fp.arm_spec(cfg.failpoints)
@@ -484,6 +489,9 @@ class Node:
         if new == "ok":
             self.sealer.wakeup()
 
+    def _on_device_error(self, reason: str) -> None:
+        self.health.failed("crypto.device", reason)
+
     def accepting_remote_txs(self) -> bool:
         """Gossip import gate (net/txsync.py): False while this node is
         busy (overload brownout) or degraded — a saturated follower must
@@ -563,6 +571,7 @@ class Node:
             "snapshot": self.snapshot.status(),
             "consensus": self.consensus.status()
             if self.consensus is not None else None,
+            "crypto": self.suite.status(),
             "cryptoLane": lane.stats() if lane is not None else None,
             "zk": self.zk.stats(),
             "groups": reg.groups() if reg is not None else [cfg.group_id],
@@ -595,6 +604,12 @@ class Node:
     def start(self) -> None:
         if self._started:
             return
+        # device path: resolve the platform and compile the shapes this
+        # node will use BEFORE it seals or opens RPC (a first 1,000-tx
+        # batch compiling under a 3 s view timeout is a view change); a
+        # `device` node without a TPU refuses to start here
+        self.suite.prepare(max(self.config.ingest_max_batch,
+                               self.config.tx_count_limit))
         if self.ledger.current_number() < 0:
             self.build_genesis()
         self._started = True
@@ -716,6 +731,8 @@ class Node:
         if self.exec_pool is not None:
             self.exec_pool.stop()
         self.health.stop()
+        if self._on_device_error in self.suite.on_device_error:
+            self.suite.on_device_error.remove(self._on_device_error)
         self._started = False
 
     # -- solo-consensus proposal path --------------------------------------

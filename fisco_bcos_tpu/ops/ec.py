@@ -323,7 +323,7 @@ def shamir_mult(cv: Curve, k1, k2, qx_r, qy_r):
         gts = jnp.asarray(cv.g_table)[None]
         d1 = fp.window_digits(k1, WINDOW)[..., ::-1, :]
         d2 = fp.window_digits(k2, WINDOW)[..., ::-1, :]
-        digs_all = jnp.stack([d1, d2])
+        digs_all = jnp.stack([d1, d2], axis=1)  # [steps, 2, B]
         negs = jnp.zeros((2, k1.shape[-1]), jnp.uint32)
         q_planes = jnp.stack([qx_r, qy_r])[None]
         return pallas_ec.ladder(cv.fp, cv.a_is_zero, cv.a_is_minus3,
@@ -419,7 +419,8 @@ def glv_shamir_mult(cv: Curve, k1, k2, qx_r, qy_r):
         qlx = f.mul(qx_r, beta)
         gts = jnp.stack([jnp.asarray(cv.g_table),
                          jnp.asarray(cv.g_table_endo)])
-        digs_all = jnp.stack([digs(a1), digs(b1), digs(a2), digs(b2)])
+        digs_all = jnp.stack([digs(a1), digs(b1), digs(a2), digs(b2)],
+                             axis=1)  # [steps, 4, B]
         negs = jnp.stack([s1, t1, s2, t2]).astype(jnp.uint32)
         q_planes = jnp.stack([jnp.stack([qx_r, qy_r]),
                               jnp.stack([qlx, qy_r])])

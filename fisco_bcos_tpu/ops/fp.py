@@ -43,32 +43,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import platform
+
 NLIMBS = 16
 LIMB_BITS = 16
 LIMB_RADIX = 1 << LIMB_BITS
 MASK = np.uint32(LIMB_RADIX - 1)
 BITS = NLIMBS * LIMB_BITS  # 256
 
-_PALLAS_CACHE: list = []
-
 
 def _use_pallas() -> bool:
-    """Pallas-fused multiplies: on for TPU backends, off on CPU (the
-    interpreter there is slower than plain XLA), overridable with
-    FBTPU_PALLAS=0/1. Resolved once at first use (backend init is when
-    the platform is known and stable)."""
-    if not _PALLAS_CACHE:
-        import os
+    """Pallas-fused kernels: compiled by Mosaic on TPU, off everywhere else
+    (the interpreter is slower than plain XLA on CPU). The platform
+    decides — asked at trace time, so it is frozen into each jit."""
+    return platform.on_tpu()
 
-        flag = os.environ.get("FBTPU_PALLAS", "")
-        if flag in ("0", "1"):
-            _PALLAS_CACHE.append(flag == "1")
-        else:
-            try:
-                _PALLAS_CACHE.append(jax.devices()[0].platform == "tpu")
-            except Exception:
-                _PALLAS_CACHE.append(False)
-    return _PALLAS_CACHE[0]
 
 __all__ = ["NLIMBS", "LIMB_BITS", "BITS", "SolinasField", "MontField",
            "to_limbs", "from_limbs_np", "window_digits", "is_zero", "eq",

@@ -206,7 +206,8 @@ def _keccak256_varlen_impl(blocks_u8, nvalid, nblocks):
 def keccak256_varlen(blocks_u8: jax.Array, nvalid: jax.Array) -> jax.Array:
     """Variable-length batch: [B, maxblocks, RATE_BYTES] pre-padded blocks,
     nvalid[B] = per-message block count. Messages shorter than maxblocks
-    mask out the trailing permutations. Returns [B, 32] digests."""
+    mask out the trailing permutations. Returns [B, 32] digests. Both
+    implementations are one jit each: one compile per (B, maxblocks)."""
     from . import fp as _fp
     if _fp._use_pallas() and blocks_u8.ndim == 3 and blocks_u8.shape[0]:
         from . import pallas_hash
@@ -216,14 +217,33 @@ def keccak256_varlen(blocks_u8: jax.Array, nvalid: jax.Array) -> jax.Array:
     return _keccak256_varlen_impl(blocks_u8, nvalid, blocks_u8.shape[-2])
 
 
-def keccak256_batch_np(msgs: list[bytes]) -> np.ndarray:
-    """Convenience host API: pad on host (bucketed to max block count),
-    hash on device, return [B, 32] uint8."""
-    padded = [pad_message_np(m) for m in msgs]
-    maxb = max(p.shape[0] for p in padded)
-    blocks = np.zeros((len(msgs), maxb, RATE_BYTES), dtype=np.uint8)
-    nvalid = np.zeros((len(msgs),), dtype=np.int32)
-    for i, p in enumerate(padded):
+def pack_batch_np(msgs, pad_fn, block_bytes: int, batch: int, nblocks: int):
+    """Host-side packing shared by the Keccak and SM3 batch APIs: pad each
+    message (`pad_fn`), lay the batch out as [batch, nblocks, block_bytes]
+    uint8 + per-message block counts. `batch`/`nblocks` are the CALLER's
+    buckets (>= len(msgs) / the longest message): every distinct pair is
+    one compiled program, so the caller keeps the set small."""
+    blocks = np.zeros((batch, nblocks, block_bytes), dtype=np.uint8)
+    nvalid = np.zeros((batch,), dtype=np.int32)
+    for i, m in enumerate(msgs):
+        p = pad_fn(m)
         blocks[i, : p.shape[0]] = p
         nvalid[i] = p.shape[0]
-    return np.asarray(keccak256_varlen(jnp.asarray(blocks), jnp.asarray(nvalid)))
+    return blocks, nvalid
+
+
+def nblocks_of(n: int) -> int:
+    """Rate blocks a message of n bytes pads to."""
+    return n // RATE_BYTES + 1
+
+
+def keccak256_batch_np(msgs: list[bytes], batch: int | None = None,
+                       nblocks: int | None = None) -> np.ndarray:
+    """Host API: pad on host into the (batch, nblocks) bucket (default: the
+    exact batch size and longest message), hash on device, return
+    [len(msgs), 32] uint8."""
+    batch = batch or len(msgs)
+    nblocks = nblocks or max(nblocks_of(len(m)) for m in msgs)
+    blocks, nvalid = pack_batch_np(msgs, pad_message_np, RATE_BYTES,
+                                   batch, nblocks)
+    return np.asarray(keccak256_varlen(blocks, nvalid))[: len(msgs)]

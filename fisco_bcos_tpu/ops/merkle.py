@@ -24,6 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import keccak as _keccak
 from . import sm3 as _sm3
@@ -87,24 +88,34 @@ def _merkle_root_bucketed(leaves: jax.Array, n: jax.Array, alg: str) -> jax.Arra
     return root
 
 
-def merkle_root(leaves, alg: str = "keccak256") -> jax.Array:
-    """Merkle root of [n, 32] uint8 leaf digests (numpy or jax)."""
-    leaves = jnp.asarray(leaves, dtype=jnp.uint8)
+# The whole-tree Pallas kernel (ops.pallas_merkle) is NOT on this dispatch:
+# Mosaic (jax 0.9.0 / libtpu 0.0.34, TPU v5e) refuses it at lowering — its
+# in-kernel byte packing (stride-4 lane slices of uint8 rows) becomes a
+# gather the TPU lowering rejects ("Shape mismatch in input, indices and
+# output"), and behind that wait uint8 [n,32] -> [m,512] reshapes, [k,17]
+# transposes, scatter `.at[].set` and an un-gridded whole-array VMEM
+# operand. That is a rewrite to word planes, not a layout fix (ROADMAP
+# queue 1 item 5). The XLA level loop below compiles and matches the host
+# oracle on the chip (PERF.md "Chip bring-up").
+
+
+def merkle_root(leaves, alg: str = "keccak256",
+                nbucket: int | None = None) -> jax.Array:
+    """Merkle root of [n, 32] uint8 leaf digests.
+
+    The leaves are zero-padded ON THE HOST to `nbucket` (default: the next
+    power of two, >= WIDTH) so the device sees one shape per bucket — the
+    root for logical n is bit-identical regardless of bucket size."""
+    leaves = np.asarray(leaves, dtype=np.uint8)
     n = leaves.shape[0]
     if n == 0:
         return jnp.zeros((DIGEST,), jnp.uint8)
-    nbucket = max(WIDTH, 1 << (n - 1).bit_length())
+    nbucket = nbucket or max(WIDTH, 1 << (n - 1).bit_length())
+    assert nbucket >= n, (nbucket, n)
     if nbucket > n:
-        leaves = jnp.concatenate(
-            [leaves, jnp.zeros((nbucket - n, DIGEST), jnp.uint8)], axis=0
-        )
-    from . import fp
-    if fp._use_pallas() and nbucket <= 65536:  # leaves stay VMEM-resident
-        # whole tree in one fused kernel: the XLA level loop pays the
-        # backend's per-op latency thousands of times per root
-        from . import pallas_merkle
-        return pallas_merkle.merkle_root_fused(leaves, jnp.int32(n), alg)
-    return _merkle_root_bucketed(leaves, jnp.int32(n), alg)
+        leaves = np.concatenate(
+            [leaves, np.zeros((nbucket - n, DIGEST), np.uint8)], axis=0)
+    return _merkle_root_bucketed(leaves, np.int32(n), alg)
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import fp
+from . import fp, platform
 from .fp import LIMB_BITS, MASK, NLIMBS
 
 # lanes per kernel instance: multiple of 128 (TPU lane width); 512 keeps the
@@ -79,6 +79,14 @@ def field_consts(field: "fp._FieldBase") -> np.ndarray:
     return c
 
 
+# The two mul bodies are inner jits: a kernel calls them hundreds of times
+# (an EC ladder step is ~130 multiplies of ~400 primitive ops each), and as
+# plain functions every call site re-traced the whole body — two minutes of
+# Python per (op, bucket) before Mosaic saw anything. As jits each body is
+# traced once per operand shape and every call site is one equation; the
+# Mosaic lowering inlines them.
+
+@functools.partial(jax.jit, static_argnums=0)
 def solinas_mul_body(field: "fp.SolinasField", a, b, limbs_col):
     """a*b mod p for p = 2^256 - c, on jnp values (pallas-inlinable).
 
@@ -106,6 +114,7 @@ def solinas_mul_body(field: "fp.SolinasField", a, b, limbs_col):
     return fp.select(brw == 0, d, r2_limbs)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def mont_mul_body(field: "fp.MontField", a, b, limbs_col, nprime_col):
     """REDC(a*b) on jnp values (pallas-inlinable); mirrors MontField.mul.
     `limbs_col`/`nprime_col`: broadcastable [NLIMBS, 1] constant inputs."""
@@ -179,14 +188,9 @@ def pallas_ok(shape) -> bool:
 
 
 def _auto_interpret(interpret: bool) -> bool:
-    """Mosaic lowering needs a real TPU; anywhere else (CPU tests with
-    FBTPU_PALLAS=1) fall back to the pallas interpreter."""
-    if interpret:
-        return True
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
+    """Mosaic compiles the kernel on TPU or the call raises; anywhere else
+    (CPU tests calling a kernel directly) the pallas interpreter runs it."""
+    return interpret or not platform.on_tpu()
 
 
 def mul(field: "fp._FieldBase", a, b, interpret: bool = False):
@@ -281,23 +285,26 @@ def mul_stacked(field: "fp._FieldBase", a, b, interpret: bool = False):
 def pow_digits_values(mul, one, a, digs_ref, nd: int, W: int = 4):
     """Windowed a^e on VALUES, exponent as `nd` MSB-first W-bit digits in
     an SMEM ref (callable from any kernel): window table built with
-    2^W - 2 multiplies, then fori over the digits."""
+    2^W - 2 multiplies, then fori over the digits. The table entry for a
+    digit is picked by a select chain on the scalar — Mosaic has no
+    dynamic_slice on values."""
     entries = [one, a]
     for _ in range((1 << W) - 2):
         entries.append(mul(entries[-1], a))
-    table = jnp.stack(entries, axis=0)
+
+    def pick(d):
+        out = entries[0]
+        for k in range(1, len(entries)):
+            out = jnp.where(d == k, entries[k], out)
+        return out
 
     def body(i, acc):
         for _ in range(W):
             acc = mul(acc, acc)
-        d = digs_ref[i]
-        factor = jax.lax.dynamic_index_in_dim(table, d, axis=0,
-                                              keepdims=False)
-        return mul(acc, factor)
+        return mul(acc, pick(digs_ref[i]))
 
-    init = jax.lax.dynamic_index_in_dim(table, digs_ref[0], axis=0,
-                                        keepdims=False)
-    return jax.lax.fori_loop(1, nd, body, init)
+    return jax.lax.fori_loop(1, nd, body, pick(digs_ref[0]))
+
 
 @functools.lru_cache(maxsize=None)
 def _pow_call(field: "fp._FieldBase", nd: int, B: int, blk: int,

@@ -133,8 +133,16 @@ def _glv_split_values(fn: _MontCtx, c_ref, k):
 
 
 
+def _dig_at(digs_all):
+    """ladder_values' digit accessor over an in-kernel [rows, steps, B]
+    VALUE (these kernels compute the digits in-kernel, so there is no ref
+    to index)."""
+    return lambda row, r: jax.lax.dynamic_index_in_dim(
+        digs_all[row], r, axis=0, keepdims=False)
+
+
 def _glv_ladder(f: FieldCtx, fn: "_MontCtx", c_ref, gts_ref, nsteps,
-                u1, u2, qx, qy):
+                u1, u2, qx, qy, tbl_ref, opnd_ref):
     """Shared scalars-to-ladder plumbing for verify and recover: GLV-split
     both scalars, build the interleaved digit/negs planes, and run
     ladder_values. qx/qy are canonical field-rep affine Q coordinates."""
@@ -153,13 +161,13 @@ def _glv_ladder(f: FieldCtx, fn: "_MontCtx", c_ref, gts_ref, nsteps,
     q_planes = jnp.stack([jnp.stack([qx, qy]),
                           jnp.stack([qlx, qy])], axis=0)
     return pallas_ec.ladder_values(f, (True, False), nsteps, 2,
-                                   gts_ref[:, :, :], digs_all, negs,
-                                   q_planes)
+                                   gts_ref[:, :, :], _dig_at(digs_all),
+                                   negs, q_planes, tbl_ref, opnd_ref)
 
 
 def _verify_kernel_body(field_p, field_n, nsteps,
                         invdigs_ref, c_ref, gts_ref, e_ref, r_ref, s_ref,
-                        qx_ref, qy_ref, ok_ref):
+                        qx_ref, qy_ref, ok_ref, tbl_ref, opnd_ref):
     f = FieldCtx(field_p, c_ref[:, _C_P:_C_P + 1])
     fn = _MontCtx(field_n, c_ref[:, _C_N:_C_N + 1],
                   c_ref[:, _C_NPRIME:_C_NPRIME + 1],
@@ -191,7 +199,8 @@ def _verify_kernel_body(field_p, field_n, nsteps,
     u1 = fn.from_rep(fn.mul(fn.to_rep(e), w))
     u2 = fn.from_rep(fn.mul(fn.to_rep(r), w))
 
-    acc = _glv_ladder(f, fn, c_ref, gts_ref, nsteps, u1, u2, qxr, qyr)
+    acc = _glv_ladder(f, fn, c_ref, gts_ref, nsteps, u1, u2, qxr, qyr,
+                      tbl_ref, opnd_ref)
     X, _, Z = acc[0], acc[1], acc[2]
     ok &= ~fp.is_zero(Z)
 
@@ -214,10 +223,10 @@ def _verify_call(field_p, field_n, nsteps: int, nd_inv: int, B: int,
     from jax.experimental.pallas import tpu as pltpu
 
     def kernel(invdigs_ref, c_ref, gts_ref, e_ref, r_ref, s_ref,
-               qx_ref, qy_ref, ok_ref):
+               qx_ref, qy_ref, ok_ref, tbl_ref, opnd_ref):
         _verify_kernel_body(field_p, field_n, nsteps, invdigs_ref,
                             c_ref[:, :], gts_ref[:, :, :], e_ref, r_ref,
-                            s_ref, qx_ref, qy_ref, ok_ref)
+                            s_ref, qx_ref, qy_ref, ok_ref, tbl_ref, opnd_ref)
 
     spec = pl.BlockSpec((NLIMBS, blk), lambda i: (0, i))
     return pl.pallas_call(
@@ -231,6 +240,7 @@ def _verify_call(field_p, field_n, nsteps: int, nd_inv: int, B: int,
             spec, spec, spec, spec, spec,
         ],
         out_specs=pl.BlockSpec((1, blk), lambda i: (0, i)),
+        scratch_shapes=pallas_ec.ladder_scratch(2, blk),
         interpret=interpret,
     )
 
@@ -282,7 +292,7 @@ def ecdsa_verify_fused(cv, e, r, s, qx, qy, interpret: bool = False):
 
 def _recover_kernel_body(field_p, field_n, nsteps, sqrt_ref, invn_ref,
                          invp_ref, c_ref, gts_ref, e_ref, r_ref, s_ref,
-                         v_ref, qx_ref, qy_ref, ok_ref):
+                         v_ref, qx_ref, qy_ref, ok_ref, tbl_ref, opnd_ref):
     f = FieldCtx(field_p, c_ref[:, _C_P:_C_P + 1])
     fn = _MontCtx(field_n, c_ref[:, _C_N:_C_N + 1],
                   c_ref[:, _C_NPRIME:_C_NPRIME + 1],
@@ -324,7 +334,8 @@ def _recover_kernel_body(field_p, field_n, nsteps, sqrt_ref, invn_ref,
     u1 = fn.from_rep(fn.mul(fn.neg(fn.to_rep(e)), rinv))  # -e/r mod n
     u2 = fn.from_rep(fn.mul(fn.to_rep(s), rinv))  # s/r mod n
 
-    acc = _glv_ladder(f, fn, c_ref, gts_ref, nsteps, u1, u2, xm, ym)
+    acc = _glv_ladder(f, fn, c_ref, gts_ref, nsteps, u1, u2, xm, ym,
+                      tbl_ref, opnd_ref)
     X, Y, Z = acc[0], acc[1], acc[2]
     ok &= ~fp.is_zero(Z)
 
@@ -344,11 +355,11 @@ def _recover_call(field_p, field_n, nsteps: int, B: int, blk: int,
     from jax.experimental.pallas import tpu as pltpu
 
     def kernel(sqrt_ref, invn_ref, invp_ref, c_ref, gts_ref, e_ref,
-               r_ref, s_ref, v_ref, qx_ref, qy_ref, ok_ref):
+               r_ref, s_ref, v_ref, qx_ref, qy_ref, ok_ref, tbl_ref, opnd_ref):
         _recover_kernel_body(field_p, field_n, nsteps, sqrt_ref, invn_ref,
                              invp_ref, c_ref[:, :], gts_ref[:, :, :],
                              e_ref, r_ref, s_ref, v_ref, qx_ref, qy_ref,
-                             ok_ref)
+                             ok_ref, tbl_ref, opnd_ref)
 
     spec = pl.BlockSpec((NLIMBS, blk), lambda i: (0, i))
     lane = pl.BlockSpec((1, blk), lambda i: (0, i))
@@ -368,6 +379,7 @@ def _recover_call(field_p, field_n, nsteps: int, B: int, blk: int,
             spec, spec, spec, lane,
         ],
         out_specs=(spec, spec, lane),
+        scratch_shapes=pallas_ec.ladder_scratch(2, blk),
         interpret=interpret,
     )
 
@@ -402,7 +414,8 @@ _S_P, _S_PNP, _S_PONE, _S_PR2, _S_A, _S_B, _S_N, _S_NNP, _S_NR2, \
 
 
 def _sm2_verify_kernel_body(field_p, field_n, nsteps, c_ref, gts_ref,
-                            e_ref, r_ref, s_ref, qx_ref, qy_ref, ok_ref):
+                            e_ref, r_ref, s_ref, qx_ref, qy_ref, ok_ref,
+                            tbl_ref, opnd_ref):
     f = _MontCtx(field_p, c_ref[:, _S_P:_S_P + 1],
                  c_ref[:, _S_PNP:_S_PNP + 1],
                  c_ref[:, _S_PONE:_S_PONE + 1],
@@ -440,9 +453,11 @@ def _sm2_verify_kernel_body(field_p, field_n, nsteps, c_ref, gts_ref,
     digs_all = jnp.stack([digs(sc), digs(t)], axis=0)
     negs = jnp.zeros((2,) + sc.shape[-1:], U32)
     q_planes = jnp.stack([jnp.stack([qxr, qyr])], axis=0)
-    acc = pallas_ec.ladder_values(f, (False, True), nsteps, 1,
-                                  gts_ref[:, :, :], digs_all, negs,
-                                  q_planes)
+    # a plain FieldCtx: the point ops are jits and take the ctx as a pytree
+    acc = pallas_ec.ladder_values(
+        FieldCtx(field_p, f.limbs_col, f.nprime_col, f.one_col),
+        (False, True), nsteps, 1, gts_ref[:, :, :], _dig_at(digs_all),
+        negs, q_planes, tbl_ref, opnd_ref)
     X, _, Z = acc[0], acc[1], acc[2]
     ok &= ~fp.is_zero(Z)
 
@@ -465,10 +480,10 @@ def _sm2_verify_call(field_p, field_n, nsteps: int, B: int, blk: int,
     from jax.experimental import pallas as pl
 
     def kernel(c_ref, gts_ref, e_ref, r_ref, s_ref, qx_ref, qy_ref,
-               ok_ref):
+               ok_ref, tbl_ref, opnd_ref):
         _sm2_verify_kernel_body(field_p, field_n, nsteps, c_ref[:, :],
                                 gts_ref[:, :, :], e_ref, r_ref, s_ref,
-                                qx_ref, qy_ref, ok_ref)
+                                qx_ref, qy_ref, ok_ref, tbl_ref, opnd_ref)
 
     spec = pl.BlockSpec((NLIMBS, blk), lambda i: (0, i))
     return pl.pallas_call(
@@ -481,6 +496,7 @@ def _sm2_verify_call(field_p, field_n, nsteps: int, B: int, blk: int,
             spec, spec, spec, spec, spec,
         ],
         out_specs=pl.BlockSpec((1, blk), lambda i: (0, i)),
+        scratch_shapes=pallas_ec.ladder_scratch(1, blk),
         interpret=interpret,
     )
 

@@ -125,6 +125,8 @@ def _sm3_varlen_impl(blocks_u8, nvalid, nblocks):
 
 
 def sm3_varlen(blocks_u8: jax.Array, nvalid: jax.Array) -> jax.Array:
+    """[B, maxblocks, 64] pre-padded blocks + per-message block counts ->
+    [B, 32] digests; each implementation is one jit."""
     from . import fp as _fp
     if _fp._use_pallas() and blocks_u8.ndim == 3 and blocks_u8.shape[0]:
         from . import pallas_hash
@@ -147,12 +149,18 @@ def pad_message_np(msg: bytes) -> np.ndarray:
     return buf.reshape(-1, BLOCK_BYTES)
 
 
-def sm3_batch_np(msgs: list[bytes]) -> np.ndarray:
-    padded = [pad_message_np(m) for m in msgs]
-    maxb = max(p.shape[0] for p in padded)
-    blocks = np.zeros((len(msgs), maxb, BLOCK_BYTES), dtype=np.uint8)
-    nvalid = np.zeros((len(msgs),), dtype=np.int32)
-    for i, p in enumerate(padded):
-        blocks[i, : p.shape[0]] = p
-        nvalid[i] = p.shape[0]
-    return np.asarray(sm3_varlen(jnp.asarray(blocks), jnp.asarray(nvalid)))
+def nblocks_of(n: int) -> int:
+    """Compression blocks a message of n bytes pads to."""
+    return (n + 8) // BLOCK_BYTES + 1
+
+
+def sm3_batch_np(msgs: list[bytes], batch: int | None = None,
+                 nblocks: int | None = None) -> np.ndarray:
+    """Host API, bucketed like keccak.keccak256_batch_np."""
+    from .keccak import pack_batch_np
+
+    batch = batch or len(msgs)
+    nblocks = nblocks or max(nblocks_of(len(m)) for m in msgs)
+    blocks, nvalid = pack_batch_np(msgs, pad_message_np, BLOCK_BYTES,
+                                   batch, nblocks)
+    return np.asarray(sm3_varlen(blocks, nvalid))[: len(msgs)]

@@ -17,9 +17,9 @@ Merkle tree's upper levels cross shards the same way (the
 sequence-parallel analogue). Per-message hashing stays single-device.
 
 `CryptoSuite(mesh_devices=N)` routes its device path through `MeshKernels`;
-the driver's `__graft_entry__.dryrun_multichip` exercises the same sharding
-on the virtual CPU mesh, which is also how the tests run
-(tests/conftest.py forces 8 host devices).
+`__graft_entry__.dryrun_multichip` exercises the same sharding on whatever
+devices JAX has; the tests run it on an 8-device host-platform mesh
+(tests/conftest.py).
 """
 
 from __future__ import annotations
@@ -30,17 +30,23 @@ from typing import Optional
 import numpy as np
 
 
-def local_mesh(max_devices: Optional[int] = None):
-    """-> Mesh over the largest power-of-two prefix of local devices on a
-    1-D "dp" axis, or None when fewer than two devices exist (single-chip
-    and host-only deployments: the unsharded path is already optimal)."""
+def local_mesh(n_devices: Optional[int] = None):
+    """-> Mesh over the largest power-of-two prefix of `n_devices` local
+    devices (default: all of them) on a 1-D "dp" axis; None when fewer
+    than two were asked for (the unsharded path). Asked for more devices
+    than JAX has, it raises — a node configured for 4 chips that finds 1
+    must not quietly run unsharded."""
     import jax
     from jax.sharding import Mesh
 
     devs = jax.devices()
-    n = len(devs) if max_devices is None else min(max_devices, len(devs))
+    n = len(devs) if n_devices is None else n_devices
     if n < 2:
         return None
+    if n > len(devs):
+        raise RuntimeError(
+            f"mesh over {n} devices asked for, JAX has {len(devs)} "
+            f"({devs[0].platform})")
     n = 1 << (n.bit_length() - 1)
     return Mesh(np.array(devs[:n]), ("dp",))
 

@@ -27,7 +27,9 @@ reuse it); `JsonRpcServer` binds it to HTTP.
 
 from __future__ import annotations
 
+import contextlib
 import json
+import threading
 import time
 from typing import Optional
 
@@ -177,27 +179,33 @@ def handle_payload_with(impl, payload, max_batch: int = 256):
                     "error": {"code": JSONRPC_INVALID_REQUEST,
                               "message": f"batch too large (> {max_batch} "
                                          "entries)"}}
-        out = []
-        deadline = time.monotonic() + BATCH_BUDGET_SECONDS
-        for entry in payload:
-            if time.monotonic() > deadline:
-                # budget exhausted: answer the remaining entries instead
-                # of executing them — this worker must come back to the
-                # pool (order + per-id shape preserved; notifications
-                # stay silent per spec)
-                if isinstance(entry, dict) and "id" not in entry:
-                    continue
-                out.append({"jsonrpc": "2.0",
-                            "id": entry.get("id")
-                            if isinstance(entry, dict) else None,
-                            "error": {"code": -32000,
-                                      "message": "batch budget exhausted"}})
-                continue
-            resp = _handle_entry(impl, entry)
-            if resp is not None:
-                out.append(resp)
-        return out or None
+        cohort = getattr(impl, "cohort", None)
+        with cohort(payload) if cohort else contextlib.nullcontext():
+            return _handle_batch(impl, payload) or None
     return _handle_entry(impl, payload)
+
+
+def _handle_batch(impl, payload: list) -> list:
+    out = []
+    deadline = time.monotonic() + BATCH_BUDGET_SECONDS
+    for entry in payload:
+        if time.monotonic() > deadline:
+            # budget exhausted: answer the remaining entries instead of
+            # executing them — this worker must come back to the pool
+            # (order + per-id shape preserved; notifications stay silent
+            # per spec)
+            if isinstance(entry, dict) and "id" not in entry:
+                continue
+            out.append({"jsonrpc": "2.0",
+                        "id": entry.get("id")
+                        if isinstance(entry, dict) else None,
+                        "error": {"code": -32000,
+                                  "message": "batch budget exhausted"}})
+            continue
+        resp = _handle_entry(impl, entry)
+        if resp is not None:
+            out.append(resp)
+    return out
 
 
 def _handle_entry(impl, entry):
@@ -220,6 +228,7 @@ class JsonRpcImpl:
         self.cache = getattr(node, "query_cache", None)
         self.max_batch = getattr(getattr(node, "config", None),
                                  "rpc_max_batch", 256)
+        self._tl = threading.local()  # .cohort: a batch's admitted txs
         self.methods = {
             "call": self.call,
             "sendTransaction": self.send_transaction,
@@ -262,6 +271,49 @@ class JsonRpcImpl:
         """Single request dict OR JSON-RPC 2.0 batch list -> response
         dict / list / None (see handle_payload_with)."""
         return handle_payload_with(self, payload, self.max_batch)
+
+    @contextlib.contextmanager
+    def cohort(self, payload: list):
+        """Scope of one JSON-RPC batch: its `sendTransaction` entries enter
+        the ingest lane TOGETHER, before the entries are answered one by
+        one. A batch runs sequentially in one worker, and each entry used
+        to block on its own admission — the lane saw one tx per batch at a
+        time, at most `rpc_workers` in flight, and no RPC client could
+        ever form a batch the device path takes (device_min_batch = 512).
+        Entries carrying their own `traceparent`, malformed ones and other
+        groups' stay on the one-by-one path, which also reports whatever
+        is wrong with them."""
+        lane = getattr(self.node, "ingest", None)
+        frames: dict[str, bytes] = {}
+        if lane is not None:
+            for e in payload:
+                if not (isinstance(e, dict)
+                        and e.get("method") == "sendTransaction"
+                        and "traceparent" not in e
+                        and isinstance(e.get("params"), list)
+                        and len(e["params"]) > 2
+                        and e["params"][0] == self.node.config.group_id
+                        and isinstance(e["params"][2], str)):
+                    continue
+                try:
+                    frames[e["params"][2]] = _unhex(e["params"][2])
+                except ValueError:
+                    continue
+        tasks: dict = {}
+        health = getattr(self.node, "health", None)
+        if len(frames) > 1 and not (health is not None
+                                    and health.writes_shed()):
+            from ..txpool.ingest import LaneStopped, TxPoolIsFull
+            try:
+                tasks = dict(zip(frames, lane.submit_wire_cohort(
+                    list(frames.values()))))
+            except (TxPoolIsFull, LaneStopped):
+                pass  # each entry meets the same condition on its own
+        self._tl.cohort = tasks
+        try:
+            yield
+        finally:
+            self._tl.cohort = None
 
     def handle(self, request: dict) -> dict:
         rid = request.get("id")
@@ -339,7 +391,10 @@ class JsonRpcImpl:
                                "node degraded: writes shed "
                                f"({health.state()})")
         raw = _unhex(tx_hex)
-        ctx = otrace.current()
+        # already in the lane with its batch's cohort? then only wait
+        admitted = (getattr(self._tl, "cohort", None) or {}).pop(tx_hex,
+                                                                 None)
+        ctx = otrace.current() if admitted is None else None
         tx = None
         if ctx is not None:
             # traced request: decode eagerly — the span context follows
@@ -366,7 +421,9 @@ class JsonRpcImpl:
             from ..txpool.ingest import TxPoolIsFull
             from ..utils.task import TaskTimeout
             try:
-                if tx is None:
+                if admitted is not None:
+                    res = admitted.result(timeout)
+                elif tx is None:
                     res = lane.submit_wire(raw, timeout=timeout)
                 else:
                     res = lane.submit(tx, timeout=timeout)

@@ -116,10 +116,15 @@ class _PipeBackend:
 def _exec_worker_main(conn, sm_crypto: bool) -> None:
     """Worker process entry (spawn target). One loop: EXEC in, DONE out,
     serving nothing else — crashes surface to the parent as a dead pipe."""
-    # the worker executes Python opcode work; device backends belong to
-    # the parent's crypto lane, and a spawned child must not try to grab
-    # an accelerator of its own
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # the worker executes Python opcode work; the chip belongs to the
+    # parent's crypto lane (one process at a time), so the child is pinned
+    # to CPU whatever the parent was started with. The spawn has already
+    # imported jax with the parent's environment: pin the config, and the
+    # env for anything this child starts in turn.
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from ..crypto.suite import make_suite
     from ..executor.executor import TransactionExecutor
     from ..protocol.columnar import decode_columns

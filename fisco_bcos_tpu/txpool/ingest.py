@@ -193,20 +193,32 @@ class IngestLane:
         wire entries into one `protocol.columnar.decode_columns` +
         `TxPool.submit_columns` call, so per-tx Python marshalling
         disappears from the hot path. Raises TxPoolIsFull at capacity."""
-        entry = _Entry(None, Task(), ctx=otrace.current(), wire=raw)
+        return self.submit_wire_cohort([raw])[0]
+
+    def submit_wire_cohort(self, raws: Sequence[bytes]) -> list[Task]:
+        """Enqueue a cohort of raw wire frames UNDER ONE LOCK HOLD; ->
+        [Task[TxSubmitResult]] in order. The dispatcher can only ever see
+        all of them or none, so a client's JSON-RPC batch reaches the
+        batch recover as one piece — enqueued one by one, the dispatcher
+        wakes on the first frame and drains whatever trickled in, which
+        is how a 1,000-tx batch became batches of a few dozen, none of
+        them device-sized. All-or-nothing: raises TxPoolIsFull when the
+        cohort does not fit."""
+        ctx = otrace.current()
+        entries = [_Entry(None, Task(), ctx=ctx, wire=raw) for raw in raws]
         with self._cv:
             if self._stop:
                 raise LaneStopped("ingest lane stopped")
-            if len(self._q) >= self.queue_cap:
-                self._rejected_total += 1
-                self._reg.inc("bcos_ingest_rejected_total")
+            if len(self._q) + len(entries) > self.queue_cap:
+                self._rejected_total += len(entries)
+                self._reg.inc("bcos_ingest_rejected_total", len(entries))
                 raise TxPoolIsFull(
                     f"ingest queue at capacity ({self.queue_cap})")
-            self._q.append(entry)
+            self._q.extend(entries)
             depth = len(self._q)
             self._cv.notify_all()
         self._reg.set_gauge("bcos_ingest_queue_depth", depth)
-        return entry.task
+        return [e.task for e in entries]
 
     def submit_wire(self, raw: bytes, timeout: float = 30.0
                     ) -> TxSubmitResult:

@@ -90,7 +90,7 @@ def test_suite_chunked_device_batches(monkeypatch):
     from fisco_bcos_tpu.crypto.suite import make_suite
 
     monkeypatch.setattr(suite_mod, "CHUNK", 8)
-    s = make_suite(backend="device", device_min_batch=1)
+    s = make_suite(backend="device", device_min_batch=1, allow_cpu=True)
     host = make_suite(backend="host")
     kps = [host.generate_keypair(bytes([i + 1]) * 8) for i in range(4)]
     digests, sigs, pubs = [], [], []

@@ -2,9 +2,9 @@
 
 The value-level Jacobian ops used inside the fused ladder kernel must
 match ops.ec's complete-by-selection ops exactly — same field, same
-selection semantics. Full-ladder parity is covered by a slower
-offline harness (interpret mode) and by the device sweep's verify
-assertions on real TPU; here CI pins the per-op contracts cheaply.
+selection semantics. Full-ladder parity against the native host path is
+asserted on the chip by chip_smoke.py's kernel stage (every bucket, with
+tampered rows); here CI pins the per-op contracts cheaply.
 """
 
 import jax

@@ -1,8 +1,8 @@
 """Fused varlen batch hashing: parity with the host oracle.
 
 CI keeps interpret-mode work tiny (single-block Keccak batch); multi-block
-masking and SM3 are covered by the offline harness and by the device
-sweep / suite assertions on real TPU.
+masking and SM3 are asserted against the native host path on the chip by
+chip_smoke.py's kernel stage.
 """
 
 import numpy as np
@@ -36,7 +36,7 @@ def test_keccak_varlen_fused_single_block():
 
 @pytest.mark.skipif("FBTPU_SLOW_TESTS" not in __import__("os").environ,
                     reason="multi-block + SM3 interpret runs are covered "
-                           "by the offline harness / device sweep")
+                           "by chip_smoke.py on the chip")
 def test_sm3_varlen_fused():
     rng = np.random.default_rng(33)
     msgs = [rng.bytes(int(n)) for n in rng.integers(0, 80, 16)]

@@ -1,9 +1,9 @@
 """Fused whole-tree Merkle kernel: parity with the host oracle.
 
 Interpreter-mode execution of the pallas kernel is slow, so CI keeps the
-buckets small (single level + the n<=1 edge); the 2-level case and the
-device-path dispatch are covered by the device sweep on real TPU
-(benchmark/device_sweep.py asserts device == host root every run).
+buckets small (single level + the n<=1 edge). The kernel is NOT on the TPU
+dispatch: Mosaic refuses it (see ops/merkle.py); these tests keep its
+interpret-mode semantics pinned for the rewrite (ROADMAP queue 1 item 5).
 """
 
 import numpy as np
@@ -28,8 +28,7 @@ def test_keccak_single_level(n):
 
 
 @pytest.mark.skipif("FBTPU_SLOW_TESTS" not in __import__("os").environ,
-                    reason="SM3 interpret-mode eval takes ~1h on one core; "
-                           "device sweep asserts SM3 tree parity on TPU")
+                    reason="SM3 interpret-mode eval takes ~1h on one core")
 def test_sm3_single_level():
     rng = np.random.default_rng(7)
     leaves = np.zeros((16, 32), np.uint8)

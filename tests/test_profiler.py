@@ -340,28 +340,28 @@ def test_perf_gate_passes_identical_rerun_and_catches_2x():
     # derived band must absorb a dip INSIDE that recorded spread
     history = [_jitter(_BASE, f) for f in (0.76, 1.0, 1.1)]
     # identical rerun: candidate == a recorded run -> PASS
-    rep = pg.gate([dict(_BASE)], history, {}, min_runs=3)
+    rep = pg.gate([dict(_BASE)], history, min_runs=3)
     assert rep["ok"], rep
     # a dip within the recorded noise: still PASS (bands from spread)
-    rep = pg.gate([_jitter(_BASE, 0.80)], history, {}, min_runs=3)
+    rep = pg.gate([_jitter(_BASE, 0.80)], history, min_runs=3)
     assert rep["ok"], rep
     # injected 2x regression on a chain row: FAIL, named
-    rep = pg.gate([_jitter(_BASE, 0.5)], history, {}, min_runs=3)
+    rep = pg.gate([_jitter(_BASE, 0.5)], history, min_runs=3)
     assert not rep["ok"]
     assert "chain_tps_4node_host" in rep["failed"]
     # lower-better direction: a 2x slowdown in latency also FAILs
     bad = dict(_BASE)
     bad["trace_e2e_p50_ms"] = _BASE["trace_e2e_p50_ms"] * 2.1
-    rep = pg.gate([bad], history, {}, min_runs=3)
+    rep = pg.gate([bad], history, min_runs=3)
     assert "trace_e2e_p50_ms" in rep["failed"]
 
 
 def test_perf_gate_catastrophic_trips_thin_history():
     pg = _gate()
     history = [dict(_BASE)]  # ONE recorded run: everything is advisory...
-    rep = pg.gate([_jitter(_BASE, 0.85)], history, {}, min_runs=3)
+    rep = pg.gate([_jitter(_BASE, 0.85)], history, min_runs=3)
     assert rep["ok"], rep  # ...so a marginal dip stays advisory
-    rep = pg.gate([_jitter(_BASE, 0.5)], history, {}, min_runs=3)
+    rep = pg.gate([_jitter(_BASE, 0.5)], history, min_runs=3)
     assert not rep["ok"]  # ...but a halved metric is fatal regardless
 
 
@@ -369,11 +369,11 @@ def test_perf_gate_noise_widens_bands():
     pg = _gate()
     history = [_jitter(_BASE, f) for f in (0.98, 1.0, 1.02)]
     cand = _jitter(_BASE, 0.84)  # just under the quiet-host band (12%)
-    quiet = pg.gate([cand], history, {}, min_runs=3, weather_now=None)
+    quiet = pg.gate([cand], history, min_runs=3, weather_now=None)
     assert not quiet["ok"]
     noisy_weather = {"psi_cpu": {"avg10": 30.0, "avg60": 10.0},
                      "steal_pct": 5.0, "spin_score": 1}
-    loud = pg.gate([cand], history, {}, min_runs=3,
+    loud = pg.gate([cand], history, min_runs=3,
                    weather_now=noisy_weather)
     assert loud["ok"], loud  # the widened band absorbs the dip
     assert loud["noisy"]
@@ -386,6 +386,6 @@ def test_perf_gate_interleaved_medians():
     # gate when the median is healthy
     cands = [_jitter(_BASE, 0.55), _jitter(_BASE, 1.0),
              _jitter(_BASE, 1.02)]
-    rep = pg.gate(cands, history, {}, min_runs=3)
+    rep = pg.gate(cands, history, min_runs=3)
     assert rep["ok"], rep
     assert rep["candidate_runs"] == 3

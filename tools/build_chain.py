@@ -12,7 +12,8 @@ BcosBuilder Pro/Max deployers. Output layout:
 
 Usage:
     python tools/build_chain.py -n 4 -o /tmp/mychain [--sm] \
-        [--consensus pbft] [--rpc-base-port 20200] [--encrypt-key PASS]
+        [--consensus pbft] [--rpc-base-port 20200] [--encrypt-key PASS] \
+        [--crypto-backend auto,host,host,host]
 
 Boot a generated node in-process:
     from fisco_bcos_tpu.tool import load_node
@@ -60,6 +61,17 @@ def build_chain(out_dir: str, n_nodes: int, sm_crypto: bool = False,
                 p2p_base_port: int | None = None,
                 p2p_ports: list[int] | None = None,
                 host: str = "127.0.0.1") -> dict:
+    # one value for every node, or one per node ("auto,host,host,host"): a
+    # chip belongs to one process at a time, so on a one-chip host exactly
+    # one daemon may be anything but `host`
+    backends = crypto_backend.split(",")
+    if len(backends) == 1:
+        backends *= n_nodes
+    if len(backends) != n_nodes or \
+            any(b not in ("auto", "host", "device") for b in backends):
+        raise ValueError(
+            f"crypto backend {crypto_backend!r}: want auto|host|device, "
+            f"once or once per node ({n_nodes})")
     suite = make_suite(sm_crypto, backend="host")
     keypairs = [suite.generate_keypair() for _ in range(n_nodes)]
     chain = ChainConfig(chain_id=chain_id, group_id=group_id,
@@ -85,7 +97,7 @@ def build_chain(out_dir: str, n_nodes: int, sm_crypto: bool = False,
             chain_id=chain_id, group_id=group_id, sm_crypto=sm_crypto,
             storage_path="data", consensus=consensus,
             storage_backend=storage_backend,
-            crypto_backend=crypto_backend,
+            crypto_backend=backends[i],
             rpc_port=(rpc_base_port + i) if rpc_base_port is not None else None,
             metrics_port=(metrics_base_port + i)
             if metrics_base_port is not None else None,
@@ -107,6 +119,7 @@ def build_chain(out_dir: str, n_nodes: int, sm_crypto: bool = False,
             "rpc_port": cfg.rpc_port,
             "metrics_port": cfg.metrics_port,
             "p2p_port": cfg.p2p_port,
+            "crypto_backend": cfg.crypto_backend,
         })
     if metric_targets:
         _write_monitor_stack(out_dir, metric_targets)
@@ -163,6 +176,11 @@ def main() -> None:
                     help="[storage] backend: auto = WAL-backed; disk = "
                          "log-structured engine (restart flat in chain "
                          "length, datasets beyond RAM)")
+    ap.add_argument("--crypto-backend", default="auto",
+                    help="[crypto] backend: auto|host|device, one value "
+                         "for all nodes or a comma list, one per node "
+                         "(one chip serves one process: on a one-chip "
+                         "host give node0 the chip and the rest `host`)")
     ap.add_argument("--encrypt-key", default=None,
                     help="passphrase to encrypt node keys at rest")
     ap.add_argument("--mode", default="air", choices=["air", "max"],
@@ -177,7 +195,7 @@ def main() -> None:
         group_id=args.group_id, rpc_base_port=args.rpc_base_port,
         p2p_base_port=args.p2p_base_port,
         metrics_base_port=args.metrics_base_port, sm_tls=args.sm_tls,
-        storage_backend=args.storage,
+        storage_backend=args.storage, crypto_backend=args.crypto_backend,
         encrypt_passphrase=args.encrypt_key.encode() if args.encrypt_key else None)
     if args.mode == "max":
         info["max_cluster"] = build_max_cluster(

@@ -8,8 +8,8 @@ red half the time and trusted never. This gate makes the comparison the
 way the repo's own PERF methodology demands:
 
   * the REFERENCE for each metric is the median of the recorded
-    trajectory (`BENCH_r*.json` `parsed` lines) plus the `chain` section
-    of `BENCH_LAST_GOOD.json` (when present);
+    trajectory (`BENCH_r*.json` `parsed` lines; with none recorded every
+    metric is reported as new);
   * the TOLERANCE BAND per metric is derived from the recorded run
     SPREAD of that very metric across the trajectory — a metric that
     historically swings 1.4x gets a wide band, a stable one gets the
@@ -27,8 +27,6 @@ enforced metric fell below its band. Exit 2 = usage/input error.
 Usage:
   tools/perf_gate.py --candidate BENCH_NEW.json [--candidate ...]
   bench.py ... | tools/perf_gate.py --candidate - --report-only
-  tools/perf_gate.py --candidate X.json --update-last-good   # record the
-      passing candidate's chain metrics into BENCH_LAST_GOOD.json[chain]
 """
 
 from __future__ import annotations
@@ -168,14 +166,6 @@ def load_history(pattern: str) -> tuple[list[dict], list[int]]:
     return lines, spins
 
 
-def load_last_good(path: str) -> dict:
-    try:
-        with open(path) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return {}
-
-
 def load_candidates(paths: list[str]) -> list[dict]:
     cands = []
     for p in paths:
@@ -214,7 +204,7 @@ def load_candidates(paths: list[str]) -> list[dict]:
     return cands
 
 
-def gate(candidates: list[dict], history: list[dict], last_good: dict,
+def gate(candidates: list[dict], history: list[dict],
          min_runs: int = 3, weather_now: dict | None = None,
          best_spin: int | None = None) -> dict:
     """Pure comparison (importable for tests): -> report dict with
@@ -232,11 +222,6 @@ def gate(candidates: list[dict], history: list[dict], last_good: dict,
     for line in history:
         for m, v in flatten(line).items():
             hist_vals.setdefault(m, []).append(v)
-    chain_lg = (last_good.get("chain") or {})
-    for m, rec in chain_lg.items():
-        v = rec.get("value") if isinstance(rec, dict) else rec
-        if isinstance(v, (int, float)) and direction(m) is not None:
-            hist_vals.setdefault(m, []).append(float(v))
 
     # host weather: candidate stamps + the gate's own fresh sample
     noisy_reasons = []
@@ -300,28 +285,6 @@ def gate(candidates: list[dict], history: list[dict], last_good: dict,
     }
 
 
-def update_last_good(path: str, candidates: list[dict]) -> None:
-    """Record the passing candidate's chain-level medians into
-    BENCH_LAST_GOOD.json under `chain` (read-modify-write via bench.py's
-    locked helper when importable, plain rewrite otherwise)."""
-    import time as _time
-    cand_vals: dict[str, list[float]] = {}
-    for line in candidates:
-        for m, v in flatten(line).items():
-            cand_vals.setdefault(m, []).append(v)
-    ts = _time.strftime("%Y-%m-%dT%H:%M:%SZ", _time.gmtime())
-    rec = load_last_good(path)
-    chain = rec.setdefault("chain", {})
-    for m, vs in cand_vals.items():
-        chain[m] = {"value": round(statistics.median(vs), 3),
-                    "runs": len(vs), "measured_at": ts}
-    rec["updated_at"] = ts
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(rec, f, indent=1, sort_keys=True)
-    os.replace(tmp, path)
-
-
 def print_report(rep: dict, out=sys.stdout) -> None:
     w = max([len(r["metric"]) for r in rep["rows"]] + [8])
     print(f"perf_gate: {rep['candidate_runs']} candidate run(s), "
@@ -353,8 +316,6 @@ def main(argv=None) -> int:
     ap.add_argument("--history", default=os.path.join(_REPO,
                                                       "BENCH_r*.json"),
                     help="trajectory glob (default: repo BENCH_r*.json)")
-    ap.add_argument("--last-good",
-                    default=os.path.join(_REPO, "BENCH_LAST_GOOD.json"))
     ap.add_argument("--min-runs", type=int, default=3,
                     help="recorded observations below this make a metric "
                          "advisory (reported, never fatal)")
@@ -362,9 +323,6 @@ def main(argv=None) -> int:
                     help="always exit 0 (the trajectory-watch mode)")
     ap.add_argument("--no-weather", action="store_true",
                     help="skip the gate-time host-weather sample")
-    ap.add_argument("--update-last-good", action="store_true",
-                    help="on PASS, record candidate chain medians into "
-                         "BENCH_LAST_GOOD.json[chain]")
     ap.add_argument("--json", action="store_true",
                     help="emit the report as one JSON document")
     args = ap.parse_args(argv)
@@ -373,17 +331,13 @@ def main(argv=None) -> int:
 
     candidates = load_candidates(args.candidate)
     history, spins = load_history(args.history)
-    last_good = load_last_good(args.last_good)
     weather_now = None if args.no_weather else hostweather.sample()
-    rep = gate(candidates, history, last_good, min_runs=args.min_runs,
+    rep = gate(candidates, history, min_runs=args.min_runs,
                weather_now=weather_now, best_spin=max(spins, default=None))
     if args.json:
         print(json.dumps(rep, indent=1))
     else:
         print_report(rep)
-    if rep["ok"] and args.update_last_good:
-        update_last_good(args.last_good, candidates)
-        print(f"perf_gate: chain medians recorded into {args.last_good}")
     if args.report_only:
         return 0
     return 0 if rep["ok"] else 1
