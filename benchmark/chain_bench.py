@@ -1293,15 +1293,15 @@ def run_trace_profile(sm: bool, backend: str, n_txs: int = 24,
     # the block stages; mixing them would count each stage four times):
     # admission on the INGRESS node, the gossiped copy's re-admission on
     # the block's LEADER (its lane coalesce is real path latency — the
-    # tx cannot seal before it), `seal` on the leader, and the block
-    # stages on the ingress node, whose commit+notify is what resolves
-    # the client's receipt wait.
+    # tx cannot seal before it), `stage.seal_wait` on the leader, and the
+    # block stages on the ingress node, whose commit+notify is what
+    # resolves the client's receipt wait.
     per_stage: dict[str, list[float]] = {}
     stitched_nodes: set = set()
     for root in roots:
         spans = otrace.TRACER.get_trace(root.trace_id.hex())
         leader = next((s["attrs"].get("node") for s in spans
-                       if s["name"] == "seal"), label)
+                       if s["name"] == "stage.seal_wait"), label)
         chosen: dict[str, dict] = {}
         for s in spans:
             node = s["attrs"].get("node")
@@ -1312,7 +1312,7 @@ def run_trace_profile(sm: bool, backend: str, n_txs: int = 24,
                     chosen.setdefault("gossip.admit", s)
                     continue
                 want = label
-            elif name == "seal":
+            elif name == "stage.seal_wait":
                 want = leader
             elif name.startswith("stage."):
                 want = label
@@ -1327,8 +1327,11 @@ def run_trace_profile(sm: bool, backend: str, n_txs: int = 24,
     rows = []
     stage_sum = 0.0
     for name in sorted(per_stage):
-        if name in ("stage.finish", "txpool.admit"):
-            continue  # finish is a zero-width stamp; admit nests in ingest
+        # inside another span of the path, or the same interval seen from
+        # the ingress node (`round_wait` = the leader's admit + seal_wait)
+        if name in ("txpool.admit", "stage.crypto", "stage.gossip",
+                    "stage.round_wait"):
+            continue
         mean = _stats.mean(per_stage[name])
         stage_sum += mean
         rows.append({"metric": "trace_profile", "unit": "ms",

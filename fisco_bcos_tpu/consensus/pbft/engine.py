@@ -34,6 +34,7 @@ fast view-change join (PBFTCacheProcessor's getViewChangeWeight shortcut).
 from __future__ import annotations
 
 import queue
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
@@ -47,7 +48,6 @@ from ...protocol import Block
 from ...utils import otrace
 from ...utils.log import LOG, badge, metric
 from ...utils.metrics import REGISTRY
-from ...utils.trace import block_trace
 from ...utils.worker import Worker
 from .. import qc
 from .messages import (
@@ -129,9 +129,10 @@ class PBFTEngine(Worker):
         # aligned clock source (tool/timesync.py median); raw UTC fallback
         self.clock_ms = clock_ms or (lambda: int(time.time() * 1000))
         self.keypair = keypair
-        # node label for the block-trace registry + span attribution (the
-        # same derivation Node uses, so all of a node's layers agree)
+        # node label of the stage table + span attribution (the same
+        # derivation Node uses, so all of a node's layers agree)
         self.trace_label = keypair.pub_bytes[:4].hex()
+        self.stages = otrace.stages(self.trace_label)
         self.front = front
         self.txpool = txpool
         self.sealer = sealer
@@ -192,6 +193,15 @@ class PBFTEngine(Worker):
         self._seals_verified = 0     # seals judged in those calls
         self._seal_bytes_last = 0    # wire bytes of the last minted carriage
         self._seal_signers_last = 0  # signers in the last minted carriage
+
+        # `round_wait`: open while this node holds unsealed transactions
+        # and no round is open. Work arrives on the admitting thread (the
+        # pool's ready notifier), rounds end on the worker: one lock.
+        self._round_wait: Optional[otrace.Stage] = None
+        self._round_lock = threading.Lock()
+        notifier = getattr(txpool, "register_unseal_notifier", None)
+        if notifier is not None:
+            notifier(self._check_round_wait)
 
         front.register_module(ModuleID.PBFT, self._on_network)
 
@@ -384,6 +394,7 @@ class PBFTEngine(Worker):
                     self.txpool.unseal(cache.proposal.tx_hashes)
             self.sealer.revoke(number)
             self._grant_sealer()
+            self._check_round_wait()
             self._try_advance(self._next_exec())
         local: list[Block] = []
         msgs: list[PBFTMessage] = []
@@ -503,6 +514,31 @@ class PBFTEngine(Worker):
     def _cache(self, number: int) -> _ProposalCache:
         return self._caches.setdefault(number, _ProposalCache())
 
+    # -- stages: the wait for a round, and the round up to execution -------
+    def _check_round_wait(self) -> None:
+        """Open `round_wait` if this node holds unsealed transactions and
+        no round is open. Called where either may have become true: the
+        pool's ready notifier (any thread) and the end of a round."""
+        if self._round_wait is not None:
+            return
+        with self._round_lock:
+            if self._round_wait is None and not any(
+                    c.proposal is not None
+                    for c in list(self._caches.values())) \
+                    and self.txpool.pending_count() > 0:
+                self._round_wait = self.stages.stage("round_wait")
+
+    def _round_opened(self, number: int, cache: _ProposalCache) -> None:
+        """A pre-prepare was sent or accepted: the wait for a round ends
+        and the block's `consensus_pre` starts, at one instant."""
+        with self._round_lock:
+            waited, self._round_wait = self._round_wait, None
+        cache.t_accept = waited.stop() if waited is not None \
+            else time.monotonic()
+        blk = self.stages.block(number)
+        blk.bind(cache.trace_ctx)
+        blk.open("consensus_pre", t0=cache.t_accept)
+
     # -- leader: pre-prepare ----------------------------------------------
     def _broadcast_preprepare(self, block: Block,
                               carried: bool = False) -> None:
@@ -547,10 +583,7 @@ class PBFTEngine(Worker):
         cache.proposal_hash = phash
         cache.trace_ctx = getattr(block, "_otrace", None) or \
             otrace.current()
-        cache.t_accept = time.monotonic()
-        if cache.trace_ctx is not None:
-            block_trace(number, owner=self.trace_label).bind(
-                cache.trace_ctx)
+        self._round_opened(number, cache)
         wire_block = block
         if not self.full_proposals and block.transactions:
             # metadata-only broadcast; the full block stays in our cache
@@ -619,10 +652,7 @@ class PBFTEngine(Worker):
         # pre-prepare's p2p envelope — adopt it for this round so THIS
         # node's consensus/execute/commit spans land in the same trace
         cache.trace_ctx = otrace.current()
-        cache.t_accept = time.monotonic()
-        if cache.trace_ctx is not None:
-            block_trace(msg.number, owner=self.trace_label).bind(
-                cache.trace_ctx)
+        self._round_opened(msg.number, cache)
         # mark the proposal's txs sealed so this node's sealer (if it leads
         # a later in-flight height) never packs them into a second proposal
         # (the reference's asyncMarkTxs on proposal receipt)
@@ -749,8 +779,8 @@ class PBFTEngine(Worker):
         proposal, phash = cache.proposal, cache.proposal_hash
         # latency attribution: time from proposal accept to execution
         # start (pre-prepare/prepare/commit quorum collection + any
-        # execution-lane queueing) — stamps the shared per-block trace
-        block_trace(number, owner=self.trace_label).stage("consensus_pre")
+        # execution-lane queueing)
+        self.stages.block(number).close("consensus_pre")
 
         def run() -> None:
             try:
@@ -982,6 +1012,7 @@ class PBFTEngine(Worker):
         self._reset_timer()
         self.sealer.revoke(number)
         self._grant_sealer()
+        self._check_round_wait()
         metric("pbft.committed", number=number, view=self.view)
         # pipeline cascade: the next height may already hold commit quorum
         # (its consensus ran while this block executed) — act on it now
@@ -1217,6 +1248,7 @@ class PBFTEngine(Worker):
         self._reset_timer()
         # NOTE: no sealer grant here — callers re-propose carried prepared
         # rounds first (safety), then call _grant_sealer themselves
+        self._check_round_wait()
         metric("pbft.newview", view=v)
 
     # -- introspection (getConsensusStatus RPC) ----------------------------

@@ -21,7 +21,10 @@ golden-value requirement).
 
 The seam says where each call ran: `status()` carries the backend as
 configured, the platform JAX resolved, per-op device/host call and item
-counts and the process's compile count — `Node.system_status()["crypto"]`.
+counts, the host's seconds around each device call (packing the arguments,
+the call until every output is numpy, unpacking them into the values
+returned) and the process's compile count —
+`Node.system_status()["crypto"]`.
 
 Signing stays host-side and single-item: a node signs only its own messages
 (one per PBFT phase — PBFTCodec.cpp:47), never in bulk.
@@ -211,7 +214,9 @@ class CryptoSuite:
         # 1,000 invalid signatures and a chain that simply stops
         self.on_device_error: list[Callable[[str], None]] = []
         self._stats_lock = threading.Lock()
-        self._stats = {op: [0, 0, 0, 0] for op in _OPS}  # dev c/i, host c/i
+        # per op: device calls/items, host calls/items, and the device
+        # calls' cumulative pack/call/unpack seconds
+        self._stats = {op: [0, 0, 0, 0, 0.0, 0.0, 0.0] for op in _OPS}
         self._ready: dict | None = None  # set by prepare()
         from . import nativehash
 
@@ -219,8 +224,8 @@ class CryptoSuite:
             self.curve = ec.SECP256K1
             self.params = refimpl.SECP256K1
             self.hash_name = "keccak256"
-            self._dev_hash = (keccak.keccak256_batch_np, keccak.nblocks_of,
-                              keccak.RATE_BYTES)
+            self._dev_hash = (keccak.keccak256_varlen, keccak.pad_message_np,
+                              keccak.nblocks_of, keccak.RATE_BYTES)
             self._host_hash = nativehash.host_hash("keccak256")
             self._host_hash_batch = nativehash.host_hash_batch("keccak256")
             self.signature_size = 65  # r(32) | s(32) | v(1)
@@ -228,8 +233,8 @@ class CryptoSuite:
             self.curve = ec.SM2P256V1
             self.params = refimpl.SM2P256V1
             self.hash_name = "sm3"
-            self._dev_hash = (sm3.sm3_batch_np, sm3.nblocks_of,
-                              sm3.BLOCK_BYTES)
+            self._dev_hash = (sm3.sm3_varlen, sm3.pad_message_np,
+                              sm3.nblocks_of, sm3.BLOCK_BYTES)
             self._host_hash = nativehash.host_hash("sm3")
             self._host_hash_batch = nativehash.host_hash_batch("sm3")
             self.signature_size = 128  # r(32) | s(32) | pub(64), SignatureDataWithPub.h
@@ -267,16 +272,20 @@ class CryptoSuite:
             return False
         return self.backend == "device" or n >= self.device_min_batch
 
-    def _count(self, op: str, device: bool, n: int) -> None:
+    def _count_host(self, op: str, n: int) -> None:
         with self._stats_lock:
             row = self._stats[op]
-            row[0 if device else 2] += 1
-            row[1 if device else 3] += n
+            row[2] += 1
+            row[3] += n
 
-    def _on_device(self, op: str, n: int, call):
-        """Run one device-path call. Inputs were validated and packed on
-        the host, so whatever this raises is a compile, lowering or device
-        failure: wrapped as DeviceError and reported to the observers."""
+    def _on_device(self, op: str, n: int, t_in: float, call, unpack=None):
+        """Run one device-path call, packed since `t_in`: `call()` issues
+        the kernel and returns its outputs as numpy, `unpack(outputs)`
+        makes the values the caller returns. Inputs were validated and
+        packed on the host, so whatever `call` raises is a compile,
+        lowering or device failure: wrapped as DeviceError and reported to
+        the observers. One clock read per boundary, nothing per item."""
+        t_call = time.monotonic()
         try:
             out = call()
         except Exception as exc:
@@ -286,7 +295,17 @@ class CryptoSuite:
             for cb in list(self.on_device_error):
                 cb(str(err))
             raise err from exc
-        self._count(op, True, n)
+        t_out = time.monotonic()
+        if unpack is not None:
+            out = unpack(out)
+        t_done = time.monotonic()
+        with self._stats_lock:
+            row = self._stats[op]
+            row[0] += 1
+            row[1] += n
+            row[4] += t_call - t_in
+            row[5] += t_out - t_call
+            row[6] += t_done - t_out
         return out
 
     def status(self) -> dict:
@@ -296,7 +315,9 @@ class CryptoSuite:
         plat = platform.resolve() if self._device_ok is not None else None
         with self._stats_lock:
             ops_ = {op: {"deviceCalls": r[0], "deviceItems": r[1],
-                         "hostCalls": r[2], "hostItems": r[3]}
+                         "hostCalls": r[2], "hostItems": r[3],
+                         "packSeconds": r[4], "callSeconds": r[5],
+                         "unpackSeconds": r[6]}
                     for op, r in self._stats.items()}
         out = {
             "kind": self.kind,
@@ -332,7 +353,7 @@ class CryptoSuite:
             first = _bucket(1 if self.backend == "device"
                             else self.device_min_batch)
             zsig = bytes(self.signature_size)
-            block = self._dev_hash[2]
+            block = self._dev_hash[3]
             for b in (x for x in BUCKETS
                       if first <= x <= _bucket(min(max_batch, CHUNK))):
                 if self.kind == "ecdsa":
@@ -367,26 +388,31 @@ class CryptoSuite:
             return []
         _lc.note_blocking("suite_batch", "hash_batch")
         if not self._use_device(n):
-            self._count("hash", False, n)
+            self._count_host("hash", n)
             return self._host_hash_batch(msgs)
-        fn, nblocks_of, _block = self._dev_hash
+        t_in = time.monotonic()
+        kernel, pad, nblocks_of, block = self._dev_hash
         nblk = [nblocks_of(len(m)) for m in msgs]
         small = [i for i, k in enumerate(nblk) if k <= HASH_MAX_BLOCKS]
         big = [i for i, k in enumerate(nblk) if k > HASH_MAX_BLOCKS]
         out: list = [None] * n
         if big:
-            self._count("hash", False, len(big))
+            self._count_host("hash", len(big))
             for i, d in zip(big, self._host_hash_batch(
                     [msgs[i] for i in big])):
                 out[i] = d
         for o, ln in _chunks(len(small)):
             idx = small[o:o + ln]
-            part = [msgs[i] for i in idx]
-            nb = _pow2(max(nblk[i] for i in idx))
-            rows = self._on_device(
-                "hash", ln, lambda: fn(part, _bucket(ln), nb))
-            for i, row in zip(idx, rows):
-                out[i] = bytes(row)
+            blocks, nvalid = keccak.pack_batch_np(
+                [msgs[i] for i in idx], pad, block, _bucket(ln),
+                _pow2(max(nblk[i] for i in idx)))
+            digests = self._on_device(
+                "hash", ln, t_in,
+                lambda: np.asarray(kernel(blocks, nvalid)),
+                lambda rows: [bytes(row) for row in rows[:ln]])
+            for i, d in zip(idx, digests):
+                out[i] = d
+            t_in = time.monotonic()
         return out
 
     def poseidon_batch(self, lefts: Sequence[bytes],
@@ -406,12 +432,13 @@ class CryptoSuite:
         if not self._use_device(n):
             from ..zk import poseidon
 
-            self._count("poseidon", False, n)
+            self._count_host("poseidon", n)
             return poseidon.hash2_batch_host(lefts, rights)
         from ..zk import poseidon_jax
 
         return self._on_device(
-            "poseidon", n, lambda: poseidon_jax.hash2_batch(lefts, rights))
+            "poseidon", n, time.monotonic(),
+            lambda: poseidon_jax.hash2_batch(lefts, rights))
 
     def merkle_root(self, leaves: Sequence[bytes]) -> bytes:
         """Deterministic width-16 Merkle root over 32-byte leaf digests
@@ -422,18 +449,22 @@ class CryptoSuite:
         if n == 0:
             return b"\x00" * DIGEST
         if n > BUCKETS[-1] or not self._use_device(n):
-            self._count("merkle", False, n)
+            self._count_host("merkle", n)
             return merkle.merkle_levels_host(list(leaves), self.hash_name)[-1][0]
+        t_in = time.monotonic()
         arr = np.frombuffer(b"".join(leaves), np.uint8).reshape(n, DIGEST)
         mk = self._mesh()
         if mk is not None:
             bucket = max(merkle.WIDTH, mk.n_devices, _pow2(n))
-            return bytes(self._on_device("merkle", n, lambda: np.asarray(
-                mk.merkle_root(_pad_rows(arr, bucket), np.int32(n),
-                               self.hash_name))))
-        bucket = max(merkle.WIDTH, _bucket(n))
-        return bytes(self._on_device("merkle", n, lambda: np.asarray(
-            merkle.merkle_root(arr, self.hash_name, bucket))))
+            root = mk.merkle_root
+        else:
+            bucket = max(merkle.WIDTH, _bucket(n))
+            root = merkle.merkle_root_padded
+        arr = _pad_rows(arr, bucket)
+        return self._on_device(
+            "merkle", n, t_in,
+            lambda: np.asarray(root(arr, np.int32(n), self.hash_name)),
+            bytes)
 
     # -- keys --------------------------------------------------------------
     def generate_keypair(self, seed: bytes | None = None) -> KeyPair:
@@ -499,22 +530,24 @@ class CryptoSuite:
               else 0 for g in sigs]
         return rs, ss
 
-    def _device_chunks(self, fn, n: int, cols: list) -> list:
-        """Run kernel `fn(curve, *cols)` over n rows: one bucket-padded
-        call up to CHUNK, CHUNK-sized calls above it (jax's async dispatch
-        overlaps the next chunk's staging with the current chunk's
-        compute). -> per-output numpy arrays trimmed to n rows."""
+    def _pad_chunks(self, n: int, cols: list) -> list:
+        """The kernel's operands for n rows: one bucket-padded set up to
+        CHUNK, CHUNK-sized sets above it. -> [(rows, padded columns)]."""
         if n <= CHUNK:
             b = self._bucket_for(n)
-            outs = [fn(self.curve, *(_pad_rows(a, b) for a in cols))]
-            spans = [(0, n)]
-        else:
-            spans = _chunks(n)
-            outs = [fn(self.curve, *(_pad_rows(a[o:o + ln], CHUNK)
-                                     for a in cols)) for o, ln in spans]
+            return [(n, [_pad_rows(a, b) for a in cols])]
+        return [(ln, [_pad_rows(a[o:o + ln], CHUNK) for a in cols])
+                for o, ln in _chunks(n)]
+
+    def _run_chunks(self, fn, chunks: list) -> list:
+        """Run kernel `fn(curve, *columns)` over `_pad_chunks`' sets, all
+        issued before the first output is fetched (jax's async dispatch
+        overlaps the next chunk's staging with the current chunk's
+        compute). -> per-output numpy arrays trimmed to the real rows."""
+        outs = [fn(self.curve, *padded) for _ln, padded in chunks]
         outs = [o if isinstance(o, tuple) else (o,) for o in outs]
         return [np.concatenate([np.asarray(o[k])[:ln]
-                                for o, (_o, ln) in zip(outs, spans)])
+                                for o, (ln, _p) in zip(outs, chunks)])
                 for k in range(len(outs[0]))]
 
     def verify_batch(self, digests: Sequence[bytes], sigs: Sequence[bytes],
@@ -527,6 +560,7 @@ class CryptoSuite:
         if n == 0:
             return np.zeros((0,), bool)
         _lc.note_blocking("suite_batch", "verify_batch")
+        t_in = time.monotonic()
         rs, ss = self._split_sigs(sigs)
         qx = [int.from_bytes(p[:32], "big") for p in pubs]
         qy = [int.from_bytes(p[32:64], "big") for p in pubs]
@@ -534,7 +568,7 @@ class CryptoSuite:
         if not self._use_device(n):
             from . import nativeec
 
-            self._count("verify", False, n)
+            self._count_host("verify", n)
             if self.kind == "ecdsa":
                 native = nativeec.ecdsa_verify_batch(es, rs, ss, qx, qy)
                 if native is not None:
@@ -550,15 +584,16 @@ class CryptoSuite:
                 refimpl.sm2_verify((x, y), d, r, s)
                 for x, y, d, r, s in zip(qx, qy, digests, rs, ss)
             ])
-        cols = [bigint.batch_to_limbs(c) for c in (es, rs, ss, qx, qy)]
         mk = self._mesh()
         if mk is not None:
             fn = (mk.verify if self.kind == "ecdsa" else mk.sm2_verify)
         else:
             fn = (ec.ecdsa_verify_batch if self.kind == "ecdsa"
                   else ec.sm2_verify_batch)
+        chunks = self._pad_chunks(n, [bigint.batch_to_limbs(c)
+                                      for c in (es, rs, ss, qx, qy)])
         return self._on_device(
-            "verify", n, lambda: self._device_chunks(fn, n, cols))[0]
+            "verify", n, t_in, lambda: self._run_chunks(fn, chunks))[0]
 
     def recover_batch(self, digests: Sequence[bytes], sigs: Sequence[bytes]
                       ) -> tuple[list[bytes | None], np.ndarray]:
@@ -573,6 +608,7 @@ class CryptoSuite:
         _lc.note_blocking("suite_batch", "recover_batch")
         if n == 0:
             return [], np.zeros((0,), bool)
+        t_in = time.monotonic()
         if self.kind == "sm":
             pubs = [g[64:128] if len(g) >= 128 else b"\x00" * 64 for g in sigs]
             ok = self.verify_batch(digests, sigs, pubs)
@@ -581,7 +617,7 @@ class CryptoSuite:
         if not device:
             from . import nativeec
 
-            self._count("recover", False, n)
+            self._count_host("recover", n)
             if (nativeec.available()
                     and all(len(d) == 32 for d in digests)):
                 # rows fast path: wire signature bytes and 32-byte tx
@@ -620,16 +656,17 @@ class CryptoSuite:
         cols.append(np.array(vs, np.uint32))
         mk = self._mesh()
         rec = mk.recover if mk is not None else ec.ecdsa_recover_batch
-        qx, qy, ok = self._on_device(
-            "recover", n, lambda: self._device_chunks(rec, n, cols))
-        out = []
-        for i in range(n):
-            if ok[i]:
-                out.append(bigint.from_limbs(qx[i]).to_bytes(32, "big")
-                           + bigint.from_limbs(qy[i]).to_bytes(32, "big"))
-            else:
-                out.append(None)
-        return out, ok
+        chunks = self._pad_chunks(n, cols)
+
+        def pub_bytes(outs):
+            qx, qy, ok = outs
+            return [bigint.from_limbs(qx[i]).to_bytes(32, "big")
+                    + bigint.from_limbs(qy[i]).to_bytes(32, "big")
+                    if ok[i] else None for i in range(n)], ok
+
+        return self._on_device(
+            "recover", n, t_in, lambda: self._run_chunks(rec, chunks),
+            pub_bytes)
 
     def recover_addresses(self, digests: Sequence[bytes], sigs: Sequence[bytes]
                           ) -> tuple[list[bytes | None], np.ndarray]:

@@ -272,6 +272,9 @@ class Node:
                          ring_size=cfg.trace_ring_size,
                          slow_ms=cfg.trace_slow_ms)
         self.trace_label = self.keypair.pub_bytes[:4].hex()
+        # the node's stage table: every layer below stamps into it by this
+        # label; a node built again in one process starts from zero
+        otrace.stages(self.trace_label).reset()
         # continuous profiling plane: process-wide like the tracer; armed
         # at a low always-on hz by default, disarmed entirely at hz=0
         from ..analysis import profiler as _profiler
@@ -308,7 +311,8 @@ class Node:
                              registry=self.metrics_view,
                              low_watermark=cfg.txpool_low_watermark,
                              high_watermark=cfg.txpool_high_watermark,
-                             priority_bands=cfg.txpool_priority_bands)
+                             priority_bands=cfg.txpool_priority_bands,
+                             trace_label=self.trace_label)
         self.ingest = IngestLane(
             self.txpool, max_batch=cfg.ingest_max_batch,
             max_wait_ms=cfg.ingest_max_wait_ms,
@@ -399,7 +403,8 @@ class Node:
             self.txsync = TransactionSync(self.front, self.txpool,
                                           self.suite, ingest=self.ingest,
                                           import_gate=self.accepting_remote_txs,
-                                          registry=self.metrics_view)
+                                          registry=self.metrics_view,
+                                          trace_label=self.trace_label)
         # snapshot/checkpoint service: always constructed (RPC status +
         # operator checkpoint() work on any node); its periodic worker only
         # runs when snapshot_interval > 0, and it serves SnapshotSync
@@ -575,7 +580,8 @@ class Node:
             "cryptoLane": lane.stats() if lane is not None else None,
             "zk": self.zk.stats(),
             "groups": reg.groups() if reg is not None else [cfg.group_id],
-            "trace": otrace.TRACER.stats(),
+            "trace": {**otrace.TRACER.stats(),
+                      "stages": otrace.stages(self.trace_label).snapshot()},
             "profile": _prof.PROFILER.stats(),
             "overload": self.overload.stats()
             if self.overload is not None else None,

@@ -64,8 +64,9 @@ class TransactionSync(Worker):
 
     def __init__(self, front: FrontService, txpool, suite,
                  anti_entropy_interval: float = 2.0, ingest=None,
-                 import_gate=None, registry=None):
+                 import_gate=None, registry=None, trace_label: str = ""):
         super().__init__("tx-sync", idle_wait=0.25)
+        self.stages = otrace.stages(trace_label)  # `gossip` below
         self.front = front
         self.txpool = txpool
         self.suite = suite
@@ -117,24 +118,30 @@ class TransactionSync(Worker):
         # adoption (sealer) re-anchors precisely.
         ctx = next((c for c in (getattr(t, "_otrace", None) for t in txs)
                     if c is not None and c.sampled), None)
-        payload_cache: dict[frozenset, bytes] = {}
-        for peer in self.front.peers():
-            with self._lock:
-                known = self._known_by_peer.setdefault(peer, set())
-                fresh = [t for t in txs if t.hash(self.suite) not in known]
-            if not fresh:
-                continue
-            key = frozenset(t.hash(self.suite) for t in fresh)
-            data = payload_cache.get(key)
-            if data is None:
-                data = payload_cache[key] = _pack_txs(fresh, self.suite)
-            with otrace.ctx_scope(ctx):  # envelope carries the trace
-                sent = self.front.send(ModuleID.TxsSync, peer, data)
-            if sent:
-                # mark known only once the frame was actually enqueued on a
-                # live session; the anti-entropy sweep covers drops beyond
+        # pack -> the last peer's frame enqueued; runs on the admitting
+        # thread (the pool's broadcast hook), so it lies inside `admit`
+        with self.stages.stage("gossip"):
+            payload_cache: dict[frozenset, bytes] = {}
+            for peer in self.front.peers():
                 with self._lock:
-                    known.update(t.hash(self.suite) for t in fresh)
+                    known = self._known_by_peer.setdefault(peer, set())
+                    fresh = [t for t in txs
+                             if t.hash(self.suite) not in known]
+                if not fresh:
+                    continue
+                key = frozenset(t.hash(self.suite) for t in fresh)
+                data = payload_cache.get(key)
+                if data is None:
+                    data = payload_cache[key] = _pack_txs(fresh,
+                                                          self.suite)
+                with otrace.ctx_scope(ctx):  # envelope carries the trace
+                    sent = self.front.send(ModuleID.TxsSync, peer, data)
+                if sent:
+                    # mark known only once the frame was actually enqueued
+                    # on a live session; the anti-entropy sweep covers drops
+                    # beyond
+                    with self._lock:
+                        known.update(t.hash(self.suite) for t in fresh)
 
     # -- missing-tx fetch (proposal verification) --------------------------
     def fetch_missing(self, peer: bytes, hashes: Sequence[bytes],

@@ -115,7 +115,13 @@ def merkle_root(leaves, alg: str = "keccak256",
     if nbucket > n:
         leaves = np.concatenate(
             [leaves, np.zeros((nbucket - n, DIGEST), np.uint8)], axis=0)
-    return _merkle_root_bucketed(leaves, np.int32(n), alg)
+    return merkle_root_padded(leaves, np.int32(n), alg)
+
+
+def merkle_root_padded(leaves: np.ndarray, n, alg: str) -> jax.Array:
+    """`merkle_root` for a caller that padded the leaves itself: [bucket,
+    32] uint8 rows of which the first `n` are the tree's."""
+    return _merkle_root_bucketed(leaves, n, alg)
 
 
 # ---------------------------------------------------------------------------

@@ -143,6 +143,61 @@ class JsonRpcError(Exception):
 _SPLICE_HEAD = b'{"jsonrpc": "2.0", "id": '
 
 
+class _EdgeStages:
+    """The stages a `sendTransaction` batch opens at one node's RPC edge
+    (utils/otrace.py). One worker runs a payload from its body to its
+    response, so the open stages ride a thread-local from where each
+    starts to where it ends: `rpc_decode` from the body in hand
+    (http_body_handler) to the frames handed to the lane
+    (JsonRpcImpl.cohort), `rpc_respond` from the cohort's first awaited
+    receipt (send_transaction) to the response bytes. `rpc_no_request` is
+    the one stage no cohort owns: open while no such batch is in flight
+    here — it is the client's turn. Batches only: a stamp per single
+    request would be a stamp per transaction."""
+
+    def __init__(self, stages: otrace.StageTable):
+        self._stages = stages
+        self._tl = threading.local()  # .decode, .respond: this worker's
+        self._lock = threading.Lock()
+        self._in_flight = 0
+        self._idle: Optional[otrace.Stage] = None
+
+    @staticmethod
+    def is_send_batch(raw: bytes) -> bool:
+        """Told from the body's bytes: the stages start before it is
+        parsed."""
+        return raw[:16].lstrip()[:1] == b"[" and b'"sendTransaction"' in raw
+
+    def body_in_hand(self) -> None:
+        with self._lock:
+            self._in_flight += 1
+            idle, self._idle = self._idle, None
+        if idle is not None:
+            idle.stop()
+        self._tl.decode = self._stages.stage("rpc_decode")
+        self._tl.respond = None
+
+    def frames_handed_over(self) -> None:
+        decode = getattr(self._tl, "decode", None)
+        if decode is not None:
+            decode.stop()
+
+    def first_receipt_in(self) -> None:
+        if getattr(self._tl, "decode", None) is not None \
+                and self._tl.respond is None:
+            self._tl.respond = self._stages.stage("rpc_respond")
+
+    def response_ready(self) -> None:
+        self._tl.decode.cancel()  # no cohort formed: nothing was decoded
+        if self._tl.respond is not None:
+            self._tl.respond.stop()
+        self._tl.decode = self._tl.respond = None
+        with self._lock:
+            self._in_flight -= 1
+            if self._in_flight == 0:
+                self._idle = self._stages.stage("rpc_no_request")
+
+
 def _encode_one(resp) -> bytes:
     if isinstance(resp, dict) and len(resp) == 3 and "error" not in resp:
         raw = getattr(resp.get("result"), "raw", None)
@@ -229,6 +284,8 @@ class JsonRpcImpl:
         self.max_batch = getattr(getattr(node, "config", None),
                                  "rpc_max_batch", 256)
         self._tl = threading.local()  # .cohort: a batch's admitted txs
+        self.edge_stages = _EdgeStages(
+            otrace.stages(getattr(node, "trace_label", "")))
         self.methods = {
             "call": self.call,
             "sendTransaction": self.send_transaction,
@@ -304,6 +361,7 @@ class JsonRpcImpl:
         if len(frames) > 1 and not (health is not None
                                     and health.writes_shed()):
             from ..txpool.ingest import LaneStopped, TxPoolIsFull
+            self.edge_stages.frames_handed_over()
             try:
                 tasks = dict(zip(frames, lane.submit_wire_cohort(
                     list(frames.values()))))
@@ -471,6 +529,9 @@ class JsonRpcImpl:
         if rc is None:
             raise JsonRpcError(JSONRPC_INTERNAL_ERROR,
                                "timed out waiting for receipt")
+        if admitted is not None:
+            # the cohort's first receipt is in: the rest is rendering
+            self.edge_stages.first_receipt_in()
         out = _receipt_json(rc, res.tx_hash)
         if require_proof:
             self._attach_proof(out, res.tx_hash, "receiptProof",
@@ -987,7 +1048,7 @@ def http_body_handler(impl, max_batch: int = 256):
     is echoed on the response, so callers can correlate without parsing
     bodies."""
 
-    def handle(raw: bytes, headers: Optional[dict] = None):
+    def respond(raw: bytes, headers: Optional[dict] = None):
         ctx = otrace.parse_traceparent(
             headers.get("traceparent")) if headers else None
         try:
@@ -1005,6 +1066,21 @@ def http_body_handler(impl, max_batch: int = 256):
         if ctx is not None:
             return body, {"traceparent": ctx.traceparent()}
         return body
+
+    # stage stamps for an impl bound to one node; the multigroup and Pro
+    # facades serve unstamped
+    edge = getattr(impl, "edge_stages", None)
+    if edge is None:
+        return respond
+
+    def handle(raw: bytes, headers: Optional[dict] = None):
+        if not edge.is_send_batch(raw):
+            return respond(raw, headers)
+        edge.body_in_hand()
+        try:
+            return respond(raw, headers)
+        finally:
+            edge.response_ready()
 
     return handle
 

@@ -36,6 +36,7 @@ anyway); across processes the same message objects ride the service RPC.
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import queue
 import threading
 from collections import deque
@@ -46,12 +47,65 @@ from ..executor.evm import EVMResult
 from ..protocol import Receipt, Transaction, TransactionStatus
 from ..storage.state import StateStorage
 from ..utils.log import LOG, badge, metric
-from ..utils.trace import DmcStepRecorder
 
 MSG_ROOT, MSG_CALL = 0, 1
 
 
 MAX_XSHARD_DEPTH = 64  # cap on cross-shard hops (each costs an executive)
+
+
+class DmcStepRecorder:
+    """Order-independent checksum of each DMC round's message stream
+    (bcos-scheduler/src/DmcStepRecorder.cpp).
+
+    Replicas executing the same block must record identical checksums per
+    round; the first differing round localises a divergence (scheduler bug,
+    nondeterministic executor, device/host kernel mismatch). XOR-combined
+    SHA-256 per message makes the checksum independent of intra-round
+    arrival order, like the reference's add-based checksum.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._rounds: list[bytes] = []
+        self._current = bytes(32)
+        self._count = 0
+
+    @staticmethod
+    def _digest(ctx: int, seq: int, to: bytes, data: bytes) -> bytes:
+        return hashlib.sha256(
+            ctx.to_bytes(8, "big") + seq.to_bytes(8, "big")
+            + len(to).to_bytes(2, "big") + to + data).digest()
+
+    def record_message(self, ctx: int, seq: int, to: bytes,
+                       data: bytes) -> None:
+        d = self._digest(ctx, seq, to, data)
+        with self._lock:
+            self._current = bytes(a ^ b for a, b in zip(self._current, d))
+            self._count += 1
+
+    def next_round(self) -> bytes:
+        """Close the current round; -> its checksum."""
+        with self._lock:
+            cksum = self._current
+            self._rounds.append(cksum)
+            self._current = bytes(32)
+            n = self._count
+            self._count = 0
+        metric("dmc.round_checksum", round=len(self._rounds),
+               messages=n, checksum=cksum[:8].hex())
+        return cksum
+
+    def checksums(self) -> list[bytes]:
+        with self._lock:
+            return list(self._rounds)
+
+    def summary(self) -> bytes:
+        """One digest over all rounds (order-sensitive across rounds)."""
+        h = hashlib.sha256()
+        for c in self.checksums():
+            h.update(c)
+        return h.digest()
 
 
 @dataclasses.dataclass

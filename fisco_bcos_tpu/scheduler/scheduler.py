@@ -52,6 +52,7 @@ from ..protocol import Block, BlockHeader, ParentInfo, Receipt, Transaction
 from ..storage.interface import ChangeSet, Entry, TransactionalStorage
 from ..storage.state import StackedStorageView, StateStorage
 from ..utils import failpoints as fp
+from ..utils import otrace
 from ..utils.log import LOG, badge, metric
 
 # deterministic fault sites on the commit pipeline (utils/failpoints.py):
@@ -106,8 +107,10 @@ class Scheduler:
         # out-of-process execution pool (scheduler/workers.py) — None =
         # in-process execute (the default); wired via attach_exec_pool
         self.exec_pool = None
-        # per-node label for the block-trace registry + span attribution
+        # per-node label: span attribution, and the node's stage table
+        # (utils/otrace.py) that every stage below is stamped into
         self.trace_label = trace_label
+        self.stages = otrace.stages(trace_label)
         self._lock = lc.make_rlock("scheduler.state")    # bookkeeping dicts
         self._exec_lock = lc.make_rlock("scheduler.exec")  # serialises execution
         self._commit_2pc = lc.make_lock("scheduler.2pc")   # serialises the 2PC
@@ -134,9 +137,6 @@ class Scheduler:
         # Commits are strictly height-ordered, so an OrderedDict evicts
         # its oldest entry in O(1) instead of re-scanning for min().
         self.last_committed_txs: "OrderedDict[int, list]" = OrderedDict()
-        # per-stage occupancy accounting (chain_bench --pipeline-profile)
-        self._stage_s: dict[str, float] = {}
-        self._stage_n: dict[str, int] = {}
         self._overlap_commits = 0      # 2PCs that ran while a block executed
         self._speculative_execs = 0    # executions stacked over uncommitted state
         self._exec_busy = False
@@ -163,29 +163,21 @@ class Scheduler:
             self._commit_thread.start()  # bcoslint: disable=thread-start-in-ctor
 
     # -- stage accounting --------------------------------------------------
-    def _stage(self, name: str, dt: float) -> None:
-        with self._lock:
-            self._stage_s[name] = self._stage_s.get(name, 0.0) + dt
-            self._stage_n[name] = self._stage_n.get(name, 0) + 1
+    # per-stage occupancy (chain_bench --pipeline-profile): the scheduler's
+    # own stages out of the node's one aggregate
+    PIPELINE_STAGES = ("fill", "execute", "roots", "consensus_wait",
+                       "commit")
 
     def pipeline_stats(self) -> dict:
+        stages = self.stages.snapshot(self.PIPELINE_STAGES)
         with self._lock:
             return {
                 "depth": len(self._spec),
                 "commit_queue": self._commit_q.qsize(),
                 "overlap_commits": self._overlap_commits,
                 "speculative_execs": self._speculative_execs,
-                "stages": {k: {"seconds": round(v, 4),
-                               "count": self._stage_n.get(k, 0)}
-                           for k, v in sorted(self._stage_s.items())},
+                "stages": stages,
             }
-
-    def reset_pipeline_stats(self) -> None:
-        with self._lock:
-            self._stage_s.clear()
-            self._stage_n.clear()
-            self._overlap_commits = 0
-            self._speculative_execs = 0
 
     def commit_backlog(self) -> int:
         """Decided-but-uncommitted depth: the commit worker's queue plus
@@ -224,7 +216,9 @@ class Scheduler:
 
         With pipelining, the proposal may chain on a not-yet-committed
         parent: reads stack over the speculative chain's changesets."""
-        t0 = time.monotonic()
+        # `fill` runs from here: the wait for the execution slot is the
+        # block's, like the pool look-up behind it
+        fill = self.stages.block(block.header.number).stage("fill")
         with self._exec_lock:
             self._exec_busy = True
             try:
@@ -232,13 +226,14 @@ class Scheduler:
                 # whatever thread drives execution (sealer, PBFT worker,
                 # sync) carry stage=execute — two dict writes per block
                 with _prof_stage("execute"):
-                    return self._execute_locked(block, sealer_list, t0)
+                    return self._execute_locked(block, sealer_list, fill)
             finally:
                 self._exec_busy = False
+                fill.cancel()  # a refused block filled nothing
 
     def _execute_locked(self, block: Block,
                         sealer_list: Sequence[bytes] | None,
-                        t0: float) -> Optional[ExecutionResult]:
+                        fill: otrace.Stage) -> Optional[ExecutionResult]:
         header = block.header
         with self._lock:
             committed = self.ledger.current_number()
@@ -277,8 +272,7 @@ class Scheduler:
             parent_hash = parent.hash(self.suite) if parent else b"\x00" * 32
             backend = self.storage
 
-        from ..utils.trace import block_trace
-        trace = block_trace(header.number, owner=self.trace_label)
+        blk = self.stages.block(header.number)
         txs = block.transactions
         if not txs and block.tx_hashes:
             if self.txpool is None:
@@ -288,45 +282,40 @@ class Scheduler:
                 LOG.warning(badge("SCHED", "missing-txs", number=header.number))
                 return None
             block.transactions = txs
-        trace.stage("fill")
-        t_fill = time.monotonic()
-        self._stage("fill", t_fill - t0)
 
         state = StateStorage(backend)
-        receipts = self._execute_stage(txs, state, backend, header)
-        trace.stage("execute")
-        t_exec = time.monotonic()
-        self._stage("execute", t_exec - t_fill)
+        with blk.stage("execute", t0=fill.stop()) as executing:
+            receipts = self._execute_stage(txs, state, backend, header)
 
         # finalise header: parent info + roots
-        header.parent_info = [ParentInfo(header.number - 1, parent_hash)]
-        header.txs_root = block.calculate_txs_root(self.suite)
-        block.receipts = receipts
-        header.receipts_root = block.calculate_receipts_root(self.suite)
-        self.ledger.prewrite_block(block, state)
-        changes = state.changeset()
-        # per-CHANGESET root, deliberately NOT cumulative: identical whether
-        # the parent's changeset is durable or still speculative
-        if self.state_index:
-            root, leaf_index = self.executor.state_root_with_leaves(changes)
-            header.state_root = root
-            # staged AFTER the root so the row never feeds its own tree;
-            # re-export picks it up for the same 2PC commit
-            self.ledger.write_state_index(state, header.number, leaf_index)
+        with blk.stage("roots", t0=executing.t1) as rooting:
+            header.parent_info = [ParentInfo(header.number - 1, parent_hash)]
+            header.txs_root = block.calculate_txs_root(self.suite)
+            block.receipts = receipts
+            header.receipts_root = block.calculate_receipts_root(self.suite)
+            self.ledger.prewrite_block(block, state)
             changes = state.changeset()
-        else:
-            header.state_root = self.executor.state_root(changes)
-        trace.stage("roots")
-        header.gas_used = sum(r.gas_used for r in receipts)
-        header.invalidate()
-        if sealer_list is not None:
-            header.sealer_list = list(sealer_list)
-        hh = header.hash(self.suite)
+            # per-CHANGESET root, deliberately NOT cumulative: identical
+            # whether the parent's changeset is durable or still speculative
+            if self.state_index:
+                root, leaf_index = \
+                    self.executor.state_root_with_leaves(changes)
+                header.state_root = root
+                # staged AFTER the root so the row never feeds its own
+                # tree; re-export picks it up for the same 2PC commit
+                self.ledger.write_state_index(state, header.number,
+                                              leaf_index)
+                changes = state.changeset()
+            else:
+                header.state_root = self.executor.state_root(changes)
+            header.gas_used = sum(r.gas_used for r in receipts)
+            header.invalidate()
+            if sealer_list is not None:
+                header.sealer_list = list(sealer_list)
+            hh = header.hash(self.suite)
         result = ExecutionResult(header, receipts, state,
                                  list(block.transactions), changes,
-                                 parent_hash, hh,
-                                 t_executed=time.monotonic())
-        self._stage("roots", result.t_executed - t_exec)
+                                 parent_hash, hh, t_executed=rooting.t1)
         with self._lock:
             # re-validate the chain didn't move while we executed (a commit
             # popping the front is fine; an abort/external jump is not)
@@ -351,9 +340,11 @@ class Scheduler:
             self._executed[hh] = result
             self._exec_heights.setdefault(header.number, set()).add(hh)
             self._spec[header.number] = result
+        # executed and kept: from here the block waits for its seals
+        blk.open("consensus_wait", t0=result.t_executed)
         metric("scheduler.execute", number=header.number, n_tx=len(txs),
                speculative=bool(spec),
-               ms=int((time.monotonic() - t0) * 1000))
+               ms=int((time.monotonic() - fill.t0) * 1000))
         return result
 
     def _execute_stage(self, txs, state: StateStorage, backend,
@@ -592,17 +583,16 @@ class Scheduler:
         changes = dict(result.changes)
         changes[(T_HEADER, _be8(number))] = Entry(result.header.encode())
         changes[(T_HASH2NUM, hh)] = Entry(_be8(number))
-        from ..utils.trace import block_trace, drop_block_trace
-        trace = block_trace(number, owner=self.trace_label)
-        trace.stage("consensus_wait")
-        if result.t_executed:
-            self._stage("consensus_wait", t0 - result.t_executed)
+        blk = self.stages.block(number)
+        blk.close("consensus_wait", t1=t0)
+        committing = blk.stage("commit", t0=t0)
         with self._commit_2pc:
             # re-check under the 2PC lock: a concurrent committer (sync
             # replay racing the commit worker) must not land a second
             # block at this height
             if self.ledger.current_number() != number - 1:
                 LOG.error(badge("SCHED", "commit-raced", number=number))
+                committing.cancel()
                 with self._lock:
                     result.committing = False
                     self._executed[hh] = result
@@ -618,6 +608,7 @@ class Scheduler:
                 LOG.exception(badge("SCHED", "commit-2pc-failed",
                                     number=number))
                 fp.fire("scheduler.2pc.rollback")
+                committing.cancel()
                 self.storage.rollback(number)
                 self._commit_fault(exc)
                 # put the executed result back: a transient storage failure
@@ -637,8 +628,7 @@ class Scheduler:
         if self._exec_busy:
             with self._lock:
                 self._overlap_commits += 1
-        trace.stage("commit")
-        self._stage("commit", time.monotonic() - t0)
+        notifying = blk.stage("notify", t0=committing.stop())
         with self._lock:
             # drop any other stale executed results for this height
             self._evict_upto_locked(number)
@@ -655,11 +645,9 @@ class Scheduler:
             self.txpool.on_block_committed(number, tx_hashes, nonces)
         self._notify_q.put(number)
         # receipt waiters are settled by on_block_committed above: stamp
-        # the notify stage before retiring the block's trace
-        trace.stage("notify")
-        tr = drop_block_trace(number, owner=self.trace_label)
-        if tr is not None:
-            tr.finish()
+        # the notify stage before retiring the block's holder
+        notifying.stop()
+        self.stages.drop_block(number)
         metric("scheduler.commit", number=number,
                ms=int((time.monotonic() - t0) * 1000))
         return True

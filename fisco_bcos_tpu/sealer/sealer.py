@@ -37,6 +37,7 @@ from typing import Callable, Optional
 from ..analysis import lockcheck as lc
 from ..protocol import Block, BlockHeader
 from ..txpool.txpool import TxPool
+from ..utils import otrace
 from ..utils.log import metric
 from ..utils.worker import Worker
 
@@ -78,9 +79,10 @@ class Sealer(Worker):
         self.current_height = current_height
         self.txpool = txpool
         self.suite = suite
-        # node label for the per-block trace registry (utils/trace.py):
-        # in-process clusters stamp per node instead of colliding
+        # node label of the stage table (utils/otrace.py): in-process
+        # clusters stamp per node instead of colliding
         self.trace_label = trace_label
+        self.stages = otrace.stages(trace_label)
         # proposal timestamp source: peer-median-aligned when wired to
         # NodeTimeMaintenance (tool/timesync.py), local UTC otherwise
         self.clock_ms = clock_ms or (lambda: int(time.time() * 1000))
@@ -100,7 +102,9 @@ class Sealer(Worker):
         self._grants: dict[int, tuple[int, int]] = {}
         # (height, view) pairs already sealed — never seal a round twice
         self._done: set[tuple[int, int]] = set()
-        self._first_pending_at: Optional[float] = None
+        # `seal_wait`, open from the first pass that finds unsealed txs
+        # under a grant until they are sealed
+        self._seal_wait: Optional[otrace.Stage] = None
         txpool.register_unseal_notifier(self.wakeup)
 
     # -- consensus drives these --------------------------------------------
@@ -133,6 +137,11 @@ class Sealer(Worker):
                 self._grants.clear()
             self.wakeup()
 
+    def _nothing_to_seal(self) -> None:
+        if self._seal_wait is not None:
+            self._seal_wait.cancel()
+            self._seal_wait = None
+
     # -- worker loop --------------------------------------------------------
     def execute_worker(self) -> Optional[float]:
         """Returns the next wait: None = sleep until a wakeup event, a
@@ -145,18 +154,18 @@ class Sealer(Worker):
             self.revoke(self.current_height())
         with self._lock:
             if not self._grants:
-                self._first_pending_at = None
+                self._nothing_to_seal()
                 return None  # grant() wakes us
             number = min(self._grants)
             view, limit = self._grants[number]
         pending = self.txpool.pending_count()
         if pending == 0:
-            self._first_pending_at = None
+            self._nothing_to_seal()
             return None  # _notify_ready (admission/unseal) wakes us
         now = time.monotonic()
-        if self._first_pending_at is None:
-            self._first_pending_at = now
-        waited = now - self._first_pending_at
+        if self._seal_wait is None:
+            self._seal_wait = self.stages.stage("seal_wait", t0=now)
+        waited = now - self._seal_wait.t0
         if pending < limit:
             if waited < self.min_seal_time:
                 # wait to fill the block: wake exactly when the window
@@ -185,10 +194,7 @@ class Sealer(Worker):
             # another proposal / expired at this height) — unseal, commit
             # removal and fresh admission all fire _notify_ready
             return None
-        t_seal = time.monotonic()
-        queue_wait = (t_seal - self._first_pending_at
-                      if self._first_pending_at is not None else 0.0)
-        self._first_pending_at = None
+        seal_wait, self._seal_wait = self._seal_wait, None
         with self._lock:
             # consume the grant BEFORE submitting: whatever happens next,
             # this (height, view) round has had its one proposal
@@ -202,19 +208,13 @@ class Sealer(Worker):
         # adopt that context as the BLOCK's: every downstream stage
         # (consensus, execute, commit, notify, on every node via the p2p
         # envelope) records into that one trace
-        from ..utils import otrace
-        from ..utils.trace import block_trace, observe_stage
-        observe_stage("queueing", queue_wait)
         ctx = next((c for c in (getattr(t, "_otrace", None) for t in txs)
                     if c is not None and c.sampled), None)
-        tr = block_trace(number, owner=self.trace_label)
         if ctx is not None:
-            tr.bind(ctx)
+            self.stages.block(number).bind(ctx)
             block._otrace = ctx
-            otrace.TRACER.record(
-                "seal", ctx, t_seal - queue_wait, t_seal,
-                attrs={"number": number, "n_tx": len(txs),
-                       "node": self.trace_label})
+        seal_wait.stop(ctx, {"number": number, "n_tx": len(txs),
+                             "node": self.trace_label})
         if not self.submit_proposal(block):
             # refused — nothing was broadcast, so the round is re-openable
             # without any vote-split risk. Txs go back to the pool. Solo

@@ -43,7 +43,6 @@ from ..utils import otrace
 from ..utils.log import LOG, badge, metric
 from ..utils.metrics import REGISTRY
 from ..utils.task import Task
-from ..utils.trace import observe_stage
 from .txpool import TxSubmitResult
 
 from ..crypto.suite import BUCKETS as _SUITE_BUCKETS
@@ -94,6 +93,10 @@ class IngestLane:
                  trace_label: str = ""):
         self.txpool = txpool
         self.trace_label = trace_label  # span node attribution
+        self.stages = otrace.stages(trace_label)
+        # `lane_wait`: open from the first entry queued (under _cv) until
+        # the dispatcher takes the batch that holds it
+        self._lane_wait: Optional[otrace.Stage] = None
         # metrics sink: a multi-group node passes a group-labeled view
         # (utils.metrics.for_group) so G lanes don't silently aggregate
         self._reg = registry if registry is not None else REGISTRY
@@ -175,6 +178,7 @@ class IngestLane:
                 raise TxPoolIsFull(
                     f"ingest queue at capacity ({self.queue_cap})")
             self._q.append(entry)
+            self._queued_locked()
             depth = len(self._q)
             self._cv.notify_all()
         self._reg.set_gauge("bcos_ingest_queue_depth", depth)
@@ -215,6 +219,7 @@ class IngestLane:
                 raise TxPoolIsFull(
                     f"ingest queue at capacity ({self.queue_cap})")
             self._q.extend(entries)
+            self._queued_locked()
             depth = len(self._q)
             self._cv.notify_all()
         self._reg.set_gauge("bcos_ingest_queue_depth", depth)
@@ -243,6 +248,7 @@ class IngestLane:
             dropped = len(wires) - accepted
             self._dropped_total += dropped
             if accepted:
+                self._queued_locked()
                 self._cv.notify_all()
         if dropped:
             self._reg.inc("bcos_ingest_dropped_total", dropped)
@@ -271,12 +277,18 @@ class IngestLane:
             dropped = len(txs) - accepted
             self._dropped_total += dropped
             if accepted:
+                self._queued_locked()
                 self._cv.notify_all()
         if dropped:
             self._reg.inc("bcos_ingest_dropped_total", dropped)
             metric("ingest.drop", n=dropped)
         self._reg.set_gauge("bcos_ingest_queue_depth", depth)
         return accepted
+
+    def _queued_locked(self) -> None:
+        if self._lane_wait is None:
+            self._lane_wait = self.stages.stage("lane_wait",
+                                                t0=self._q[0].t_enq)
 
     # -- adaptive coalescing -----------------------------------------------
     def _plan(self, queued: int) -> tuple[int, float]:
@@ -350,9 +362,12 @@ class IngestLane:
                 batch = [self._q.popleft()
                          for _ in range(min(len(self._q), self.max_batch))]
                 depth = len(self._q)
+                waited, self._lane_wait = self._lane_wait, None
+                if depth:  # what stays queued has been waiting as well
+                    self._queued_locked()
             self._reg.set_gauge("bcos_ingest_queue_depth", depth)
             try:
-                self._dispatch(batch)
+                self._dispatch(batch, waited)
             except Exception as exc:  # noqa: BLE001 — lane must survive
                 LOG.exception(badge("INGEST", "dispatch-failed",
                                     n=len(batch)))
@@ -360,8 +375,10 @@ class IngestLane:
                     if e.task is not None:
                         e.task.reject(exc)
 
-    def _dispatch(self, batch: list[_Entry]) -> None:
-        now = time.monotonic()
+    def _dispatch(self, batch: list[_Entry],
+                  waited: Optional[otrace.Stage] = None) -> None:
+        # latency attribution: the batch's coalesce time ends here
+        now = waited.stop() if waited is not None else time.monotonic()
         # columnar entries (raw wire frames) and object entries dispatch
         # through their own pool doors; a mixed drain pays two recover
         # calls, but producers are homogeneous per deployment (wire RPC +
@@ -398,7 +415,7 @@ class IngestLane:
         # one pool call per path == one device recover for the drained set
         from ..analysis.profiler import stage as _prof_stage
         t0 = time.perf_counter()
-        with _prof_stage("ingest.admit"):
+        with _prof_stage("ingest.admit"), self.stages.stage("admit"):
             if obj_entries:
                 results = self.txpool.submit_batch(
                     [e.tx for e in obj_entries], broadcast=self.broadcast)
@@ -414,15 +431,9 @@ class IngestLane:
                     if e.task is not None:
                         e.task.resolve(res)
         dt = time.perf_counter() - t0
-        # latency attribution: per-batch coalesce time into the stage
-        # histogram; traced submissions additionally get their own
-        # enqueue-to-admitted span (one per traced entry, linked to the
-        # shared batch by the batch-size attribute)
-        # unlabeled registry on purpose: every bcos_tx_stage_seconds
-        # stage must live in ONE series family or cross-stage shares
-        # (the dashboard's headline panel) skew — the block stages are
-        # unlabeled, so these are too
-        observe_stage("ingest", now - batch[0].t_enq)
+        # traced submissions additionally get their own enqueue-to-admitted
+        # span (one per traced entry, linked to the shared batch by the
+        # batch-size attribute)
         t_done = time.monotonic()
         for e in batch:
             if e.ctx is not None and e.ctx.sampled:

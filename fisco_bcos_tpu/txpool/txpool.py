@@ -45,6 +45,7 @@ from ..analysis import lockcheck as lc
 from ..ledger.ledger import Ledger
 from ..protocol import Block, Transaction, TransactionStatus, batch_hash, \
     batch_recover_senders
+from ..utils import otrace
 from ..utils.log import LOG, badge, metric
 
 DEFAULT_POOL_LIMIT = 15000  # txpool.limit default (NodeConfig.cpp:473-493)
@@ -103,8 +104,10 @@ class TxPool:
                  group_id: str = "group0", pool_limit: int = DEFAULT_POOL_LIMIT,
                  block_limit_range: int = 600, registry=None,
                  low_watermark: float = 0.7, high_watermark: float = 0.95,
-                 priority_bands: bool = True):
+                 priority_bands: bool = True, trace_label: str = ""):
         self.suite = suite
+        # the node's stage table (utils/otrace.py): `crypto` below
+        self.stages = otrace.stages(trace_label)
         self._registry = registry  # None -> utils.metrics.REGISTRY
         self.ledger = ledger
         self.chain_id = chain_id
@@ -266,14 +269,10 @@ class TxPool:
         drops: list[tuple[bytes, TransactionStatus, object]] = []
         if need_verify:
             sub = [txs[i] for i in need_verify]
-            t_rec = time.monotonic()
-            senders, ok = batch_recover_senders(sub, self.suite)
             # per-batch signature-recover time -> the latency attribution
-            # plane's "crypto" stage (covers the lane AND direct paths);
-            # unlabeled on purpose — all bcos_tx_stage_seconds stages
-            # share one series family so cross-stage shares stay honest
-            from ..utils.trace import observe_stage
-            observe_stage("crypto", time.monotonic() - t_rec)
+            # plane's "crypto" stage (covers the lane AND direct paths)
+            with self.stages.stage("crypto"):
+                senders, ok = batch_recover_senders(sub, self.suite)
             current = self.ledger.current_number()  # off-lock, as above
             with self._lock:
                 occupancy = len(self._pending)
@@ -338,7 +337,6 @@ class TxPool:
         for tx in txs:
             ctx = getattr(tx, "_otrace", None)
             if ctx is not None and ctx.sampled:
-                from ..utils import otrace
                 otrace.TRACER.record(
                     "txpool.admit", ctx, t0,
                     attrs={"n": len(txs), "ok": n_ok,
@@ -392,7 +390,6 @@ class TxPool:
                 results[i] = TxSubmitResult(
                     b"", TransactionStatus.REQUEST_NOT_BELIEVABLE)
         hashes = cols.ensure_hashes(self.suite)
-        from ..utils.trace import observe_stage
         # ledger reads OUTSIDE txpool.state (same rationale as
         # submit_batch: GIL-held / possibly-RPC work off the hot lock)
         current = self.ledger.current_number()
@@ -427,9 +424,8 @@ class TxPool:
         drops: list[tuple[bytes, TransactionStatus, object]] = []
         accepted: list = []
         if need_verify:
-            t_rec = time.monotonic()
-            ok_mask = cols.ensure_senders(self.suite, rows=need_verify)
-            observe_stage("crypto", time.monotonic() - t_rec)
+            with self.stages.stage("crypto"):
+                ok_mask = cols.ensure_senders(self.suite, rows=need_verify)
             current = self.ledger.current_number()  # off-lock, as above
             with self._lock:
                 occupancy = len(self._pending)

@@ -28,10 +28,23 @@ propagation — while staying stdlib-only:
     cross-thread handoffs (ingest lane entries, sealed blocks, PBFT
     messages) pin the context onto the carried object.
 
+  * stages (`stages(owner).stage(name)` / `.block(number)`): the ONE
+    stamp of the latency attribution plane. A cohort's or a block's stage
+    is stamped once and feeds three sinks — the always-on per-node
+    aggregate behind `getSystemStatus()["trace"]["stages"]`, the sampled
+    span + the dashboard's per-stage histogram (`STAGE_HISTOGRAM`), and a
+    `jax.profiler.TraceAnnotation` of the same name, so that a profiler
+    session sees the stage on the device's clock. The per-block holder
+    (`BlockStages`) also carries the block's bound span context, so
+    sealer/consensus/scheduler stamp one block without threading it
+    through every signature.
+
 Cost contract: with no context attached and sampling off, the
 instrumented hot paths pay one branch (plus, where slow-capture applies,
 one monotonic clock read); span dicts are only materialised for sampled
-or slow spans.
+or slow spans. Stages are stamped per cohort and per block, never per
+transaction: two clock reads, one locked add, one histogram observation
+and an inert TraceMe each.
 """
 
 from __future__ import annotations
@@ -43,6 +56,7 @@ from collections import deque
 from typing import Optional
 
 from .log import LOG, badge
+from .metrics import REGISTRY
 
 
 def _rand_id(nbytes: int) -> bytes:
@@ -364,8 +378,7 @@ class Tracer:
                 self._slow.append(span)
             self._recorded += 1
         if slow:
-            from . import metrics as _m  # lazy: slow path only
-            _m.REGISTRY.inc("bcos_trace_slow_spans_total")
+            REGISTRY.inc("bcos_trace_slow_spans_total")
             LOG.warning(badge("TRACE", "slow-span", name=name,
                               ms=span["duration_ms"],
                               trace=span["traceId"][:16]))
@@ -438,3 +451,211 @@ def configure(sample_rate: Optional[float] = None,
     TRACER.configure(sample_rate=sample_rate, ring_size=ring_size,
                      slow_ms=slow_ms)
     return TRACER
+
+
+# -- stages ---------------------------------------------------------------
+# one cohort's round trip through a node, from the request body in hand to
+# the response handed to the socket; a name is the aggregate's key, the
+# histogram's label and the profiler annotation at once, so `[a-z_]+`
+STAGES = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "gossip",
+          "crypto", "round_wait", "seal_wait", "consensus_pre", "fill",
+          "execute", "roots", "consensus_wait", "commit", "notify",
+          "rpc_respond")
+STAGE_HISTOGRAM = "bcos_tx_stage_seconds"
+# two series of the histogram are older than the stage names
+_HISTOGRAM_LABEL = {"lane_wait": "ingest", "seal_wait": "queueing"}
+# stage durations live between "instant" and "a slow block": the default
+# time buckets bottom out too low and top out too high
+_STAGE_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+                  0.25, 0.5, 1.0, 2.5, 5.0)
+
+_annotation = None  # jax.profiler.TraceAnnotation; False: not importable
+
+
+def _annotate(name: str):
+    """A started TraceMe of `name`, or None where jax.profiler cannot be
+    imported. Outside a profiler session a TraceMe is inert, so there is
+    no switch; inside one it is written to the `/host:CPU` plane when it
+    is stopped, on whatever thread stops it."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except Exception:  # noqa: BLE001 — no jax, no annotation
+            _annotation = False
+    if not _annotation:
+        return None
+    ann = _annotation(name)
+    ann.__enter__()
+    return ann
+
+
+class Stage:
+    """One open stage. `stop()` closes it into the three sinks; as a
+    context manager the scope is the stage. `t1` is the stop time, for
+    the stage that starts where this one ended."""
+
+    __slots__ = ("_table", "_block", "name", "t0", "t1", "_ann")
+
+    def __init__(self, table: "StageTable", name: str,
+                 t0: Optional[float] = None, block=None):
+        self._table = table
+        self._block = block
+        self.name = name
+        self.t1 = None
+        self._ann = _annotate(name)
+        self.t0 = time.monotonic() if t0 is None else t0
+
+    def stop(self, ctx: Optional[SpanContext] = None,
+             attrs: Optional[dict] = None,
+             t1: Optional[float] = None) -> float:
+        """-> the stop time. `ctx`/`attrs` go to the span sink (a block's
+        stage takes its block's, any other the stopping thread's current
+        context); a second stop does nothing."""
+        table = self._table
+        if table is None:
+            return self.t1
+        self._table = None
+        self.t1 = time.monotonic() if t1 is None else t1
+        self._end_annotation()
+        blk = self._block
+        if blk is not None:
+            ctx = ctx or blk.ctx
+            attrs = {"number": blk.number, "node": table.owner,
+                     **(attrs or {})}
+        elif ctx is None:
+            ctx = current()  # the stopping thread's, where it scopes one
+        table._observe(self.name, self.t0, self.t1, ctx, attrs)
+        return self.t1
+
+    def cancel(self) -> None:
+        """Drop an open stage that turned out not to be one (an early
+        return, a wait that ended with nothing to wait for)."""
+        self._table = None
+        self._end_annotation()
+
+    def _end_annotation(self) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.stop()
+        return False
+
+
+class BlockStages:
+    """One block's stages on one node, and the span context bound to the
+    block (sealer binds on the leader, the PBFT engine on replicas from
+    the pre-prepare's envelope): stages stopped from here on are spans of
+    that trace. `open`/`close` by name carry a stage from the handler
+    that starts it to the one that ends it."""
+
+    __slots__ = ("_table", "number", "ctx", "_open")
+
+    def __init__(self, table: "StageTable", number: int):
+        self._table = table
+        self.number = number
+        self.ctx: Optional[SpanContext] = None
+        self._open: dict[str, Stage] = {}
+
+    def bind(self, ctx: Optional[SpanContext]) -> None:
+        if ctx is not None and ctx.sampled:
+            self.ctx = ctx
+
+    def stage(self, name: str, t0: Optional[float] = None) -> Stage:
+        return Stage(self._table, name, t0, block=self)
+
+    def open(self, name: str, t0: Optional[float] = None) -> None:
+        """Start `name` and keep it (once: a re-entered handler keeps the
+        first) until `close(name)`."""
+        if name not in self._open:
+            self._open[name] = self.stage(name, t0)
+
+    def close(self, name: str, t1: Optional[float] = None) -> None:
+        st = self._open.pop(name, None)
+        if st is not None:
+            st.stop(t1=t1)
+
+
+class StageTable:
+    """A node's always-on stage aggregate, {name: [count, seconds]}, and
+    its per-block holders. Keyed by the node's trace label (`stages`):
+    the tracer is process-wide, and in-process clusters must not add
+    their nodes together."""
+
+    KEEP_BLOCKS = 64
+
+    def __init__(self, owner: str = ""):
+        self.owner = owner
+        self._lock = threading.Lock()
+        self._agg: dict[str, list] = {n: [0, 0.0] for n in STAGES}
+        self._blocks: dict[int, BlockStages] = {}
+
+    def stage(self, name: str, t0: Optional[float] = None) -> Stage:
+        """A started stage: `with table.stage(name):`, or keep it and
+        `.stop()` it where it ends."""
+        return Stage(self, name, t0)
+
+    def block(self, number: int) -> BlockStages:
+        with self._lock:
+            blk = self._blocks.get(number)
+            if blk is None:
+                blk = self._blocks[number] = BlockStages(self, number)
+                for old in [n for n in self._blocks
+                            if n < number - self.KEEP_BLOCKS]:
+                    del self._blocks[old]
+            return blk
+
+    def drop_block(self, number: int) -> None:
+        with self._lock:
+            self._blocks.pop(number, None)
+
+    def _observe(self, name: str, t0: float, t1: float,
+                 ctx: Optional[SpanContext], attrs: Optional[dict]) -> None:
+        dt = max(0.0, t1 - t0)
+        with self._lock:
+            row = self._agg.setdefault(name, [0, 0.0])
+            row[0] += 1
+            row[1] += dt
+        # the unlabeled registry on purpose: every stage lives in ONE
+        # series family, or the dashboard's cross-stage shares skew
+        REGISTRY.observe(STAGE_HISTOGRAM, dt,
+                         {"stage": _HISTOGRAM_LABEL.get(name, name)},
+                         buckets=_STAGE_BUCKETS)
+        if ctx is not None and ctx.sampled:
+            TRACER.record(f"stage.{name}", ctx, t0, t1, attrs=attrs)
+        else:
+            TRACER.observe_slow(f"stage.{name}", dt, attrs=attrs)
+
+    def snapshot(self, names: Optional[tuple] = None) -> dict:
+        """{name: {"count", "seconds"}}: every stage of `STAGES` from the
+        start, so a reader's delta never meets a missing key."""
+        with self._lock:
+            return {n: {"count": r[0], "seconds": r[1]}
+                    for n, r in self._agg.items()
+                    if names is None or n in names}
+
+    def reset(self) -> None:
+        with self._lock:
+            self._agg = {n: [0, 0.0] for n in STAGES}
+            self._blocks.clear()
+
+
+_tables: dict[str, StageTable] = {}
+_tables_lock = threading.Lock()
+
+
+def stages(owner: str = "") -> StageTable:
+    """The stage table of the node labelled `owner` (one node per process
+    stamps the same table everywhere; in-process clusters pass their node
+    label so stamps don't collide)."""
+    table = _tables.get(owner)
+    if table is None:
+        with _tables_lock:
+            table = _tables.setdefault(owner, StageTable(owner))
+    return table

@@ -59,7 +59,9 @@ def test_auto_without_a_tpu_stays_on_the_host():
     st = suite.status()
     assert st["platform"] == "cpu" and st["pallas"] == "off"
     assert st["ops"]["recover"] == {"deviceCalls": 0, "deviceItems": 0,
-                                    "hostCalls": 1, "hostItems": 1000}
+                                    "hostCalls": 1, "hostItems": 1000,
+                                    "packSeconds": 0.0, "callSeconds": 0.0,
+                                    "unpackSeconds": 0.0}
     assert COMPILE_LOG.snapshot()["compiles"] == before  # nothing to XLA:CPU
 
 

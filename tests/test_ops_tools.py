@@ -117,7 +117,8 @@ def test_light_monitor_flags_lag_and_down(tmp_path):
 
 
 def test_dmc_step_recorder_matches_across_replicas():
-    from fisco_bcos_tpu.utils.trace import BlockTrace, DmcStepRecorder
+    from fisco_bcos_tpu.scheduler.dmc_rounds import DmcStepRecorder
+    from fisco_bcos_tpu.utils import otrace
 
     def run(messages):
         rec = DmcStepRecorder()
@@ -141,13 +142,21 @@ def test_dmc_step_recorder_matches_across_replicas():
     assert c.checksums()[0] == a.checksums()[0]
     assert c.checksums()[1] != a.checksums()[1]
 
-    tr = BlockTrace(7)
-    tr.stage("seal")
-    time.sleep(0.01)
-    tr.stage("execute")
-    stages = tr.finish()
-    assert set(stages) == {"seal", "execute", "finish"}
-    assert stages["execute"] >= 0.01
+    # the per-block stage holder (otrace.BlockStages): a stage carried by
+    # name from one handler to the next, one scoped where it runs
+    table = otrace.stages("ops-tools-test")
+    table.reset()
+    blk = table.block(7)
+    blk.open("consensus_pre")
+    with blk.stage("execute"):
+        time.sleep(0.01)
+    blk.close("consensus_pre")
+    blk.close("consensus_pre")  # closed once: a second close stamps nothing
+    stages = table.snapshot(("consensus_pre", "execute", "commit"))
+    assert {k: v["count"] for k, v in stages.items()} == {
+        "consensus_pre": 1, "execute": 1, "commit": 0}
+    assert stages["consensus_pre"]["seconds"] \
+        >= stages["execute"]["seconds"] >= 0.01
 
 
 def test_storage_tool_cluster_mode(tmp_path):

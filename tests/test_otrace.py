@@ -280,7 +280,7 @@ def test_chain_trace_propagation_4node():
         # ONE trace id covering admission -> seal -> consensus ->
         # execute -> commit -> receipt notify
         assert len({s["traceId"] for s in spans}) == 1
-        for expected in ("ingest.admit", "txpool.admit", "seal",
+        for expected in ("ingest.admit", "txpool.admit", "stage.seal_wait",
                          "pbft.consensus", "stage.execute", "stage.commit",
                          "stage.notify"):
             assert expected in names, (expected, sorted(names))

@@ -361,7 +361,7 @@ def test_sealer_keeps_filling_while_pipeline_busy():
     sealer.grant(3, 0)
     sealer.execute_worker()
     assert len(proposals) == 2  # still filling
-    sealer._first_pending_at = time.monotonic() - 6.0  # window elapsed
+    sealer._seal_wait.t0 = time.monotonic() - 6.0  # window elapsed
     sealer.execute_worker()
     assert len(proposals) == 3
 

@@ -96,6 +96,21 @@ def _known_shape_root(stop):
     _known_shape_mid(stop)
 
 
+def _leaf_line(p):
+    """(line, its sample count) of the synthetic burner's folded stack."""
+    line = next((ln for ln in p.folded().splitlines()
+                 if "_known_shape_leaf" in ln), None)
+    return line, int(line.rsplit(" ", 1)[1]) if line else 0
+
+
+def _wait(predicate, seconds: float = 30.0) -> None:
+    """Until the sampler has seen enough, not for a fixed time: beside five
+    xdist workers a 150 Hz sampler may get a tenth of its samples."""
+    deadline = time.monotonic() + seconds
+    while not predicate() and time.monotonic() < deadline:
+        time.sleep(0.05)
+
+
 def test_folded_stacks_synthetic_shape():
     p = profiler.SamplingProfiler()
     stop = threading.Event()
@@ -104,15 +119,13 @@ def test_folded_stacks_synthetic_shape():
     t.start()
     try:
         p.configure(hz=150, ring=1024)
-        time.sleep(0.7)
+        _wait(lambda: _leaf_line(p)[1] >= 10)
         p.configure(hz=0)
     finally:
         stop.set()
         t.join(5)
-    folded = p.folded()
-    line = next((ln for ln in folded.splitlines()
-                 if "_known_shape_leaf" in ln), None)
-    assert line is not None, folded[:800]
+    line, _count = _leaf_line(p)
+    assert line is not None, p.folded()[:800]
     # root-first order with the full call chain intact
     i_root = line.index("_known_shape_root")
     i_mid = line.index("_known_shape_mid")
@@ -133,7 +146,8 @@ def test_cpu_attribution_names_the_burner():
     t.start()
     try:
         p.configure(hz=100, ring=1024)
-        time.sleep(1.0)
+        _wait(lambda: _leaf_line(p)[1] >= 20
+              and p.attribution()["total_cpu_seconds"] > 0.1)
         attrib = p.attribution()
         p.configure(hz=0)
     finally:
