@@ -557,6 +557,7 @@ class Node:
         lane = getattr(self.suite, "_lane", None)  # LaneSuite seam
         storage_stats = getattr(self.storage, "stats", None)
         reg = self.group_registry
+        stage_table = otrace.stages(self.trace_label)
         out = {
             "group": cfg.group_id,
             "chain": cfg.chain_id,
@@ -581,7 +582,8 @@ class Node:
             "zk": self.zk.stats(),
             "groups": reg.groups() if reg is not None else [cfg.group_id],
             "trace": {**otrace.TRACER.stats(),
-                      "stages": otrace.stages(self.trace_label).snapshot()},
+                      "stages": stage_table.snapshot(),
+                      "counters": stage_table.counters()},
             "profile": _prof.PROFILER.stats(),
             "overload": self.overload.stats()
             if self.overload is not None else None,

@@ -167,20 +167,25 @@ def merkle_levels_host(leaves: list[bytes], alg: str = "keccak256") -> list[list
     return levels
 
 
+def sibling_group(level: list[bytes], group: int) -> list[bytes]:
+    """The WIDTH siblings of `level`'s `group`-th parent, zero-padded: one
+    row of a proof, the same for every leaf under that parent."""
+    sibs = list(level[group * WIDTH: (group + 1) * WIDTH])
+    if len(sibs) < WIDTH:
+        sibs.extend([b"\x00" * DIGEST] * (WIDTH - len(sibs)))
+    return sibs
+
+
 def proof_from_levels(levels: list[list[bytes]], index: int):
     """Inclusion proof for leaf `index` out of prebuilt levels — the
-    shared walk for `merkle_proof` and the commit-time batch renderer
-    (zk/proof.py), which builds the levels ONCE per block instead of once
-    per transaction."""
+    shared walk for `merkle_proof` and the light node's span server,
+    which build the levels ONCE per block instead of once per
+    transaction. Slices its group out of each level; never copies one."""
     proof = []
     idx = index
     for level in levels[:-1]:
-        cur = list(level)
-        while len(cur) % WIDTH:
-            cur.append(b"\x00" * DIGEST)
         group = idx // WIDTH
-        sibs = cur[group * WIDTH : (group + 1) * WIDTH]
-        proof.append((sibs, idx % WIDTH))
+        proof.append((sibling_group(level, group), idx % WIDTH))
         idx = group
     return proof
 

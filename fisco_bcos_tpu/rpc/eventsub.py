@@ -468,32 +468,9 @@ class SubHub:
             rows = cache.get(("logs", number))
             if rows is not None:
                 return rows
-        # prime raced or cache disabled: render the rows now (same shape
-        # prime_block builds), fenced like any other lazy render
-        gen = cache.generation() if cache is not None else 0
-        ledger = self.node.ledger
-        rows, size = [], 0
-        from .cache import RawResult
-        from .server import _hex
-        for ti, tx_hash in enumerate(ledger.tx_hashes_by_number(number)):
-            rc = ledger.receipt(tx_hash)
-            if rc is None:
-                continue
-            for idx, log in enumerate(rc.logs):
-                frag = RawResult({
-                    "address": _hex(log.address),
-                    "topics": [_hex(t) for t in log.topics],
-                    "data": _hex(log.data),
-                    "blockNumber": number,
-                    "transactionHash": _hex(tx_hash),
-                    "transactionIndex": ti,
-                    "logIndex": idx,
-                })
-                rows.append((log, frag.raw))
-                size += len(frag.raw)
-        if cache is not None:
-            cache.put(("logs", number), rows, gen, size=size + 64)
-        return rows
+        # prime raced or cache disabled: the impl's shared render of the
+        # block — the very rows prime_block publishes
+        return self.impl.log_rows(number)[0]
 
     # -- telemetry ---------------------------------------------------------
     def note_latency(self, seconds: float) -> None:

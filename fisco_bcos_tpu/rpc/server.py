@@ -19,7 +19,10 @@ Hot immutable queries (block/tx/receipt JSON, recovered senders) serve
 from the commit-coherent `QueryCache` (rpc/cache.py) when the node has
 one: rendered once per commit (`JsonRpcImpl.prime_block` rides
 `Scheduler.on_commit`) or on first touch, invalidated on rollback and
-snapshot install.
+snapshot install. A committed block's fragments are built ONCE, from the
+objects the commit holds (`Scheduler.last_committed`), and everyone who
+wants them takes the same bytes: the cohort's worker waiting in
+`sendTransaction`, the cache, the subscription fan-out (`_BlockFragments`).
 
 `JsonRpcImpl` is transport-independent (the WS server and the in-process SDK
 reuse it); `JsonRpcServer` binds it to HTTP.
@@ -31,12 +34,14 @@ import contextlib
 import json
 import threading
 import time
+from collections import OrderedDict
 from typing import Optional
 
-from ..protocol import Block, BlockHeader, Receipt, Transaction
+from ..protocol import (Block, BlockHeader, Receipt, Transaction,
+                        batch_recover_senders)
 from ..utils import otrace
 from ..utils.log import LOG, badge
-from .cache import RawResult
+from .cache import RawResult, encode_compact
 from .edge import EventLoopHttpServer, WorkerPool
 
 JSONRPC_PARSE_ERROR = -32700
@@ -125,6 +130,54 @@ def _header_json(h: BlockHeader) -> dict:
         "signatureList": [{"index": i, "signature": _hex(s)}
                           for i, s in h.signature_list],
     }
+
+
+def _senders_size(senders: list) -> int:
+    """Cache footprint of a senders row: bytes rows are not JSON, so they
+    are sized directly (no dumps)."""
+    return sum(len(s) if s else 1 for s in senders) + 48
+
+
+def _receipt_fragments(block: Optional[Block]) -> dict:
+    """{tx hash: RawResult} for a committed block's receipts; {} for no
+    block."""
+    out = {}
+    if block is not None:
+        for h, rc in zip(block.tx_hashes, block.receipts):
+            doc = _receipt_json(rc, h)
+            # not a stored field: the ledger's copy, which every other
+            # reader renders, carries none
+            doc["message"] = ""
+            out[h] = RawResult(doc)
+    return out
+
+
+class _BlockFragments:
+    """One committed block's response fragments, built once and shared.
+
+    `gen` is the cache generation captured before the block's first read:
+    whatever of this table is published goes in under it, so a pass that
+    raced an invalidation inserts nothing, whoever ran it. `once` is the
+    guard: the notifier thread (prime_block) and a cohort's RPC worker
+    reach a fresh commit within a millisecond of each other, the first
+    builds a part, the other waits for it and takes the same objects."""
+
+    __slots__ = ("number", "gen", "_lock", "_parts")
+
+    def __init__(self, number: int, gen: Optional[int]):
+        self.number = number
+        self.gen = gen
+        self._lock = threading.Lock()
+        self._parts: dict = {}
+
+    def once(self, part: str, build):
+        with self._lock:
+            if part not in self._parts:
+                self._parts[part] = build()
+            return self._parts[part]
+
+    def peek(self, part: str):
+        return self._parts.get(part)
 
 
 class JsonRpcError(Exception):
@@ -283,9 +336,14 @@ class JsonRpcImpl:
         self.cache = getattr(node, "query_cache", None)
         self.max_batch = getattr(getattr(node, "config", None),
                                  "rpc_max_batch", 256)
-        self._tl = threading.local()  # .cohort: a batch's admitted txs
-        self.edge_stages = _EdgeStages(
-            otrace.stages(getattr(node, "trace_label", "")))
+        # .cohort: a batch's admitted txs; .frags: the committed block its
+        # worker answers from; .answered / .shared: the batch's counts
+        self._tl = threading.local()
+        self._stages = otrace.stages(getattr(node, "trace_label", ""))
+        self.edge_stages = _EdgeStages(self._stages)
+        # number -> the last few committed blocks' shared fragments
+        self._frags: "OrderedDict[int, _BlockFragments]" = OrderedDict()
+        self._frags_lock = threading.Lock()
         self.methods = {
             "call": self.call,
             "sendTransaction": self.send_transaction,
@@ -367,11 +425,15 @@ class JsonRpcImpl:
                     list(frames.values()))))
             except (TxPoolIsFull, LaneStopped):
                 pass  # each entry meets the same condition on its own
-        self._tl.cohort = tasks
+        tl = self._tl
+        tl.cohort, tl.frags, tl.answered, tl.shared = tasks, None, 0, 0
         try:
             yield
         finally:
-            self._tl.cohort = None
+            if tl.answered:
+                self._stages.count("cohort_receipts", tl.answered)
+                self._stages.count("cohort_receipts_shared", tl.shared)
+            tl.cohort = tl.frags = None
 
     def handle(self, request: dict) -> dict:
         rid = request.get("id")
@@ -515,27 +577,39 @@ class JsonRpcImpl:
         # original would have.
         if not wait:
             return {"transactionHash": _hex(res.tx_hash), "status": None}
-        # remaining budget only: admission may have consumed part of the
-        # client's timeout — wait=True must not double-spend it
-        from ..txpool.txpool import TxDropped
-        try:
-            rc = self.node.txpool.wait_for_receipt(
-                res.tx_hash, max(0.0, deadline - time.monotonic()))
-        except TxDropped as exc:
-            # evicted/shed after admission: settle NOW with the typed
-            # status instead of burning the client's full timeout
-            raise JsonRpcError(int(exc.status),
-                               TransactionStatus(exc.status).name)
-        if rc is None:
-            raise JsonRpcError(JSONRPC_INTERNAL_ERROR,
-                               "timed out waiting for receipt")
-        if admitted is not None:
-            # the cohort's first receipt is in: the rest is rendering
-            self.edge_stages.first_receipt_in()
-        out = _receipt_json(rc, res.tx_hash)
+        h = res.tx_hash
+        cohort = admitted is not None
+        # the committed block this worker already answers from holds the
+        # rest of the cohort's receipts, rendered: no wait, no ledger read
+        frags = self._tl.frags if cohort else None
+        out = frags.peek("receipts").get(h) if frags is not None else None
+        if out is None:
+            # remaining budget only: admission may have consumed part of
+            # the client's timeout — wait=True must not double-spend it
+            from ..txpool.txpool import TxDropped
+            try:
+                rc = self.node.txpool.wait_for_receipt(
+                    h, max(0.0, deadline - time.monotonic()))
+            except TxDropped as exc:
+                # evicted/shed after admission: settle NOW with the typed
+                # status instead of burning the client's full timeout
+                raise JsonRpcError(int(exc.status),
+                                   TransactionStatus(exc.status).name)
+            if rc is None:
+                raise JsonRpcError(JSONRPC_INTERNAL_ERROR,
+                                   "timed out waiting for receipt")
+            if cohort:
+                # the cohort's first receipt is in: the rest is rendering
+                self.edge_stages.first_receipt_in()
+            out = self._shared_receipt(h, rc.block_number, build=cohort)
+        if cohort:
+            self._tl.answered += 1
+            self._tl.shared += out is not None
+        if out is None:
+            out = _receipt_json(rc, h)  # no shared fragment: as ever
         if require_proof:
-            self._attach_proof(out, res.tx_hash, "receiptProof",
-                               "receiptsRoot",
+            out = dict(out)  # shared fragments are frozen; annotate a copy
+            self._attach_proof(out, h, "receiptProof", "receiptsRoot",
                                self.node.ledger.receipt_proof)
         return out
 
@@ -681,89 +755,179 @@ class JsonRpcImpl:
             if hit is not None and len(hit) == len(block.transactions):
                 return hit
         # one batch recover for all senders (not a per-tx scalar loop)
-        from ..protocol import batch_recover_senders
         senders, _ = batch_recover_senders(block.transactions,
                                            self.node.suite)
         if cache is not None and gen is not None:
-            # bytes rows are not JSON: size them directly (no dumps)
             cache.put(("senders", n), senders, gen,
-                      size=sum(len(s) if s else 1 for s in senders) + 48)
+                      size=_senders_size(senders))
         return senders
+
+    # -- a committed block's shared fragments --------------------------------
+    def _fragments(self, number: int) -> _BlockFragments:
+        """Block `number`'s table, made on first ask. The generation is
+        captured here, before anything of the block is read; a table
+        from before an invalidation is not handed out again."""
+        cache = self.cache
+        gen = cache.generation() if cache is not None else None
+        with self._frags_lock:
+            frags = self._frags.get(number)
+            if frags is None or frags.gen != gen:
+                frags = self._frags[number] = _BlockFragments(number, gen)
+                self._frags.move_to_end(number)
+                while len(self._frags) > 8:
+                    self._frags.popitem(last=False)
+        return frags
+
+    def _committed_block(self, number: int) -> Optional[Block]:
+        """Block `number` as the commit holds it (`Scheduler.
+        last_committed`: live txs with their senders, receipts with their
+        hashes) under the header row, which gained its seals at commit and
+        is the one row read. A stash miss (restart, sync replay, snapshot
+        install) or a stash that is not this chain's block reads the body
+        back from the ledger; None where it cannot be had whole."""
+        ledger = self.node.ledger
+        header = ledger.header_by_number(number)
+        if header is None:
+            return None
+        suite = self.node.suite
+        stash = getattr(getattr(self.node, "scheduler", None),
+                        "last_committed", {}).get(number)
+        if stash is not None \
+                and stash.header.hash(suite) == header.hash(suite):
+            return Block(header=header, transactions=stash.transactions,
+                         receipts=stash.receipts, tx_hashes=stash.tx_hashes)
+        block = ledger.block_by_number(number, with_txs=True)
+        if block is None or not (len(block.transactions) == len(
+                block.receipts) == len(block.tx_hashes)):
+            return None  # body rows lost to a prune sweep: serve on demand
+        block.header = header
+        return block
+
+    def _block_receipts(self, frags: _BlockFragments) -> dict:
+        """{tx hash: receipt fragment} of the table's block — one render
+        and one encoding per receipt, by whoever gets here first; {} where
+        the block cannot be had (every reader then renders as ever)."""
+        block = frags.once("block",
+                           lambda: self._committed_block(frags.number))
+        return frags.once("receipts", lambda: _receipt_fragments(block))
+
+    def _shared_receipt(self, h: bytes, number: int, build: bool):
+        """Receipt `h`'s shared fragment out of block `number`'s table, or
+        None. A cohort's worker (`build`) makes the table if the notifier
+        has not, and keeps it for its batch's other entries — by
+        reference, so no answer hangs on a cache entry surviving
+        eviction; any other reader takes what is there."""
+        if not build:
+            frags = self._frags.get(number)
+            receipts = frags.peek("receipts") if frags is not None else None
+            return receipts.get(h) if receipts else None
+        try:
+            frags = self._fragments(number)
+            out = self._block_receipts(frags).get(h)
+        except Exception:  # noqa: BLE001 — sharing is best-effort
+            LOG.exception(badge("RPC", "fragment-render-failed",
+                                number=number))
+            return None
+        if out is not None:
+            self._tl.frags = frags
+        return out
+
+    def log_rows(self, number: int) -> tuple[list, int]:
+        """-> ([(LogEntry, fragment bytes)], bytes) for block `number`: the
+        row the subscription fan-out filters and joins. Built once beside
+        the receipts, around the hexes their `logEntries` hold."""
+        frags = self._fragments(number)
+        receipts = self._block_receipts(frags)
+
+        def build() -> tuple[list, int]:
+            block = frags.peek("block")
+            rows: list[tuple] = []
+            size = 64
+            if block is None or not receipts:
+                return rows, size
+            for ti, (h, rc) in enumerate(zip(block.tx_hashes,
+                                             block.receipts)):
+                if not rc.logs:
+                    continue
+                doc = receipts[h]
+                for idx, (log, entry) in enumerate(zip(rc.logs,
+                                                       doc["logEntries"])):
+                    raw = encode_compact({
+                        **entry, "blockNumber": number,
+                        "transactionHash": doc["transactionHash"],
+                        "transactionIndex": ti, "logIndex": idx}).encode()
+                    rows.append((log, raw))
+                    size += len(raw)
+            return rows, size
+        return frags.once("logs", build)
 
     # -- commit-time cache priming (Scheduler.on_commit observer) ----------
     def prime_block(self, number: int) -> None:
         """Render the just-committed block's hot responses once, off the
-        consensus path (runs on the scheduler's notifier thread): block
-        JSON with txs / tx-hash-only / header-only, per-tx transaction +
-        receipt JSON, per-log push fragments, and the recovered-senders
-        row. Every fragment is a RawResult — its bytes are encoded HERE,
-        exactly once; polled hits splice them (encode_jsonrpc) and the
-        subscription fan-out (rpc/eventsub.SubHub) pushes the same bytes,
-        so a notification costs zero extra render."""
+        consensus path (runs on the scheduler's notifier thread), in one
+        pass over the objects the commit holds and in the order someone
+        waits for them: the receipts (a cohort's worker is parked on
+        them), the per-log push fragments and the header (the fan-out's),
+        the block views with the per-tx fragments and the senders row,
+        then the proof bundles. Every fragment is a RawResult — its bytes
+        are encoded HERE, exactly once (a tx's inside the full block are
+        the same bytes, joined); polled hits splice them (encode_jsonrpc)
+        and the subscription fan-out (rpc/eventsub.SubHub) pushes the same
+        bytes. The block's entries enter the cache in one transaction."""
         cache = self.cache
         if cache is None:
             return
+        priming = self._stages.stage("prime")
         try:
-            gen = cache.generation()
-            ledger = self.node.ledger
-            block = ledger.block_by_number(number, with_txs=True)
-            if block is None or number > ledger.current_number():
+            frags = self._fragments(number)
+            receipts = self._block_receipts(frags)
+            block = frags.peek("block")
+            if block is None:
+                priming.cancel()
                 return
-            # use the scheduler's LIVE tx objects when they are this
-            # block's: their senders were recovered at admission/verify,
-            # so the render below costs ZERO extra recover batches
-            # (ledger reads decode fresh copies with _sender unset)
-            stash = getattr(self.node.scheduler, "last_committed_txs",
-                            {}).get(number)
-            if stash is not None and len(stash) == len(block.transactions):
-                block.transactions = list(stash)
-            full = RawResult(self._block_json(block, False, False, gen=gen))
-            cache.put(("block", number, False, False), full, gen,
-                      size=len(full.raw))
-            hashes_only = RawResult(self._block_json(block, False, True))
-            cache.put(("block", number, False, True), hashes_only, gen,
-                      size=len(hashes_only.raw))
-            header = RawResult(self._block_json(block, True, False))
-            cache.put(("block", number, True, False), header, gen,
-                      size=len(header.raw))
-            suite = self.node.suite
-            for tx, tj in zip(block.transactions, full["transactions"]):
-                h = tx.hash(suite)
-                rtj = RawResult(tj)
-                cache.put(("tx", h), rtj, gen, size=len(rtj.raw))
-            # receipts + the per-log push fragments: the logs row carries
-            # (LogEntry, rendered bytes) pairs so the subscription fan-out
-            # does filter matching + buffer joins only — no dumps, no
-            # ledger reads on the hot path
-            log_rows: list[tuple] = []
-            log_bytes = 0
-            for ti, (rc, tx) in enumerate(zip(block.receipts,
-                                              block.transactions)):
-                h = tx.hash(suite)
-                rrc = RawResult(_receipt_json(rc, h))
-                cache.put(("rc", h), rrc, gen, size=len(rrc.raw))
-                for idx, log in enumerate(rc.logs):
-                    frag = RawResult({
-                        "address": _hex(log.address),
-                        "topics": [_hex(t) for t in log.topics],
-                        "data": _hex(log.data),
-                        "blockNumber": number,
-                        "transactionHash": _hex(h),
-                        "transactionIndex": ti,
-                        "logIndex": idx,
-                    })
-                    log_rows.append((log, frag.raw))
-                    log_bytes += len(frag.raw)
-            cache.put(("logs", number), log_rows, gen,
-                      size=log_bytes + 64)
-            # ZK proof plane: render every tx's getProof bundle (both
-            # trees' levels built once) so proof hits cost zero walks
+            entries = [(("rc", h), doc, len(doc.raw))
+                       for h, doc in receipts.items()]
+            entries.append((("logs", number), *self.log_rows(number)))
+            head = self._block_json(block, True, False)
+            header_only = RawResult(head)
+            entries.append((("block", number, True, False), header_only,
+                            len(header_only.raw)))
+            # the live txs carry the senders admission recovered: this
+            # recovers none; a block read back pays its one batch here
+            senders, _ = batch_recover_senders(block.transactions,
+                                               self.node.suite)
+            entries.append((("senders", number), senders,
+                            _senders_size(senders)))
+            txs = [RawResult(_tx_json(t, h, sender=sender))
+                   for t, h, sender in zip(block.transactions,
+                                           block.tx_hashes, senders)]
+            entries.extend((("tx", h), tj, len(tj.raw))
+                           for h, tj in zip(block.tx_hashes, txs))
+            # the three views share the header's bytes; the full one
+            # joins the tx fragments' instead of dumping them again
+            opening = header_only.raw[:-1] + b',"transactions":['
+            full = RawResult({**head, "transactions": txs},
+                             opening + b",".join(t.raw for t in txs) + b"]}")
+            entries.append((("block", number, False, False), full,
+                            len(full.raw)))
+            hexes = [tj["hash"] for tj in txs]
+            hashes_only = RawResult(
+                {**head, "transactions": hexes},
+                opening + b",".join(b'"%b"' % x.encode() for x in hexes)
+                + b"]}")
+            entries.append((("block", number, False, True), hashes_only,
+                            len(hashes_only.raw)))
+            # ZK proof plane: every tx's getProof bundle, both trees'
+            # levels built once from the hashes in hand
             zk = getattr(self.node, "zk", None)
             if zk is not None and getattr(self.node.config, "zk_proofs",
                                           True):
-                zk.prime(number, gen, cache)
+                entries.extend(zk.block_proofs(block))
+            cache.put_many(entries, frags.gen)
         except Exception:  # noqa: BLE001 — priming is best-effort
             LOG.exception(badge("RPC", "cache-prime-failed", number=number))
+        finally:
+            priming.stop()
 
     # -- ZK proof plane ----------------------------------------------------
     def get_proof(self, group: str, node_name: str = "", tx_hash: str = "",
@@ -790,7 +954,8 @@ class JsonRpcImpl:
                 gen = cache.generation() if cache is not None else None
                 doc = zkproof.render_proof_doc(ledger, h)
                 if doc is not None and cache is not None:
-                    cache.put(("proof", h), doc, gen)
+                    cache.put(("proof", h), doc, gen,
+                              size=zkproof.proof_doc_size(doc))
             if zk is not None:
                 zk.note_proof(hit)
             if doc is None:

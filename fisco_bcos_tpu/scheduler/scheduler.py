@@ -132,11 +132,14 @@ class Scheduler:
         # over wiped tables. The RPC query cache (rpc/cache.py) rides this:
         # it must be empty BEFORE any reader can observe the new state.
         self.on_invalidate: list = []
-        # number -> the committed block's live txs, for commit observers
-        # that want the sender-populated tx objects (RPC cache priming).
-        # Commits are strictly height-ordered, so an OrderedDict evicts
-        # its oldest entry in O(1) instead of re-scanning for min().
-        self.last_committed_txs: "OrderedDict[int, list]" = OrderedDict()
+        # number -> the committed block as the commit holds it, for commit
+        # observers (RPC fragment priming, the cohort's response): the
+        # LIVE txs (senders recovered at admission/verify), the receipts
+        # with their cached hashes, the tx hashes — nothing to read back,
+        # decode, recover or hash again. Commits are strictly
+        # height-ordered, so an OrderedDict evicts its oldest entry in
+        # O(1) instead of re-scanning for min().
+        self.last_committed: "OrderedDict[int, Block]" = OrderedDict()
         self._overlap_commits = 0      # 2PCs that ran while a block executed
         self._speculative_execs = 0    # executions stacked over uncommitted state
         self._exec_busy = False
@@ -632,13 +635,16 @@ class Scheduler:
         with self._lock:
             # drop any other stale executed results for this height
             self._evict_upto_locked(number)
-            # hand the committed block's LIVE txs (senders already
-            # recovered at admission/verify) to the commit observers —
-            # prime_block renders the senders row from these instead of
-            # re-recovering freshly-decoded copies
-            self.last_committed_txs[number] = result.txs
-            while len(self.last_committed_txs) > 8:
-                self.last_committed_txs.popitem(last=False)
+            # hand the committed block itself to the commit observers:
+            # the 2PC above made it durable, so whoever renders it (the
+            # notifier's prime_block, a cohort's RPC worker) starts from
+            # these objects and not from the rows just written
+            self.last_committed[number] = Block(
+                header=result.header, transactions=result.txs,
+                receipts=result.receipts,
+                tx_hashes=[t.hash(self.suite) for t in result.txs])
+            while len(self.last_committed) > 8:
+                self.last_committed.popitem(last=False)
         if self.txpool is not None:
             tx_hashes = self.ledger.tx_hashes_by_number(number)
             nonces = self.ledger.nonces_by_number(number)
@@ -664,8 +670,8 @@ class Scheduler:
             self._executed.clear()
             self._exec_heights.clear()
             # the stash refers to the pre-install chain — a same-number
-            # block on the installed chain must not reuse its senders
-            self.last_committed_txs.clear()
+            # block on the installed chain must not be rendered from it
+            self.last_committed.clear()
         # BEFORE the commit notification fans out: a reader woken by the
         # new height must never be served a pre-install cache entry
         self._fire_invalidate(number)

@@ -456,11 +456,18 @@ def configure(sample_rate: Optional[float] = None,
 # -- stages ---------------------------------------------------------------
 # one cohort's round trip through a node, from the request body in hand to
 # the response handed to the socket; a name is the aggregate's key, the
-# histogram's label and the profiler annotation at once, so `[a-z_]+`
+# histogram's label and the profiler annotation at once, so `[a-z_]+`.
+# `prime` is beside that chain, not in it: the commit observer's render of
+# the block's fragments runs on the notifier thread while `rpc_respond`
+# answers from them
 STAGES = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "gossip",
           "crypto", "round_wait", "seal_wait", "consensus_pre", "fill",
           "execute", "roots", "consensus_wait", "commit", "notify",
-          "rpc_respond")
+          "rpc_respond", "prime")
+# what the edge counts beside its stages, per cohort and never per stamp:
+# receipts a `sendTransaction` batch was answered, and those of them taken
+# from the committed block's shared fragments (rpc/server.py)
+COUNTERS = ("cohort_receipts", "cohort_receipts_shared")
 STAGE_HISTOGRAM = "bcos_tx_stage_seconds"
 # two series of the histogram are older than the stage names
 _HISTOGRAM_LABEL = {"lane_wait": "ingest", "seal_wait": "queueing"}
@@ -594,6 +601,7 @@ class StageTable:
         self.owner = owner
         self._lock = threading.Lock()
         self._agg: dict[str, list] = {n: [0, 0.0] for n in STAGES}
+        self._counts: dict[str, int] = dict.fromkeys(COUNTERS, 0)
         self._blocks: dict[int, BlockStages] = {}
 
     def stage(self, name: str, t0: Optional[float] = None) -> Stage:
@@ -640,9 +648,19 @@ class StageTable:
                     for n, r in self._agg.items()
                     if names is None or n in names}
 
+    def count(self, name: str, n: int) -> None:
+        with self._lock:
+            self._counts[name] = self._counts.get(name, 0) + n
+
+    def counters(self) -> dict:
+        """{name: total}: every name of `COUNTERS` from the start."""
+        with self._lock:
+            return dict(self._counts)
+
     def reset(self) -> None:
         with self._lock:
             self._agg = {n: [0, 0.0] for n in STAGES}
+            self._counts = dict.fromkeys(COUNTERS, 0)
             self._blocks.clear()
 
 

@@ -4,9 +4,9 @@ Three jobs:
 
   * `render_block_proofs` — at commit time (riding the PR-5 QueryCache
     prime path, off the consensus thread) build the block's tx and
-    receipt Merkle levels ONCE and cache every transaction's full
-    `getProof` response, so steady-state proof hits cost zero tree walks
-    and zero hashing.
+    receipt Merkle levels ONCE, from the leaf hashes the commit holds,
+    and render every transaction's full `getProof` response, so
+    steady-state proof hits cost zero tree walks and zero hashing.
   * `verify_inclusion_batch` — check N width-16 ledger proofs (tx,
     receipt, state-changeset) with ONE batched hash call: every level's
     node group is known up front, so the hashes are independent and the
@@ -83,38 +83,55 @@ def verify_inclusion_batch(suite, items: Sequence[tuple]) -> np.ndarray:
 
 # -- commit-time rendering ---------------------------------------------------
 
-def render_block_proofs(node, cache, number: int, gen: int) -> int:
-    """Render every tx's `getProof` response for a committed block into
-    the query cache: both trees' levels built once, receipts hashed in
-    one batch, one cache entry per tx hash. Returns entries rendered."""
-    ledger = node.ledger
-    hashes = ledger.tx_hashes_by_number(number)
-    if not hashes:
-        return 0
-    header = ledger.header_by_number(number)
-    if header is None:
-        return 0
-    receipts = [ledger.receipt(h) for h in hashes]
-    if any(rc is None for rc in receipts):
-        return 0  # raced a prune/rollback; serve on demand instead
-    from ..protocol import prefill_hashes
-    prefill_hashes(receipts, lambda rc: rc.encode(), node.suite)
-    alg = node.suite.hash_name
-    tx_levels = m.merkle_levels_host(hashes, alg)
-    rc_levels = m.merkle_levels_host([rc.hash(node.suite)
-                                      for rc in receipts], alg)
-    for i, h in enumerate(hashes):
+def _hexed_rows(levels: list[list[bytes]]) -> list[list[list[str]]]:
+    """Every proof row of a tree, hexed once: level -> group -> the
+    WIDTH sibling digests as JSON strings. The leaves under one parent
+    share its row (the list itself: proof documents are frozen)."""
+    return [[[_hex(s) for s in m.sibling_group(level, g)]
+             for g in range(-(-len(level) // m.WIDTH))]
+            for level in levels[:-1]]
+
+
+def _proof_from_rows(rows: list[list[list[str]]], index: int) -> list[dict]:
+    proof = []
+    for groups in rows:
+        group = index // m.WIDTH
+        proof.append({"siblings": groups[group], "index": index % m.WIDTH})
+        index = group
+    return proof
+
+
+def proof_doc_size(doc: dict) -> int:
+    """Cache footprint of a proof document, reckoned rather than dumped:
+    a row is WIDTH quoted 0x-digests, the envelope four more and change."""
+    rows = len(doc["txProof"]) + len(doc["receiptProof"])
+    return 320 + rows * (m.WIDTH * (2 * m.DIGEST + 5) + 28)
+
+
+def render_block_proofs(number: int, header, tx_hashes: Sequence[bytes],
+                        receipt_hashes: Sequence[bytes], alg: str) -> list:
+    """Every tx's `getProof` document for a committed block, out of the
+    leaf hashes the commit already holds: each tree's levels built once,
+    each sibling row hexed once. -> [(cache key, document, size)], for the
+    caller's one cache transaction. Same documents as `render_proof_doc`."""
+    if not tx_hashes:
+        return []
+    tx_rows = _hexed_rows(m.merkle_levels_host(list(tx_hashes), alg))
+    rc_rows = _hexed_rows(m.merkle_levels_host(list(receipt_hashes), alg))
+    txs_root, receipts_root = _hex(header.txs_root), \
+        _hex(header.receipts_root)
+    out = []
+    for i, h in enumerate(tx_hashes):
         doc = {
             "blockNumber": number,
             "txHash": _hex(h),
-            "txsRoot": _hex(header.txs_root),
-            "txProof": w16_proof_json(m.proof_from_levels(tx_levels, i)),
-            "receiptsRoot": _hex(header.receipts_root),
-            "receiptProof": w16_proof_json(
-                m.proof_from_levels(rc_levels, i)),
+            "txsRoot": txs_root,
+            "txProof": _proof_from_rows(tx_rows, i),
+            "receiptsRoot": receipts_root,
+            "receiptProof": _proof_from_rows(rc_rows, i),
         }
-        cache.put(("proof", h), doc, gen)
-    return len(hashes)
+        out.append((("proof", h), doc, proof_doc_size(doc)))
+    return out
 
 
 def render_proof_doc(ledger, tx_hash: bytes) -> Optional[dict]:
@@ -154,16 +171,27 @@ class ZkPlane:
         self._verified = 0
         self._verify_calls = 0
 
-    def prime(self, number: int, gen: int, cache) -> None:
+    def block_proofs(self, block) -> list:
+        """The committed `block`'s proof documents as cache entries
+        (`render_block_proofs`); [] where rendering them failed."""
+        suite = self.node.suite
         try:
-            n = render_block_proofs(self.node, cache, number, gen)
+            # one batch where the block came from the ledger; a commit's
+            # own receipts carry their hashes and this hashes nothing
+            from ..protocol import prefill_hashes
+            prefill_hashes(block.receipts, lambda rc: rc.encode(), suite)
+            entries = render_block_proofs(
+                block.header.number, block.header, block.tx_hashes,
+                [rc.hash(suite) for rc in block.receipts], suite.hash_name)
         except Exception:  # noqa: BLE001 — priming is best-effort
-            LOG.exception(badge("ZK", "proof-prime-failed", number=number))
-            return
-        if n:
+            LOG.exception(badge("ZK", "proof-prime-failed",
+                                number=block.header.number))
+            return []
+        if entries:
             with self._lock:
-                self._rendered += n
-            self._reg.inc("bcos_zk_proofs_rendered_total", n)
+                self._rendered += len(entries)
+            self._reg.inc("bcos_zk_proofs_rendered_total", len(entries))
+        return entries
 
     def note_proof(self, hit: bool) -> None:
         with self._lock:

@@ -358,25 +358,29 @@ def test_receipt_subscription_is_lossless_one_shot():
 # ---------------------------------------------------------------------------
 
 class _DumpsCounter:
-    """Counts json.dumps calls whose argument is a CONTAINER (fragment
-    renders); id-only dumps (ints/strings, the envelope splice) are
-    free by design and not counted."""
+    """Counts JSON encodings whose argument is a CONTAINER (fragment
+    renders), whichever door they take: `json.dumps` and the fragments'
+    own encoder (rpc/cache.encode_compact) both end in
+    `JSONEncoder.encode`. Id-only dumps (ints/strings, the envelope
+    splice) are free by design and not counted."""
 
     def __init__(self):
         self.container_calls = 0
-        self._orig = json.dumps
+        self._orig = json.JSONEncoder.encode
 
     def __enter__(self):
-        def counting(obj, *a, **k):
+        orig = self._orig
+
+        def counting(encoder, obj):
             if isinstance(obj, (dict, list, tuple)):
                 self.container_calls += 1
-            return self._orig(obj, *a, **k)
+            return orig(encoder, obj)
 
-        json.dumps = counting
+        json.JSONEncoder.encode = counting
         return self
 
     def __exit__(self, *exc):
-        json.dumps = self._orig
+        json.JSONEncoder.encode = self._orig
 
 
 def test_notification_render_cost_is_independent_of_subscriber_count():

@@ -314,14 +314,21 @@ def test_crash_between_commits_replays_to_identical_root(tmp_path):
     recovered.close()
 
 
-def test_last_committed_txs_ordered_eviction():
+def test_last_committed_ordered_eviction():
     suite, storage, ledger, pool, sched, kp = make_stack()
     for i in range(1, 11):
         r = sched.execute_block(make_block(
             i, kp, [reg_tx(suite, kp, b"e%d" % i, 1, "e%d" % i)]))
         assert sched.commit_block(r.header)
-    keys = list(sched.last_committed_txs)
+    keys = list(sched.last_committed)
     assert keys == list(range(3, 11))  # oldest evicted in commit order
+    # what the commit hands over is the block as committed: the live txs
+    # and receipts, the tx hashes, the header with the ledger's hash
+    blk = sched.last_committed[10]
+    assert blk.tx_hashes == ledger.tx_hashes_by_number(10)
+    assert [rc.encode() for rc in blk.receipts] == [
+        ledger.receipt(h).encode() for h in blk.tx_hashes]
+    assert blk.header.hash(suite) == ledger.header_by_number(10).hash(suite)
 
 
 # -- sealer busy-fill --------------------------------------------------------

@@ -10,12 +10,18 @@ request pipelining with in-order responses.
 import http.client
 import json
 import socket
+import threading
 
 import pytest
 
+from fisco_bcos_tpu.executor import precompiled as pc
 from fisco_bcos_tpu.init.node import Node, NodeConfig
 from fisco_bcos_tpu.net.websocket import ws_connect
+from fisco_bcos_tpu.protocol import Receipt, Transaction
+from fisco_bcos_tpu.rpc import server as rpc_server
 from fisco_bcos_tpu.sdk.client import SdkClient
+
+from test_eventsub import wait_until
 
 
 @pytest.fixture(scope="module")
@@ -542,3 +548,197 @@ def test_ws_shed_batch_gets_per_id_errors(batch_node, monkeypatch):
     finally:
         for _ in range(taken):
             ws._fallback.release()
+
+
+# ---------------------------------------------------------------------------
+# a cohort is answered from its committed block's shared fragments
+# ---------------------------------------------------------------------------
+
+NOTIFIER = "sched-notify"
+COHORT = 64
+
+
+def cohort_node(sm: bool, **cfg):
+    """A started solo host-crypto node with `rich` funded, its key pair
+    and the impl whose prime_block rides the commit."""
+    node = Node(NodeConfig(crypto_backend="host", sm_crypto=sm,
+                           min_seal_time=0.0, rpc_port=0, ws_port=0, **cfg))
+    node.start()
+    kp = node.suite.generate_keypair(b"cohort-client")
+    fund = Transaction(to=pc.BALANCE_ADDRESS, nonce="fund", block_limit=400,
+                       input=pc.encode_call("register", lambda w: w.blob(
+                           b"rich").u64(10 ** 6))).sign(node.suite, kp)
+    rc = node.txpool.wait_for_receipt(node.send_transaction(fund).tx_hash, 30)
+    assert rc is not None and rc.status == 0
+    return node, kp, node.rpc.impl
+
+
+def cohort_txs(node, kp, tag: str, n: int = COHORT) -> list:
+    """n signed txs for one cohort: transfers out of `rich` (a log each),
+    one register (no log) and one transfer out of nowhere (it fails: a
+    status, and a message the stored receipt does not keep)."""
+    def call(i):
+        if i == 1:
+            return pc.encode_call("register", lambda w: w.blob(
+                f"{tag}-new".encode()).u64(5))
+        src = b"nobody" if i == 2 else b"rich"
+        return pc.encode_call("transfer", lambda w: w.blob(src).blob(
+            f"{tag}-{i}".encode()).u64(1 + i % 7))
+    return [Transaction(to=pc.BALANCE_ADDRESS, nonce=f"{tag}-{i}",
+                        block_limit=400, input=call(i)).sign(node.suite, kp)
+            for i in range(n)]
+
+
+def send_cohort(node, txs, require_proof=False, ws=False) -> list:
+    """One JSON-RPC batch of sendTransaction (wait=true) -> the parsed
+    results in request order."""
+    payload = [{"jsonrpc": "2.0", "id": i, "method": "sendTransaction",
+                "params": ["group0", "", "0x" + tx.encode().hex(),
+                           require_proof, True]}
+               for i, tx in enumerate(txs)]
+    if ws:
+        conn = ws_connect(node.config.rpc_host, node.ws.port)
+        try:
+            conn.send_text(json.dumps(payload))
+            out = json.loads(conn.recv()[1])
+        finally:
+            conn.close()
+    else:
+        out = json.loads(_post_raw(node, json.dumps(payload).encode()))
+    assert [r["id"] for r in out] == list(range(len(txs))), out[:2]
+    assert all("result" in r for r in out), [r for r in out
+                                             if "result" not in r][:2]
+    return [r["result"] for r in out]
+
+
+class Tally:
+    """Who rendered, decoded and encoded what, by thread name: the three
+    things a cohort's response used to do per entry."""
+
+    def __init__(self, monkeypatch):
+        self.render, self.decode, self.encode = [], [], []
+        render, decode = rpc_server._receipt_json, Receipt.decode.__func__
+        encode = json.JSONEncoder.encode
+
+        def me():
+            return threading.current_thread().name
+
+        def counted_render(rc, h):
+            self.render.append(me())
+            return render(rc, h)
+
+        def counted_decode(cls, data):
+            self.decode.append(me())
+            return decode(cls, data)
+
+        def counted_encode(encoder, obj):
+            if isinstance(obj, (dict, list, tuple)):
+                self.encode.append(me())
+            return encode(encoder, obj)
+
+        monkeypatch.setattr(rpc_server, "_receipt_json", counted_render)
+        monkeypatch.setattr(Receipt, "decode", classmethod(counted_decode))
+        monkeypatch.setattr(json.JSONEncoder, "encode", counted_encode)
+
+    def by_workers(self, calls: list) -> int:
+        """Calls made by neither the notifier nor the test's own thread:
+        the RPC edge's."""
+        return sum(1 for name in calls
+                   if name not in (NOTIFIER, "MainThread"))
+
+
+def hold_prime(node, impl):
+    """Put a gate before the commit observer's prime_block and a flag
+    after it -> (gate, primed numbers)."""
+    gate, primed = threading.Event(), set()
+    gate.set()
+    at = node.scheduler.on_commit.index(impl.prime_block)
+
+    def held(number):
+        gate.wait(20)
+        impl.prime_block(number)
+        primed.add(number)
+
+    node.scheduler.on_commit[at] = held
+    return gate, primed
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["secp", "sm"])
+@pytest.mark.parametrize("how", ["http", "ws", "require_proof", "cache8",
+                                 "worker_first", "notifier_first"])
+def test_cohort_answers_equal_the_ledgers_render(sm, how, monkeypatch):
+    """A 64-tx cohort's sendTransaction results equal `_receipt_json` of
+    the ledger's receipts and `getTransactionReceipt` of the same hashes —
+    over both transports, with proofs, with every fragment evicted before
+    the response, and whichever of the cohort's worker and the notifier
+    reaches the committed block first (the once-guard, both orders). Each
+    receipt is rendered once, whoever needs it; the response decodes one
+    receipt a block and, once the block's fragments are there, encodes
+    nothing."""
+    proof = how == "require_proof"
+    node, kp, impl = cohort_node(
+        sm, **({"rpc_cache_entries": 8} if how == "cache8" else {}))
+    try:
+        gate, primed = hold_prime(node, impl)
+        if how == "notifier_first":
+            waited = node.txpool.wait_for_receipt
+
+            def late(h, timeout=30.0):
+                rc = waited(h, timeout)
+                if rc is not None:  # the worker arrives after the prime
+                    wait_until(lambda: rc.block_number in primed)
+                return rc
+            monkeypatch.setattr(node.txpool, "wait_for_receipt", late)
+        txs = cohort_txs(node, kp, how)
+        hashes = [tx.hash(node.suite) for tx in txs]
+        before = node.system_status()["trace"]["counters"]
+        tally = Tally(monkeypatch)
+        if how == "worker_first":
+            gate.clear()  # the notifier waits until the response is out
+        got = send_cohort(node, txs, require_proof=proof, ws=how == "ws")
+        in_response = (tally.by_workers(tally.decode),
+                       tally.by_workers(tally.encode), len(tally.render))
+        if how == "worker_first":
+            assert not primed - {1} and in_response[2] == COHORT
+        gate.set()
+        head = node.ledger.current_number()
+        assert wait_until(lambda: head in primed)
+        rendered = len(tally.render)
+        monkeypatch.undo()
+
+        # the answers: what the ledger holds, rendered the plain way
+        ledger = node.ledger
+        receipts = [ledger.receipt(h) for h in hashes]
+        blocks = {rc.block_number for rc in receipts}
+        want = [rpc_server._receipt_json(rc, h)
+                for rc, h in zip(receipts, hashes)]
+        assert [rc.status == 0 for rc in receipts].count(False) == 1
+        assert sum(len(rc.logs) for rc in receipts) == COHORT - 2
+        if proof:
+            assert all(g.pop("receiptsRoot") and g.pop("receiptProof")
+                       for g in got)
+        assert got == want
+        cli = SdkClient(f"http://{node.rpc.host}:{node.rpc.port}")
+        polled = cli.request_batch([
+            ("getTransactionReceipt", ["group0", "", "0x" + h.hex(), proof])
+            for h in hashes])
+        polled = [r["result"] for r in polled]
+        if proof:
+            assert all(p.pop("receiptsRoot") and p.pop("receiptProof")
+                       for p in polled)
+        assert polled == want
+
+        # the work: one render a receipt, shared; the edge counted it
+        assert rendered == COHORT, tally.render
+        after = node.system_status()["trace"]["counters"]
+        assert after["cohort_receipts"] - before["cohort_receipts"] == COHORT
+        assert after["cohort_receipts_shared"] \
+            - before["cohort_receipts_shared"] == COHORT
+        if not proof:  # proofs read the block back per entry, as ever
+            assert in_response[0] <= len(blocks), tally.decode
+        if how == "notifier_first":
+            assert in_response[1] == 0, tally.encode
+        elif not proof:
+            assert in_response[1] <= COHORT, tally.encode
+    finally:
+        node.stop()

@@ -13,13 +13,22 @@ per-changeset, so matching roots do not prove matching state.
 
 import http.client
 import json
+import threading
 import time
+
+import pytest
 
 from fisco_bcos_tpu.crypto.suite import make_suite
 from fisco_bcos_tpu.executor import precompiled as pc
 from fisco_bcos_tpu.init.node import Node, NodeConfig
 from fisco_bcos_tpu.protocol import Transaction
+from fisco_bcos_tpu.rpc import server as rpc_server
+from fisco_bcos_tpu.rpc.cache import QueryCache, RawResult
 from fisco_bcos_tpu.sdk.client import SdkClient
+from fisco_bcos_tpu.zk import proof as zkproof
+
+from test_rpc_batch import (COHORT, NOTIFIER, cohort_node, cohort_txs,
+                            hold_prime, send_cohort, wait_until)
 
 
 class CountingSuite:
@@ -308,8 +317,6 @@ def test_no_stale_read_after_snapshot_install():
 def test_cache_lru_and_generation_fencing():
     """Unit: LRU eviction respects the entry bound; a put carrying a
     pre-invalidation generation is dropped (in-flight render fencing)."""
-    from fisco_bcos_tpu.rpc.cache import QueryCache
-
     c = QueryCache(max_entries=2)
     g = c.generation()
     c.put("a", {"v": 1}, g)
@@ -326,3 +333,255 @@ def test_cache_lru_and_generation_fencing():
     assert c.get("e") == {"v": 5}
     stats = c.stats()
     assert stats["invalidations"] == 1 and stats["entries"] == 1
+
+
+# ---------------------------------------------------------------------------
+# the commit's one render pass (prime_block) against first-touch renders
+# ---------------------------------------------------------------------------
+
+
+def _commit_cohort(node, kp, tag: str):
+    """One cohort block through the pool's batch door (no RPC worker in
+    the way) -> (block number, tx hashes) once the prime has published."""
+    txs = cohort_txs(node, kp, tag)
+    hashes = [tx.hash(node.suite) for tx in txs]
+    node.txpool.submit_batch([Transaction.decode(tx.encode()) for tx in txs])
+    for h in hashes:
+        assert node.txpool.wait_for_receipt(h, 30) is not None
+    n = node.ledger.current_number()
+    assert {node.ledger.receipt(h).block_number for h in hashes} == {n}
+    assert wait_until(lambda: node.query_cache.get(("senders", n)))
+    return n, hashes
+
+
+def _published(cache, n: int, hashes: list) -> dict:
+    """Every fragment the prime publishes for block n, parsed; a
+    fragment's bytes and its dict say the same thing."""
+    keys = [("block", n, False, False), ("block", n, False, True),
+            ("block", n, True, False)]
+    keys += [(kind, h) for h in hashes for kind in ("tx", "rc", "proof")]
+    out = {}
+    for key in keys:
+        frag = cache.get(key)
+        assert frag is not None, key
+        if key[0] != "proof":
+            assert isinstance(frag, RawResult), key
+            assert json.loads(frag.raw) == json.loads(json.dumps(frag)), key
+        out[key] = json.loads(json.dumps(frag))
+    rows = cache.get(("logs", n))
+    out["logs"] = [(log, json.loads(raw)) for log, raw in rows]
+    out["senders"] = cache.get(("senders", n))
+    return out
+
+
+def _first_touch(node, n: int, hashes: list) -> dict:
+    """The same keys rendered the cold way: an impl with no cache, the
+    ledger's rows, `render_proof_doc`."""
+    cold = rpc_server.JsonRpcImpl(node)
+    cold.cache = None
+    out = {("block", n, h_only, tx_only): cold.get_block_by_number(
+        "group0", "", n, h_only, tx_only)
+        for h_only, tx_only in ((False, False), (False, True),
+                                (True, False))}
+    logs = []
+    for ti, h in enumerate(hashes):
+        out[("tx", h)] = cold._tx_json_cached(h)
+        out[("rc", h)] = cold._receipt_json_cached(h)
+        out[("proof", h)] = zkproof.render_proof_doc(node.ledger, h)
+        for idx, log in enumerate(node.ledger.receipt(h).logs):
+            logs.append((log, {**out[("rc", h)]["logEntries"][idx],
+                               "blockNumber": n,
+                               "transactionHash": "0x" + h.hex(),
+                               "transactionIndex": ti, "logIndex": idx}))
+    out = json.loads(json.dumps({repr(k): v for k, v in out.items()}))
+    out["logs"] = logs
+    out["senders"] = [tx.sender(node.suite) for tx in (
+        node.ledger.transaction(h) for h in hashes)]
+    return out
+
+
+class CountingCrypto(CountingSuite):
+    """Also counts batch hashes, and says which thread asked."""
+
+    def __init__(self, suite):
+        super().__init__(suite)
+        self.calls = []
+
+    def recover_addresses(self, hashes, sigs):
+        self.calls.append(("recover", threading.current_thread().name))
+        return super().recover_addresses(hashes, sigs)
+
+    def hash_batch(self, msgs):
+        self.calls.append(("hash_batch", threading.current_thread().name))
+        return self._suite.hash_batch(msgs)
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["secp", "sm"])
+def test_prime_publishes_what_first_touch_renders(sm):
+    """After one commit every published fragment — the three block views,
+    each tx, each receipt, the logs row, each proof document, the senders
+    row — equals the first-touch render of the same key on a cold cache."""
+    node, kp, _impl = cohort_node(sm)
+    try:
+        n, hashes = _commit_cohort(node, kp, "par")
+        assert hashes == node.ledger.tx_hashes_by_number(n)
+        got = _published(node.query_cache, n, hashes)
+        want = _first_touch(node, n, hashes)
+        assert len(got["logs"]) == COHORT - 2
+        assert got.pop("logs") == want.pop("logs")
+        assert got.pop("senders") == want.pop("senders")
+        assert {repr(k): v for k, v in got.items()} == want
+        full = got[("block", n, False, False)]
+        assert [t["hash"] for t in full["transactions"]] \
+            == got[("block", n, False, True)]["transactions"]
+        assert all(t.get("from") for t in full["transactions"])
+    finally:
+        node.stop()
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["secp", "sm"])
+def test_prime_hashes_and_recovers_nothing(sm):
+    """submit -> commit -> prime costs no hash_batch and no recover beyond
+    admission's and execution's: the notifier thread, which renders the
+    block from what the commit holds, asks the suite for neither, and an
+    RPC cohort's worker neither."""
+    counting = CountingCrypto(make_suite(sm, backend="host"))
+    node = Node(NodeConfig(crypto_backend="host", sm_crypto=sm,
+                           min_seal_time=0.0, rpc_port=0), suite=counting)
+    node.start()
+    try:
+        kp = counting.generate_keypair(b"cohort-client")
+        impl = node.rpc.impl
+        _gate, primed = hold_prime(node, impl)
+        send_cohort(node, cohort_txs(node, kp, "cnt")[3:])
+        head = node.ledger.current_number()
+        assert wait_until(lambda: head in primed)
+        assert node.query_cache.get(("proof", node.ledger.
+                                     tx_hashes_by_number(head)[0]))
+        assert counting.calls, "the instrument saw nothing"
+        off_path = [c for c in counting.calls
+                    if c[1] == NOTIFIER or c[1].startswith("rpc")]
+        assert not off_path, off_path
+    finally:
+        node.stop()
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["secp", "sm"])
+def test_stash_miss_takes_the_ledger_path(sm):
+    """Restart, sync replay, snapshot install: no stash. The prime reads
+    the block back and publishes the same fragments."""
+    node, kp, impl = cohort_node(sm)
+    try:
+        n, hashes = _commit_cohort(node, kp, "miss")
+        from_stash = _published(node.query_cache, n, hashes)
+        node.scheduler.last_committed.clear()
+        impl._frags.clear()
+        node.query_cache.invalidate()
+        assert node.query_cache.get(("rc", hashes[0])) is None
+        impl.prime_block(n)
+        from_ledger = _published(node.query_cache, n, hashes)
+        assert from_ledger == from_stash
+        # and a stash that is another chain's block is not believed
+        node.scheduler.last_committed[n] = node.scheduler.last_committed.get(
+            n - 1) or node.ledger.block_by_number(n - 1)
+        impl._frags.clear()
+        node.query_cache.invalidate()
+        impl.prime_block(n)
+        assert _published(node.query_cache, n, hashes) == from_stash
+    finally:
+        node.stop()
+
+
+@pytest.mark.parametrize("runner", ["notifier", "worker"])
+def test_a_pass_that_raced_an_invalidation_inserts_nothing(runner):
+    """The generation is captured before the block's first read; an
+    invalidation during the pass voids all of it — also the receipts a
+    cohort's worker rendered before the notifier took the table over."""
+    node, kp, impl = cohort_node(False)
+    try:
+        cache = node.query_cache
+        gate, primed = hold_prime(node, impl)
+        rows = impl.log_rows
+
+        def raced(number):
+            cache.invalidate()  # a rollback lands mid-pass
+            return rows(number)
+
+        impl.log_rows = raced
+        txs = cohort_txs(node, kp, "race")
+        hashes = [tx.hash(node.suite) for tx in txs]
+        if runner == "worker":
+            gate.clear()
+            got = send_cohort(node, txs)  # the worker renders the receipts
+            assert len(got) == COHORT and not primed - {1}
+            gate.set()
+        else:
+            node.txpool.submit_batch(txs)
+            for h in hashes:
+                assert node.txpool.wait_for_receipt(h, 30) is not None
+        head = node.ledger.current_number()
+        assert wait_until(lambda: head in primed)
+        assert cache.stats()["entries"] == 0, cache.stats()
+        assert all(cache.get((kind, h)) is None for h in hashes
+                   for kind in ("rc", "tx", "proof"))
+        # the next pass, under the new generation, publishes
+        impl.log_rows = rows
+        impl.prime_block(head)
+        assert cache.get(("rc", hashes[-1])) is not None
+    finally:
+        node.stop()
+
+
+def test_put_many_is_one_fenced_transaction():
+    reg_calls = []
+
+    class Reg:
+        def inc(self, *a, **k):
+            pass
+
+        def set_gauge(self, name, value, **k):
+            reg_calls.append(name)
+
+    c = QueryCache(max_entries=3, registry=Reg())
+    g = c.generation()
+    assert c.put_many([(k, {"v": k}, 10) for k in "abcd"], g) is True
+    assert len(reg_calls) == 2, reg_calls  # one update of each gauge
+    assert c.get("a") is None and c.get("d") == {"v": "d"}  # LRU bound
+    assert c.stats()["bytes"] == 30
+    c.invalidate()
+    assert c.put_many([("e", {"v": 5}, 10)], g) is False
+    assert c.get("e") is None and c.stats()["entries"] == 0
+
+
+def test_once_guard_builds_a_part_once_under_contention():
+    """More threads than cores ask one table for the same part at once,
+    under a shortened switch interval: one build, one object for all."""
+    import sys
+
+    frags = rpc_server._BlockFragments(7, gen=0)
+    builds, got, start = [], [], threading.Event()
+
+    def build():
+        builds.append(threading.current_thread().name)
+        time.sleep(0.01)  # long enough for every thread to pile up
+        return {"built": len(builds)}
+
+    def ask():
+        start.wait(5)
+        got.append(frags.once("receipts", build))
+
+    threads = [threading.Thread(target=ask) for _ in range(32)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        start.set()
+        for t in threads:
+            t.join(10)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert len(builds) == 1 and len(got) == 32
+    assert all(g is got[0] for g in got) and got[0] == {"built": 1}
+    assert frags.peek("receipts") is got[0] and frags.peek("logs") is None

@@ -6,19 +6,24 @@ proofs anchored at header.state_root, the verifyProofs batched RPC, and
 the crypto lane's poseidon op (two concurrent callers merge into ONE
 base-suite call)."""
 
+import json
 import threading
 import time
 
 import numpy as np
+import pytest
 
 from fisco_bcos_tpu.crypto.lane import CryptoLane, LaneSuite
 from fisco_bcos_tpu.crypto.suite import make_suite
 from fisco_bcos_tpu.executor import precompiled as pc
 from fisco_bcos_tpu.executor.executor import state_leaf_payload
 from fisco_bcos_tpu.init.node import Node, NodeConfig
+from fisco_bcos_tpu.ops import merkle
 from fisco_bcos_tpu.protocol import Transaction
 from fisco_bcos_tpu.zk import poseidon as zp
 from fisco_bcos_tpu.zk import proof as zkproof
+
+from test_rpc_batch import cohort_node, cohort_txs, wait_until
 
 
 def _unhex(s):
@@ -253,3 +258,75 @@ def test_lane_merges_poseidon_batches():
     finally:
         base.poseidon_batch = orig
         lane.stop()
+
+
+# ---------------------------------------------------------------------------
+# proof bundles from shared rows: the same documents, the same walks
+# ---------------------------------------------------------------------------
+
+
+def _copying_walk(levels, index):
+    """The walk `proof_from_levels` replaced: pad a copy of every level
+    for every leaf."""
+    proof, idx = [], index
+    for level in levels[:-1]:
+        cur = list(level)
+        while len(cur) % merkle.WIDTH:
+            cur.append(b"\x00" * merkle.DIGEST)
+        group = idx // merkle.WIDTH
+        proof.append((cur[group * merkle.WIDTH:(group + 1) * merkle.WIDTH],
+                      idx % merkle.WIDTH))
+        idx = group
+    return proof
+
+
+@pytest.mark.parametrize("alg", ["keccak256", "sm3"])
+@pytest.mark.parametrize("n", [1, 2, 16, 17, 255, 256, 257, 1000])
+def test_proof_from_levels_slices_what_the_copying_walk_padded(n, alg):
+    leaves = [bytes([i % 251, i // 251]) * 16 for i in range(n)]
+    levels = merkle.merkle_levels_host(leaves, alg)
+    root = levels[-1][0]
+    for i in sorted({0, n // 3, n - 1}):
+        proof = merkle.proof_from_levels(levels, i)
+        assert proof == _copying_walk(levels, i)
+        assert merkle.verify_merkle_proof(leaves[i], proof, root, alg)
+    before = [list(level) for level in levels]
+    merkle.proof_from_levels(levels, n - 1)
+    assert levels == before  # a padded row is the proof's, not the level's
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["secp", "sm"])
+@pytest.mark.parametrize("n", [1, 17, 300])
+def test_primed_proof_documents_equal_render_proof_doc(n, sm):
+    """Zero, one and two real levels above the leaves: every document the
+    commit renders from shared rows is `render_proof_doc`'s, verifies
+    against the header's roots, and is sized without being dumped."""
+    node, kp, impl = cohort_node(sm, tx_count_limit=1000)
+    try:
+        txs = cohort_txs(node, kp, f"doc{n}", n)
+        node.txpool.submit_batch(txs)
+        hashes = [tx.hash(node.suite) for tx in txs]
+        for h in hashes:
+            assert node.txpool.wait_for_receipt(h, 30) is not None
+        assert wait_until(lambda: impl.cache.get(("proof", hashes[-1])))
+        suite = node.suite
+        for h in hashes:
+            doc = impl.cache.get(("proof", h))
+            assert doc == zkproof.render_proof_doc(node.ledger, h)
+            assert abs(zkproof.proof_doc_size(doc) - len(json.dumps(
+                doc, separators=(",", ":")))) <= 64
+        items = []
+        for h in (hashes[0], hashes[n // 2], hashes[-1]):
+            doc = impl.get_proof("group0", tx_hash="0x" + h.hex())
+            assert doc["found"]
+            header = node.ledger.header_by_number(doc["blockNumber"])
+            assert _unhex(doc["txsRoot"]) == header.txs_root
+            items.append((h, zkproof.w16_proof_from_json(doc["txProof"]),
+                          header.txs_root))
+            items.append((node.ledger.receipt(h).hash(suite),
+                          zkproof.w16_proof_from_json(doc["receiptProof"]),
+                          header.receipts_root))
+        assert zkproof.verify_inclusion_batch(suite, items).all()
+        assert node.zk.stats()["proofsRendered"] >= n
+    finally:
+        node.stop()
