@@ -95,6 +95,22 @@ class _ProposalCache:
         self.t_accept: float = 0.0
 
 
+# the block size `view_timeout` is meant for: upstream's genesis default
+VIEW_TIMEOUT_BLOCK_TXS = 1000
+
+
+def round_allowance(view_timeout: float, block_tx_count_limit: int) -> float:
+    """How long a round may go without a decided block before this node
+    asks for a view change. A round's work — the leader's admission of the
+    gossiped transactions, proposal verification, execution, the roots,
+    the commit — grows with the block's transactions, so the allowance is
+    `view_timeout` per VIEW_TIMEOUT_BLOCK_TXS of the chain's
+    `tx_count_limit` (the ledger's system config, the same on every
+    node), and never less than `view_timeout`."""
+    return view_timeout * max(
+        1.0, block_tx_count_limit / VIEW_TIMEOUT_BLOCK_TXS)
+
+
 class PBFTEngine(Worker):
     def __init__(self, suite, keypair, front: FrontService, txpool, sealer,
                  scheduler, ledger, leader_period: int = 1,
@@ -145,7 +161,10 @@ class PBFTEngine(Worker):
         # (TxPool.cpp:160 fetch-missing). True: ship full txs in-band.
         self.full_proposals = full_proposals
         self.leader_period = max(1, leader_period)
-        self.base_timeout = view_timeout
+        # `view_timeout` is what a round of upstream's default block
+        # (VIEW_TIMEOUT_BLOCK_TXS) may take; `base_timeout` follows the
+        # chain's own block size (_grant_sealer)
+        self.view_timeout = view_timeout
         # proposal pipeline depth: consensus runs for heights in
         # (committed, committed + waterline] concurrently, execution stays
         # strictly in order — the reference's water-size window
@@ -154,6 +173,8 @@ class PBFTEngine(Worker):
         self.waterline = max(1, waterline)
 
         cfg = ledger.ledger_config()
+        self.base_timeout = round_allowance(view_timeout,
+                                            cfg.block_tx_count_limit)
         self.nodes: list[bytes] = sorted(n.node_id for n in cfg.consensus_nodes)
         self.index = self.nodes.index(keypair.pub_bytes)
         self.n = len(self.nodes)
@@ -183,7 +204,7 @@ class PBFTEngine(Worker):
         self._viewchanges: dict[int, dict[int, PBFTMessage]] = {}
         self._inbox: "queue.Queue[tuple[str, object]]" = queue.Queue()
         self._deadline = 0.0
-        self._timeout = view_timeout
+        self._timeout = self.base_timeout
         self._committed_waiters: list = []
         # heights whose checkpoint quorum landed this drain — their seals
         # are judged TOGETHER at the end of the worker pass (one lane call
@@ -300,6 +321,10 @@ class PBFTEngine(Worker):
     def _grant_sealer(self) -> None:
         cfg = self.ledger.ledger_config()
         self._reload_membership(cfg)
+        # a governance change of tx_count_limit holds from the next
+        # reset of the timer (every commit makes one)
+        self.base_timeout = round_allowance(self.view_timeout,
+                                            cfg.block_tx_count_limit)
         self._maybe_grant(self.ledger.current_number() + 1, cfg)
 
     def _maybe_grant(self, number: int, cfg=None) -> None:

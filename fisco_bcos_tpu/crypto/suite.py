@@ -21,9 +21,10 @@ golden-value requirement).
 
 The seam says where each call ran: `status()` carries the backend as
 configured, the platform JAX resolved, per-op device/host call and item
-counts, the host's seconds around each device call (packing the arguments,
-the call until every output is numpy, unpacking them into the values
-returned) and the process's compile count —
+counts, the lanes the device calls were issued with (padding to the
+bucket included), the host's seconds around each device call (packing the
+arguments, the call until every output is numpy, unpacking them into the
+values returned) and the process's compile count —
 `Node.system_status()["crypto"]`.
 
 Signing stays host-side and single-item: a node signs only its own messages
@@ -214,9 +215,10 @@ class CryptoSuite:
         # 1,000 invalid signatures and a chain that simply stops
         self.on_device_error: list[Callable[[str], None]] = []
         self._stats_lock = threading.Lock()
-        # per op: device calls/items, host calls/items, and the device
-        # calls' cumulative pack/call/unpack seconds
-        self._stats = {op: [0, 0, 0, 0, 0.0, 0.0, 0.0] for op in _OPS}
+        # per op: device calls/items, host calls/items, the device
+        # calls' cumulative pack/call/unpack seconds, and the lanes they
+        # were issued with (items plus the padding to their buckets)
+        self._stats = {op: [0, 0, 0, 0, 0.0, 0.0, 0.0, 0] for op in _OPS}
         self._ready: dict | None = None  # set by prepare()
         from . import nativehash
 
@@ -278,13 +280,15 @@ class CryptoSuite:
             row[2] += 1
             row[3] += n
 
-    def _on_device(self, op: str, n: int, t_in: float, call, unpack=None):
-        """Run one device-path call, packed since `t_in`: `call()` issues
-        the kernel and returns its outputs as numpy, `unpack(outputs)`
-        makes the values the caller returns. Inputs were validated and
-        packed on the host, so whatever `call` raises is a compile,
-        lowering or device failure: wrapped as DeviceError and reported to
-        the observers. One clock read per boundary, nothing per item."""
+    def _on_device(self, op: str, n: int, lanes: int, t_in: float, call,
+                   unpack=None):
+        """Run one device-path call of `n` items in `lanes` lanes, packed
+        since `t_in`: `call()` issues the kernel and returns its outputs
+        as numpy, `unpack(outputs)` makes the values the caller returns.
+        Inputs were validated and packed on the host, so whatever `call`
+        raises is a compile, lowering or device failure: wrapped as
+        DeviceError and reported to the observers. One clock read per
+        boundary, nothing per item."""
         t_call = time.monotonic()
         try:
             out = call()
@@ -306,6 +310,7 @@ class CryptoSuite:
             row[4] += t_call - t_in
             row[5] += t_out - t_call
             row[6] += t_done - t_out
+            row[7] += lanes
         return out
 
     def status(self) -> dict:
@@ -315,6 +320,7 @@ class CryptoSuite:
         plat = platform.resolve() if self._device_ok is not None else None
         with self._stats_lock:
             ops_ = {op: {"deviceCalls": r[0], "deviceItems": r[1],
+                         "deviceLanes": r[7],
                          "hostCalls": r[2], "hostItems": r[3],
                          "packSeconds": r[4], "callSeconds": r[5],
                          "unpackSeconds": r[6]}
@@ -403,11 +409,12 @@ class CryptoSuite:
                 out[i] = d
         for o, ln in _chunks(len(small)):
             idx = small[o:o + ln]
+            bucket = _bucket(ln)
             blocks, nvalid = keccak.pack_batch_np(
-                [msgs[i] for i in idx], pad, block, _bucket(ln),
+                [msgs[i] for i in idx], pad, block, bucket,
                 _pow2(max(nblk[i] for i in idx)))
             digests = self._on_device(
-                "hash", ln, t_in,
+                "hash", ln, bucket, t_in,
                 lambda: np.asarray(kernel(blocks, nvalid)),
                 lambda rows: [bytes(row) for row in rows[:ln]])
             for i, d in zip(idx, digests):
@@ -437,7 +444,7 @@ class CryptoSuite:
         from ..zk import poseidon_jax
 
         return self._on_device(
-            "poseidon", n, time.monotonic(),
+            "poseidon", n, n, time.monotonic(),
             lambda: poseidon_jax.hash2_batch(lefts, rights))
 
     def merkle_root(self, leaves: Sequence[bytes]) -> bytes:
@@ -462,7 +469,7 @@ class CryptoSuite:
             root = merkle.merkle_root_padded
         arr = _pad_rows(arr, bucket)
         return self._on_device(
-            "merkle", n, t_in,
+            "merkle", n, bucket, t_in,
             lambda: np.asarray(root(arr, np.int32(n), self.hash_name)),
             bytes)
 
@@ -539,6 +546,11 @@ class CryptoSuite:
         return [(ln, [_pad_rows(a[o:o + ln], CHUNK) for a in cols])
                 for o, ln in _chunks(n)]
 
+    @staticmethod
+    def _lanes(chunks: list) -> int:
+        """Lanes `_pad_chunks`' sets issue: the sum of their buckets."""
+        return sum(padded[0].shape[0] for _ln, padded in chunks)
+
     def _run_chunks(self, fn, chunks: list) -> list:
         """Run kernel `fn(curve, *columns)` over `_pad_chunks`' sets, all
         issued before the first output is fetched (jax's async dispatch
@@ -593,7 +605,8 @@ class CryptoSuite:
         chunks = self._pad_chunks(n, [bigint.batch_to_limbs(c)
                                       for c in (es, rs, ss, qx, qy)])
         return self._on_device(
-            "verify", n, t_in, lambda: self._run_chunks(fn, chunks))[0]
+            "verify", n, self._lanes(chunks), t_in,
+            lambda: self._run_chunks(fn, chunks))[0]
 
     def recover_batch(self, digests: Sequence[bytes], sigs: Sequence[bytes]
                       ) -> tuple[list[bytes | None], np.ndarray]:
@@ -665,8 +678,8 @@ class CryptoSuite:
                     if ok[i] else None for i in range(n)], ok
 
         return self._on_device(
-            "recover", n, t_in, lambda: self._run_chunks(rec, chunks),
-            pub_bytes)
+            "recover", n, self._lanes(chunks), t_in,
+            lambda: self._run_chunks(rec, chunks), pub_bytes)
 
     def recover_addresses(self, digests: Sequence[bytes], sigs: Sequence[bytes]
                           ) -> tuple[list[bytes | None], np.ndarray]:

@@ -26,7 +26,7 @@ from ..ledger.ledger import ConsensusNode, Ledger
 from ..protocol import Block
 from ..scheduler.scheduler import Scheduler
 from ..sealer.sealer import Sealer
-from ..txpool.ingest import IngestLane
+from ..txpool.ingest import IngestLane, lane_limits
 from ..txpool.txpool import TxPool
 from ..utils.log import LOG, badge
 from ..consensus import qc
@@ -99,10 +99,10 @@ class NodeConfig:
     # concurrent RPC/gossip submissions into device-sized submit_batch
     # calls. ingest_lane=False restores direct per-call submission (the
     # per-request baseline, kept for benchmarking and odd embeddings).
+    # The largest batch a dispatch takes and the queue's capacity follow
+    # from tx_count_limit and txpool_limit (ingest.lane_limits).
     ingest_lane: bool = True
-    ingest_max_batch: int = 4096
     ingest_max_wait_ms: float = 15.0
-    ingest_queue_cap: int = 8192
     min_seal_time: float = 0.05
     # busy-pipeline fill ceiling: while a block is executing/committing the
     # sealer keeps filling the next proposal up to this long (bigger DAG
@@ -313,10 +313,13 @@ class Node:
                              high_watermark=cfg.txpool_high_watermark,
                              priority_bands=cfg.txpool_priority_bands,
                              trace_label=self.trace_label)
+        # also the largest batch prepare() warms shapes for (start)
+        self.lane_batch, lane_cap = lane_limits(cfg.tx_count_limit,
+                                                cfg.txpool_limit)
         self.ingest = IngestLane(
-            self.txpool, max_batch=cfg.ingest_max_batch,
+            self.txpool, max_batch=self.lane_batch,
             max_wait_ms=cfg.ingest_max_wait_ms,
-            queue_cap=cfg.ingest_queue_cap,
+            queue_cap=lane_cap,
             registry=self.metrics_view,
             trace_label=self.trace_label) if cfg.ingest_lane else None
         # overload controller (utils/overload.py): one busy/brownout state
@@ -616,8 +619,7 @@ class Node:
         # node will use BEFORE it seals or opens RPC (a first 1,000-tx
         # batch compiling under a 3 s view timeout is a view change); a
         # `device` node without a TPU refuses to start here
-        self.suite.prepare(max(self.config.ingest_max_batch,
-                               self.config.tx_count_limit))
+        self.suite.prepare(self.lane_batch)
         if self.ledger.current_number() < 0:
             self.build_genesis()
         self._started = True

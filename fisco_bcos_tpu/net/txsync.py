@@ -85,6 +85,7 @@ class TransactionSync(Worker):
         self._reg = registry if registry is not None else REGISTRY
         self.anti_entropy_interval = anti_entropy_interval
         self._last_sweep = 0.0
+        self._swept: set[bytes] = set()  # unsealed at the last sweep
         self._lock = threading.Lock()
         self._known_by_peer: dict[bytes, set[bytes]] = {}
         front.register_module(ModuleID.TxsSync, self._on_message)
@@ -96,7 +97,16 @@ class TransactionSync(Worker):
         if now - self._last_sweep < self.anti_entropy_interval:
             return
         self._last_sweep = now
-        pending = self.txpool.pending_txs(self.ANTI_ENTROPY_MAX)
+        # only what was already waiting at the last sweep: a transaction
+        # admitted since then is still on its way by ordinary gossip, and
+        # re-advertised beside it the sweep's small frame overtakes the
+        # cohort's large one — a leader then seals the sweep's 256 as a
+        # block of their own before the other 9,744 have arrived
+        unsealed = self.txpool.pending_txs()
+        hashes = [t.hash(self.suite) for t in unsealed]
+        pending = [t for t, h in zip(unsealed, hashes)
+                   if h in self._swept][:self.ANTI_ENTROPY_MAX]
+        self._swept = set(hashes)
         if not pending:
             return
         # deliberately ignores _known_by_peer: that cache is optimistic

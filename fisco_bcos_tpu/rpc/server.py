@@ -420,11 +420,26 @@ class JsonRpcImpl:
                                     and health.writes_shed()):
             from ..txpool.ingest import LaneStopped, TxPoolIsFull
             self.edge_stages.frames_handed_over()
+            # whole: one piece in the lane's queue that one dispatch can
+            # take, so one admission, one recover and (up to the chain's
+            # tx_count_limit) one block
+            whole = len(frames) <= lane.max_batch
             try:
                 tasks = dict(zip(frames, lane.submit_wire_cohort(
                     list(frames.values()))))
-            except (TxPoolIsFull, LaneStopped):
-                pass  # each entry meets the same condition on its own
+            except (TxPoolIsFull, LaneStopped) as exc:
+                # each entry meets the same condition on its own, one
+                # round trip after the other: said, not hidden
+                whole = False
+                LOG.warning(badge("RPC", "cohort-refused", n=len(frames),
+                                  lane_batch=lane.max_batch,
+                                  reason=repr(exc)))
+            else:
+                if not whole:
+                    LOG.warning(badge("RPC", "cohort-split", n=len(frames),
+                                      lane_batch=lane.max_batch))
+            self._stages.count("cohorts", 1)
+            self._stages.count("cohorts_whole", int(whole))
         tl = self._tl
         tl.cohort, tl.frags, tl.answered, tl.shared = tasks, None, 0, 0
         try:

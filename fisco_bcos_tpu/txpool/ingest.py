@@ -52,6 +52,26 @@ from ..crypto.suite import BUCKETS as _SUITE_BUCKETS
 # compiled-executable grid (1 prepended: a lone idle tx is its own batch)
 _SIZE_BUCKETS = (1,) + tuple(_SUITE_BUCKETS)
 
+# the most independent submissions one dispatch coalesces on a chain whose
+# blocks are smaller than this: the suite's 4096 bucket, one device call
+COALESCE_BATCH = 4096
+
+
+def lane_limits(tx_count_limit: int, txpool_limit: int) -> tuple[int, int]:
+    """-> (max_batch, queue_cap) of a node's lane, from what the chain and
+    the pool say; neither is a knob.
+
+    One dispatch hands `admit` at most a full block (a client's batch of
+    up to `tx_count_limit` reaches the pool, the recover and the sealer
+    as one piece), and on a chain of small blocks up to COALESCE_BATCH,
+    as far as the pool has room beside a sealed block that waits for its
+    commit. The queue holds one batch more than the one in admission.
+    tx_count_limit 1000 under the pool's default 15000 gives 4096 and
+    8192, the constants these were."""
+    max_batch = max(1, tx_count_limit,
+                    min(COALESCE_BATCH, txpool_limit - tx_count_limit))
+    return max_batch, 2 * max_batch
+
 
 class TxPoolIsFull(RuntimeError):
     """Ingest queue at capacity — backpressure, not an internal error.

@@ -466,8 +466,10 @@ STAGES = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "gossip",
           "rpc_respond", "prime")
 # what the edge counts beside its stages, per cohort and never per stamp:
 # receipts a `sendTransaction` batch was answered, and those of them taken
-# from the committed block's shared fragments (rpc/server.py)
-COUNTERS = ("cohort_receipts", "cohort_receipts_shared")
+# from the committed block's shared fragments; batches of `sendTransaction`
+# received, and those the lane took as one piece (rpc/server.py)
+COUNTERS = ("cohort_receipts", "cohort_receipts_shared", "cohorts",
+            "cohorts_whole")
 STAGE_HISTOGRAM = "bcos_tx_stage_seconds"
 # two series of the histogram are older than the stage names
 _HISTOGRAM_LABEL = {"lane_wait": "ingest", "seal_wait": "queueing"}

@@ -59,6 +59,7 @@ def test_auto_without_a_tpu_stays_on_the_host():
     st = suite.status()
     assert st["platform"] == "cpu" and st["pallas"] == "off"
     assert st["ops"]["recover"] == {"deviceCalls": 0, "deviceItems": 0,
+                                    "deviceLanes": 0,
                                     "hostCalls": 1, "hostItems": 1000,
                                     "packSeconds": 0.0, "callSeconds": 0.0,
                                     "unpackSeconds": 0.0}

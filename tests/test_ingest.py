@@ -352,8 +352,8 @@ def test_node_send_transaction_contract_survives_lane_conditions():
     TXPOOL_FULL status, a stopped lane falls back to the direct pool."""
     from fisco_bcos_tpu.init.node import Node, NodeConfig
 
-    node = Node(NodeConfig(crypto_backend="host", min_seal_time=0.0,
-                           ingest_queue_cap=1))
+    node = Node(NodeConfig(crypto_backend="host", min_seal_time=0.0))
+    node.ingest.queue_cap = 1  # follows from the limits; a test's to cut
     node.start()
     try:
         kp = node.suite.generate_keypair(b"contract")

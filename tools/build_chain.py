@@ -13,7 +13,8 @@ BcosBuilder Pro/Max deployers. Output layout:
 Usage:
     python tools/build_chain.py -n 4 -o /tmp/mychain [--sm] \
         [--consensus pbft] [--rpc-base-port 20200] [--encrypt-key PASS] \
-        [--crypto-backend auto,host,host,host]
+        [--crypto-backend auto,host,host,host] \
+        [--block-tx-count-limit 1000]
 
 Boot a generated node in-process:
     from fisco_bcos_tpu.tool import load_node
@@ -60,7 +61,10 @@ def build_chain(out_dir: str, n_nodes: int, sm_crypto: bool = False,
                 sm_tls: bool = False,
                 p2p_base_port: int | None = None,
                 p2p_ports: list[int] | None = None,
-                host: str = "127.0.0.1") -> dict:
+                host: str = "127.0.0.1",
+                block_tx_count_limit: int = 1000) -> dict:
+    if block_tx_count_limit < 1:
+        raise ValueError("block_tx_count_limit must be >= 1")
     # one value for every node, or one per node ("auto,host,host,host"): a
     # chip belongs to one process at a time, so on a one-chip host exactly
     # one daemon may be anything but `host`
@@ -76,6 +80,7 @@ def build_chain(out_dir: str, n_nodes: int, sm_crypto: bool = False,
     keypairs = [suite.generate_keypair() for _ in range(n_nodes)]
     chain = ChainConfig(chain_id=chain_id, group_id=group_id,
                         sm_crypto=sm_crypto, consensus_type=consensus,
+                        block_tx_count_limit=block_tx_count_limit,
                         sealers=[kp.pub_bytes for kp in keypairs])
     ca = None
     if sm_tls:
@@ -96,6 +101,9 @@ def build_chain(out_dir: str, n_nodes: int, sm_crypto: bool = False,
         cfg = NodeConfig(
             chain_id=chain_id, group_id=group_id, sm_crypto=sm_crypto,
             storage_path="data", consensus=consensus,
+            # config.ini repeats what genesis says; genesis is what a
+            # node obeys (tool/config.py _load_node_parts)
+            tx_count_limit=block_tx_count_limit,
             storage_backend=storage_backend,
             crypto_backend=backends[i],
             rpc_port=(rpc_base_port + i) if rpc_base_port is not None else None,
@@ -181,6 +189,13 @@ def main() -> None:
                          "for all nodes or a comma list, one per node "
                          "(one chip serves one process: on a one-chip "
                          "host give node0 the chip and the rest `host`)")
+    ap.add_argument("--block-tx-count-limit", type=int, default=1000,
+                    help="genesis [consensus] block_tx_count_limit: the "
+                         "most transactions a block may hold (the ledger's "
+                         "system config tx_count_limit). The ingest lane's "
+                         "batch and queue sizes and the crypto shapes a "
+                         "device node compiles follow from it; keep it "
+                         "under [txpool] limit")
     ap.add_argument("--encrypt-key", default=None,
                     help="passphrase to encrypt node keys at rest")
     ap.add_argument("--mode", default="air", choices=["air", "max"],
@@ -196,6 +211,7 @@ def main() -> None:
         p2p_base_port=args.p2p_base_port,
         metrics_base_port=args.metrics_base_port, sm_tls=args.sm_tls,
         storage_backend=args.storage, crypto_backend=args.crypto_backend,
+        block_tx_count_limit=args.block_tx_count_limit,
         encrypt_passphrase=args.encrypt_key.encode() if args.encrypt_key else None)
     if args.mode == "max":
         info["max_cluster"] = build_max_cluster(
