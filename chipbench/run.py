@@ -310,6 +310,8 @@ def run_cell(args, cell, config, traffic, e2e, per_layer, workdir,
         "height_after": obs.snap["after"]["status"]["0"]["blockNumber"],
         "trace": trace,
         "hash_name": "sm3" if config["sm_crypto"] else "keccak256",
+        "presign_batches": {"signed": load.window_signed,
+                            "taken": load.window_taken},
     }
     if trace is not None and not rehearse:
         ev["trace_status"] = {
@@ -330,7 +332,8 @@ def run_cell(args, cell, config, traffic, e2e, per_layer, workdir,
         f"client={json.dumps(client)} slowest {1000 * max(lat):.0f} ms, "
         f"last receipt {t_last - t0:.3f} s, "
         f"gather {t_gather:.1f} s judge {t_judge:.1f} s "
-        f"height {ans['height']}")
+        f"height {ev['height_before']} -> {ev['height_after']} over the "
+        f"window, {ans['height']} at the end")
     if controls is not None:
         for name, failed_by in controls.items():
             log(f"control {name}: fails {failed_by or 'NOTHING'}")

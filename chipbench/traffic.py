@@ -45,6 +45,9 @@ class Load:
         self.port = cluster.port(0)
         self.requests: list[Req] = []
         self.exhausted = False   # ran out of signed transactions
+        # batches signed ahead for the window and those it took; a loop
+        # that signs as it goes has neither
+        self.window_signed = self.window_taken = None
         self.height = 0          # highest block number seen in a receipt
         self.t0 = self.t1 = 0.0  # the measured window
         self._lock = threading.Lock()
@@ -85,11 +88,12 @@ class ClosedBatch(Load):
 
     def presign(self) -> None:
         p = self.p
-        n = int(p["presign_tx_per_s"] * self.seconds) \
-            + p["senders"] * p["batch"] * p["warmup_batches_per_sender"]
-        for _ in range(-(-n // p["batch"]) * p["batch"]):
+        window = -(-int(p["presign_tx_per_s"] * self.seconds) // p["batch"])
+        warm = p["senders"] * p["warmup_batches_per_sender"]
+        for _ in range((window + warm) * p["batch"]):
             self._sign(False)
         self._cursor = 0
+        self.window_signed, self.window_taken = window, 0
 
     def _take(self, measured: bool) -> list[Req] | None:
         with self._lock:
@@ -97,6 +101,7 @@ class ClosedBatch(Load):
             if o + self.p["batch"] > len(self.requests):
                 return None
             self._cursor = o + self.p["batch"]
+            self.window_taken += measured
         reqs = self.requests[o:o + self.p["batch"]]
         for r in reqs:
             r.measured = measured
