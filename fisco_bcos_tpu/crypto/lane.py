@@ -83,14 +83,14 @@ class CryptoLane:
         self.suite = suite
         self.wait = max(0.0, float(wait_ms)) / 1000.0
         self.max_batch = max(1, int(max_batch))
-        # host-path fan-out: the device path shards a merged batch across
-        # chips (parallel/mesh.py), so ONE lane call already uses the
-        # whole accelerator — but the native host path is single-core per
-        # FFI call, and a lane that serializes G groups' crypto onto one
-        # core would UNDO the concurrency the per-group suites had. Large
-        # merged host batches are therefore split across a small pool of
-        # GIL-releasing native calls (the reference's tbb
-        # verify_worker_num fan-out, NodeConfig.cpp:486). 0 = #cores.
+        # host-path fan-out of merged HASH batches: the device path shards
+        # a merged batch across chips (parallel/mesh.py), so ONE lane call
+        # already uses the whole accelerator — but a native host hash call
+        # is single-core, and a lane that serializes G groups' hashing
+        # onto one core would UNDO the concurrency the per-group suites
+        # had. Verify and recover are not fanned out here: their host door
+        # splits a batch across cores itself (nativeec._run_spans), for
+        # every caller. 0 = #cores.
         import os as _os
         self.host_workers = host_workers or min(4, _os.cpu_count() or 1)
         self._pool = None  # lazy ThreadPoolExecutor
@@ -361,15 +361,7 @@ class CryptoLane:
             digests.extend(d)
             sigs.extend(g)
             pubs.extend(p)
-        chunks = self._host_chunks(len(digests))
-        if chunks:
-            parts = self._fan_out(
-                lambda o, ln: self.suite.verify_batch(
-                    digests[o:o + ln], sigs[o:o + ln], pubs[o:o + ln]),
-                chunks)
-            ok = np.concatenate([np.asarray(p) for p in parts])
-        else:
-            ok = np.asarray(self.suite.verify_batch(digests, sigs, pubs))
+        ok = np.asarray(self.suite.verify_batch(digests, sigs, pubs))
         off = 0
         for r in batch:
             r.task.resolve(ok[off:off + r.n])
@@ -381,16 +373,8 @@ class CryptoLane:
             d, g = r.args
             digests.extend(d)
             sigs.extend(g)
-        chunks = self._host_chunks(len(digests))
-        if chunks:
-            parts = self._fan_out(
-                lambda o, ln: self.suite.recover_batch(
-                    digests[o:o + ln], sigs[o:o + ln]), chunks)
-            pubs = [p for part in parts for p in part[0]]
-            ok = np.concatenate([np.asarray(part[1]) for part in parts])
-        else:
-            pubs, ok = self.suite.recover_batch(digests, sigs)
-            ok = np.asarray(ok)
+        pubs, ok = self.suite.recover_batch(digests, sigs)
+        ok = np.asarray(ok)
         off = 0
         for r in batch:
             r.task.resolve((pubs[off:off + r.n], ok[off:off + r.n]))
@@ -419,9 +403,9 @@ class CryptoLane:
             lefts.extend(a)
             rights.extend(b)
         # no host fan-out here: the Poseidon host oracle is pure-Python
-        # bigint code that never releases the GIL (unlike the native FFI
-        # verify/recover/hash paths _host_chunks exists for), so a pool
-        # split would serialize anyway and only add dispatch overhead
+        # bigint code that never releases the GIL (unlike the native hash
+        # path _host_chunks exists for), so a pool split would serialize
+        # anyway and only add dispatch overhead
         out = self.suite.poseidon_batch(lefts, rights)
         off = 0
         for r in batch:

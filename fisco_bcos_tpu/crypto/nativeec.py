@@ -8,6 +8,12 @@ fallback, accelerator-free deployments — at ~100x the pure-Python oracle
 (`crypto.refimpl`), which stays untouched as the golden reference.
 
 Row format: count x 32 big-endian bytes per scalar/coordinate.
+
+A batch of more than one native chunk (128 rows) is issued as up to four
+concurrent native calls over disjoint row spans (`_run_spans`): ctypes
+releases the GIL around each, so a host node checks a cohort on several
+cores, as the reference's tbb `verify_worker_num` workers do
+(NodeConfig.cpp:486).
 """
 
 from __future__ import annotations
@@ -15,6 +21,7 @@ from __future__ import annotations
 import ctypes
 import os
 import threading
+from concurrent.futures import ThreadPoolExecutor, wait
 from typing import Optional
 
 _LIB_ENV = "FBTPU_NCRYPTO_LIB"
@@ -27,6 +34,15 @@ _loaded = False
 _lock = threading.Lock()
 
 _CURVE_SECP, _CURVE_SM2 = 0, 1
+
+# rows the C loops walk at a time (ncrypto.cpp CHUNK): one batched
+# inversion per chunk, so a span of whole chunks computes what the
+# single call computes for the same rows
+_CHUNK = 128
+_MAX_WORKERS = 4
+
+_pool: Optional[ThreadPoolExecutor] = None
+_pool_pid = 0
 
 
 def load_library():
@@ -84,6 +100,72 @@ def available() -> bool:
     return load_library() is not None
 
 
+def _workers() -> int:
+    return min(_MAX_WORKERS, len(os.sched_getaffinity(0)))
+
+
+def spans_of(n: int) -> list[tuple[int, int]]:
+    """[(first row, rows)] of the native calls a batch of n rows is issued
+    as: one while n fits a native chunk, else as many spans of whole
+    chunks as this process has cores for (at most four), the tail's odd
+    rows in the last."""
+    chunks = -(-n // _CHUNK)
+    parts = min(_workers(), chunks)
+    if parts < 2:
+        return [(0, n)]
+    base, extra = divmod(chunks, parts)
+    out, row = [], 0
+    for i in range(parts):
+        rows = min((base + (i < extra)) * _CHUNK, n - row)
+        out.append((row, rows))
+        row += rows
+    return out
+
+
+def parts_of(n: int) -> int:
+    """Native calls a batch door issues for n rows (`hostParts` of the
+    suite's status): 1 where the library is unavailable and the caller
+    walks the oracle on its own thread."""
+    return len(spans_of(n)) if available() else 1
+
+
+def _span_pool() -> ThreadPoolExecutor:
+    """The process's pool, made on its first split batch (and anew in a
+    forked child, whose copy of the parent's pool has no threads)."""
+    global _pool, _pool_pid
+    with _lock:
+        if _pool is None or _pool_pid != os.getpid():
+            _pool = ThreadPoolExecutor(_MAX_WORKERS,
+                                       thread_name_prefix="nativeec")
+            _pool_pid = os.getpid()
+        return _pool
+
+
+def _run_spans(fn, lead: tuple, n: int, ins: tuple, outs: tuple) -> None:
+    """`fn(*lead, rows, *inputs, *outputs)` over the n rows, split as
+    `spans_of(n)` says. `ins`: (bytes, bytes a row) read-only row
+    buffers, each span passing its slice; `outs`: (ctypes uint8 array,
+    bytes a row) preallocated for all n rows, each span writing its own
+    part in place. The caller's thread runs the last span and joins the
+    others, so a one-span batch makes no pool and no thread hop."""
+    def run(span):
+        o, ln = span
+        fn(*lead, ln,
+           *(b[o * w:(o + ln) * w] for b, w in ins),
+           *((ctypes.c_uint8 * (ln * w)).from_buffer(buf, o * w)
+             for buf, w in outs))
+
+    *beside, mine = spans_of(n)
+    pending = [_span_pool().submit(run, sp) for sp in beside]
+    try:
+        run(mine)
+    finally:
+        # every part has ended before a buffer is read or released
+        wait(pending)
+    for f in pending:
+        f.result()  # a part's failure is raised here
+
+
 def _check_lens(n: int, *seqs) -> None:
     """The C side reads n rows from EVERY buffer: a short argument list
     would be a heap overread, so fail loudly at the boundary instead."""
@@ -105,6 +187,18 @@ def _e_rows(es, n, order: int) -> bytes:
         for v in es[:n])
 
 
+def _verify(fn, lead: tuple, order: int, es, rs, ss, qxs, qys) -> list:
+    n = len(es)
+    _check_lens(n, rs, ss, qxs, qys)
+    ok = (ctypes.c_uint8 * n)()
+    _run_spans(fn, lead, n,
+               tuple((b, 32) for b in (
+                   _e_rows(es, n, order), _rows(rs, n), _rows(ss, n),
+                   _rows(qxs, n), _rows(qys, n))),
+               ((ok, 1),))
+    return [bool(v) for v in ok]
+
+
 def ecdsa_verify_batch(es, rs, ss, qxs, qys) -> Optional[list]:
     """ints -> [bool]; None when the library is unavailable."""
     from . import refimpl
@@ -112,13 +206,8 @@ def ecdsa_verify_batch(es, rs, ss, qxs, qys) -> Optional[list]:
     lib = load_library()
     if lib is None:
         return None
-    n = len(es)
-    _check_lens(n, rs, ss, qxs, qys)
-    ok = (ctypes.c_uint8 * n)()
-    lib.ncrypto_ecdsa_verify_batch(
-        _CURVE_SECP, n, _e_rows(es, n, refimpl.SECP256K1.n), _rows(rs, n),
-        _rows(ss, n), _rows(qxs, n), _rows(qys, n), ok)
-    return [bool(v) for v in ok]
+    return _verify(lib.ncrypto_ecdsa_verify_batch, (_CURVE_SECP,),
+                   refimpl.SECP256K1.n, es, rs, ss, qxs, qys)
 
 
 def sm2_verify_batch(es, rs, ss, qxs, qys) -> Optional[list]:
@@ -127,13 +216,8 @@ def sm2_verify_batch(es, rs, ss, qxs, qys) -> Optional[list]:
     lib = load_library()
     if lib is None:
         return None
-    n = len(es)
-    _check_lens(n, rs, ss, qxs, qys)
-    ok = (ctypes.c_uint8 * n)()
-    lib.ncrypto_sm2_verify_batch(n, _e_rows(es, n, refimpl.SM2P256V1.n),
-                                 _rows(rs, n), _rows(ss, n), _rows(qxs, n),
-                                 _rows(qys, n), ok)
-    return [bool(v) for v in ok]
+    return _verify(lib.ncrypto_sm2_verify_batch, (),
+                   refimpl.SM2P256V1.n, es, rs, ss, qxs, qys)
 
 
 def ecdsa_sign(secret: int, digest: bytes) -> Optional[tuple]:
@@ -203,10 +287,16 @@ def ecdsa_recover_batch_rows(e_rows: bytes, r_rows: bytes, s_rows: bytes,
     if (len(e_rows) != 32 * n or len(r_rows) != 32 * n
             or len(s_rows) != 32 * n):
         raise ValueError("row buffer length mismatch")
+    return _recover(lib, n, e_rows, r_rows, s_rows, vs)
+
+
+def _recover(lib, n: int, e_rows: bytes, r_rows: bytes, s_rows: bytes,
+             vs: bytes) -> tuple:
     ok = (ctypes.c_uint8 * n)()
     pubs = (ctypes.c_uint8 * (64 * n))()
-    lib.ncrypto_ecdsa_recover_batch(
-        _CURVE_SECP, n, e_rows, r_rows, s_rows, vs, pubs, ok)
+    _run_spans(lib.ncrypto_ecdsa_recover_batch, (_CURVE_SECP,), n,
+               ((e_rows, 32), (r_rows, 32), (s_rows, 32), (vs, 1)),
+               ((pubs, 64), (ok, 1)))
     raw = bytes(pubs)
     out = [raw[64 * i:64 * i + 64] if ok[i] else None for i in range(n)]
     return out, [bool(v) for v in ok]
@@ -221,11 +311,6 @@ def ecdsa_recover_batch(es, rs, ss, vs) -> Optional[tuple]:
         return None
     n = len(es)
     _check_lens(n, rs, ss, vs)
-    ok = (ctypes.c_uint8 * n)()
-    pubs = (ctypes.c_uint8 * (64 * n))()
-    lib.ncrypto_ecdsa_recover_batch(
-        _CURVE_SECP, n, _e_rows(es, n, refimpl.SECP256K1.n), _rows(rs, n),
-        _rows(ss, n), bytes(v & 0xFF for v in vs[:n]), pubs, ok)
-    raw = bytes(pubs)
-    out = [raw[64 * i:64 * i + 64] if ok[i] else None for i in range(n)]
-    return out, [bool(v) for v in ok]
+    return _recover(lib, n, _e_rows(es, n, refimpl.SECP256K1.n),
+                    _rows(rs, n), _rows(ss, n),
+                    bytes(v & 0xFF for v in vs[:n]))

@@ -216,9 +216,12 @@ class CryptoSuite:
         self.on_device_error: list[Callable[[str], None]] = []
         self._stats_lock = threading.Lock()
         # per op: device calls/items, host calls/items, the device
-        # calls' cumulative pack/call/unpack seconds, and the lanes they
-        # were issued with (items plus the padding to their buckets)
-        self._stats = {op: [0, 0, 0, 0, 0.0, 0.0, 0.0, 0] for op in _OPS}
+        # calls' cumulative pack/call/unpack seconds, the lanes they
+        # were issued with (items plus the padding to their buckets),
+        # the native calls the host batches were issued as and the items
+        # of those issued as more than one (nativeec.parts_of)
+        self._stats = {op: [0, 0, 0, 0, 0.0, 0.0, 0.0, 0, 0, 0]
+                       for op in _OPS}
         self._ready: dict | None = None  # set by prepare()
         from . import nativehash
 
@@ -274,11 +277,14 @@ class CryptoSuite:
             return False
         return self.backend == "device" or n >= self.device_min_batch
 
-    def _count_host(self, op: str, n: int) -> None:
+    def _count_host(self, op: str, n: int, parts: int = 1) -> None:
         with self._stats_lock:
             row = self._stats[op]
             row[2] += 1
             row[3] += n
+            row[8] += parts
+            if parts > 1:
+                row[9] += n
 
     def _on_device(self, op: str, n: int, lanes: int, t_in: float, call,
                    unpack=None):
@@ -322,6 +328,7 @@ class CryptoSuite:
             ops_ = {op: {"deviceCalls": r[0], "deviceItems": r[1],
                          "deviceLanes": r[7],
                          "hostCalls": r[2], "hostItems": r[3],
+                         "hostParts": r[8], "hostSplitItems": r[9],
                          "packSeconds": r[4], "callSeconds": r[5],
                          "unpackSeconds": r[6]}
                     for op, r in self._stats.items()}
@@ -580,7 +587,7 @@ class CryptoSuite:
         if not self._use_device(n):
             from . import nativeec
 
-            self._count_host("verify", n)
+            self._count_host("verify", n, nativeec.parts_of(n))
             if self.kind == "ecdsa":
                 native = nativeec.ecdsa_verify_batch(es, rs, ss, qx, qy)
                 if native is not None:
@@ -630,7 +637,7 @@ class CryptoSuite:
         if not device:
             from . import nativeec
 
-            self._count_host("recover", n)
+            self._count_host("recover", n, nativeec.parts_of(n))
             if (nativeec.available()
                     and all(len(d) == 32 for d in digests)):
                 # rows fast path: wire signature bytes and 32-byte tx

@@ -235,9 +235,11 @@ def test_lane_suite_delegates_and_bypasses_tiny_batches(lane_pair):
 
 
 def test_host_fan_out_preserves_results():
-    """Large merged HOST batches split across the lane's worker pool (the
-    tbb verify_worker_num analogue): results must be order-preserving and
-    bit-identical to the unsplit call, bad slices staying positional."""
+    """Merged HOST batches through the lane: a recover reaches the base
+    suite whole (its host door splits it across cores, nativeec), a hash
+    batch is split across the lane's worker pool; results must be
+    order-preserving and bit-identical to the unsplit call, bad slices
+    staying positional."""
     counting = CountingSuite(make_suite(False, backend="host"))
     lane = CryptoLane(counting, host_workers=2)
     suite = LaneSuite(lane, tag="g")
@@ -249,7 +251,7 @@ def test_host_fan_out_preserves_results():
         sigs = s[:10] + sb + s[10:]
         counting.recover_calls = 0
         pubs, ok = suite.recover_batch(digests, sigs)
-        assert counting.recover_calls == 2  # fanned across the pool
+        assert counting.recover_calls == 1  # the door splits, not the lane
         want = [True] * 10 + [False] * 4 + [True] * 10
         assert list(np.asarray(ok)) == want
         ref_pubs, _ = counting._suite.recover_batch(digests, sigs)
