@@ -96,7 +96,7 @@ class NodeConfig:
     client_read_rate: float = 0.0
     client_read_burst: float = 0.0
     # continuous-batching ingest lane (txpool/ingest.py): coalesces
-    # concurrent RPC/gossip submissions into device-sized submit_batch
+    # concurrent RPC/gossip submissions into device-sized submit_columns
     # calls. ingest_lane=False restores direct per-call submission (the
     # per-request baseline, kept for benchmarking and odd embeddings).
     # The largest batch a dispatch takes and the queue's capacity follow
@@ -820,7 +820,7 @@ class Node:
                                       TransactionStatus.TXPOOL_FULL)
             except (LaneStopped, TaskTimeout):
                 pass  # shutdown race / wedged dispatcher: the pool still
-                #       works, and _precheck dedups a queued copy
+                #       works, and its precheck dedups a queued copy
             except Exception:  # noqa: BLE001 — a failed DISPATCH rejects
                 # every coalesced submitter with the batch's error; retry
                 # THIS tx alone on the direct path so one bad cohort

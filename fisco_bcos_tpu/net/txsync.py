@@ -3,8 +3,9 @@
 Reference counterpart: /root/reference/bcos-txpool/bcos-txpool/sync/
 TransactionSync.cpp — broadcast of newly submitted txs to peers, batch
 import of received packets (the **tbb::parallel_for over tx->verify** at
-:516-537 that the TPU batch-recover call replaces here: received batches go
-through `TxPool.submit_batch`, i.e. ONE device recover kernel per packet),
+:516-537 that the TPU batch-recover call replaces here: received frames go,
+undecoded, through `TxPool.submit_columns`, i.e. ONE device recover kernel
+per packet, or one per lane drain where the node has an ingest lane),
 on-demand fetch of a proposal's missing txs (TxPool.cpp:160
 asyncVerifyBlock's fetch-missing path), and a periodic maintenance sweep
 (TransactionSync.cpp's executeWorker maintainTransactions loop).
@@ -31,7 +32,8 @@ import time
 from typing import Sequence
 
 from ..codec.wire import Reader, Writer
-from ..protocol import Transaction, batch_hash
+from ..protocol import Transaction, TransactionStatus, batch_hash
+from ..protocol.columnar import decode_columns
 from ..utils import otrace
 from ..utils.log import metric
 from ..utils.worker import Worker
@@ -170,14 +172,13 @@ class TransactionSync(Worker):
         # still recomputes the real hashes
         if {h for h, _raw in pairs} != set(hashes):
             return False
-        txs = [Transaction.decode(raw) for _h, raw in pairs]
         # consensus import: proposal verification must succeed even on a
         # saturated pool — watermark admission does not apply here (the
         # p2p layer protects these frames for the same reason)
-        results = self.txpool.submit_batch(txs, broadcast=False,
-                                           consensus=True)
-        metric("txsync.fetch_missing", n=len(txs), peer=peer[:8].hex())
-        from ..protocol import TransactionStatus
+        results = self.txpool.submit_columns(
+            decode_columns([raw for _h, raw in pairs]), broadcast=False,
+            consensus=True)
+        metric("txsync.fetch_missing", n=len(pairs), peer=peer[:8].hex())
         okset = (TransactionStatus.OK, TransactionStatus.ALREADY_IN_TXPOOL,
                  TransactionStatus.ALREADY_KNOWN)
         return all(r.status in okset for r in results)
@@ -219,5 +220,4 @@ class TransactionSync(Worker):
             self.ingest.submit_many_wire_nowait(wires)
             return
         # one TPU batch-recover for the whole gossip packet
-        from ..protocol.columnar import decode_columns
         self.txpool.submit_columns(decode_columns(wires), broadcast=True)

@@ -186,7 +186,7 @@ class TxColumns:
         "abi_off", "abi_end",
         "version", "block_limit", "import_time", "attribute",
         "chain_id", "group_id", "nonce",
-        "hashes", "senders", "decode_ok", "fallback", "_views",
+        "hashes", "senders", "decode_ok", "fallback", "_views", "traces",
     )
 
     def __len__(self) -> int:
@@ -260,9 +260,11 @@ class TxColumns:
 
     # -- views ---------------------------------------------------------------
     def view(self, i: int):
-        """The row's lazy tx object — a `TxView`, or the materialised
-        `Transaction` for non-canonical fallback rows (which IS the full
-        API already). Cached: the pool holds one object per admitted row."""
+        """The row's lazy tx object — a `TxView`, or the `Transaction` of
+        a fallback row (which IS the full API already). Cached: the pool
+        holds one object per admitted row. The row's span context
+        (`traces`) rides on it as `_otrace`, where the sealer and gossip
+        look for a transaction's trace."""
         v = self._views.get(i)
         if v is None:
             v = self.fallback.get(i)
@@ -270,6 +272,9 @@ class TxColumns:
                 if not self.decode_ok[i]:
                     raise ValueError(f"columnar row {i} failed decode")
                 v = TxView(self, i, self.hashes[i], self.senders[i])
+            ctx = self.traces.get(i)
+            if ctx is not None:
+                v._otrace = ctx
             self._views[i] = v
         return v
 
@@ -381,6 +386,7 @@ def decode_columns(wires: Sequence[bytes]) -> TxColumns:
     cols.decode_ok = np.zeros(n, bool)
     cols.fallback = {}
     cols._views = {}
+    cols.traces = {}  # row -> otrace span context of a traced submission
 
     interned: dict[bytes, str] = {}
     arena = cols.arena
@@ -418,13 +424,19 @@ def decode_columns(wires: Sequence[bytes]) -> TxColumns:
 
 
 def columns_from_transactions(txs: Sequence[Transaction]) -> TxColumns:
-    """Columns over already-decoded txs (bench A/B + worker-side reuse):
-    encodes each once (cached for decoded txs) and re-parses into the
-    arena — identity caches carry over."""
+    """Columns over already-decoded txs (`TxPool.submit_batch`): encodes
+    each once (cached for decoded txs) and re-parses into the arena.
+    Identity caches and span contexts carry over, and each row's object
+    is the tx itself (`fallback`), so what the batch hash and recover
+    learn is cached on it and the pool holds the caller's object."""
     cols = decode_columns([t.encode() for t in txs])
     for i, t in enumerate(txs):
+        cols.fallback[i] = t
         if t._hash is not None:
             cols.hashes[i] = t._hash
         if t._sender is not None:
             cols.senders[i] = t._sender
+        ctx = getattr(t, "_otrace", None)
+        if ctx is not None:
+            cols.traces[i] = ctx
     return cols

@@ -513,6 +513,14 @@ class JsonRpcImpl:
                            f"unknown group {group}")
 
     # -- tx path -----------------------------------------------------------
+    def _submit_direct(self, raw: bytes):
+        """No lane, or the lane failed this request: the transaction
+        alone through the pool's `submit` (a `RemoteTxPool` has no other
+        way in), under the request's span context."""
+        tx = Transaction.decode(raw)
+        tx._otrace = otrace.current()
+        return self.node.txpool.submit(tx)
+
     def send_transaction(self, group: str, node_name: str = "",
                          tx_hex: str = "", require_proof: bool = False,
                          wait: bool = True, timeout: float = 30.0):
@@ -529,17 +537,6 @@ class JsonRpcImpl:
         # already in the lane with its batch's cohort? then only wait
         admitted = (getattr(self._tl, "cohort", None) or {}).pop(tx_hex,
                                                                  None)
-        ctx = otrace.current() if admitted is None else None
-        tx = None
-        if ctx is not None:
-            # traced request: decode eagerly — the span context follows
-            # the TX OBJECT from here (ingest lane entry -> pool admission
-            # -> sealer adoption -> every node's consensus spans via the
-            # p2p envelope). Tracing is sampled, so the object-path cost
-            # is paid on a fraction of requests.
-            tx = Transaction.decode(raw)
-            tx._otrace = ctx
-        from ..protocol import TransactionStatus
         # the wait budget is CLIENT-supplied: clamp it, or a crafted
         # request parks a shared-pool worker for arbitrary time
         timeout = max(0.0, min(float(timeout), MAX_WAIT_SECONDS))
@@ -549,19 +546,20 @@ class JsonRpcImpl:
             # continuous-batching lane: this request's tx coalesces with
             # every other in-flight sendTransaction (and gossip arrivals)
             # into ONE batch recover; the future resolves with this tx's
-            # own admission result. Untraced requests ride the COLUMNAR
-            # door: the raw frame is never decoded into a Transaction on
-            # this thread — the dispatcher folds the cohort's frames into
-            # one arena-backed column batch (protocol.columnar)
+            # own admission result. The raw frame is never decoded into a
+            # Transaction on this thread — the dispatcher folds the
+            # cohort's frames into one arena-backed column batch
+            # (protocol.columnar) — and a traced request's span context
+            # rides the lane entry to the admitted row's view (-> sealer
+            # adoption -> every node's consensus spans via the p2p
+            # envelope)
             from ..txpool.ingest import TxPoolIsFull
             from ..utils.task import TaskTimeout
             try:
                 if admitted is not None:
                     res = admitted.result(timeout)
-                elif tx is None:
-                    res = lane.submit_wire(raw, timeout=timeout)
                 else:
-                    res = lane.submit(tx, timeout=timeout)
+                    res = lane.submit_wire(raw, timeout=timeout)
             except TxPoolIsFull as exc:
                 raise JsonRpcError(int(TransactionStatus.TXPOOL_FULL),
                                    str(exc))
@@ -571,15 +569,13 @@ class JsonRpcImpl:
                 raise JsonRpcError(JSONRPC_INTERNAL_ERROR,
                                    "timed out waiting for admission")
             except Exception:  # noqa: BLE001 — LaneStopped or dispatch
-                # failure. submit_batch guards its broadcast hooks, so a
+                # failure. Admission guards its broadcast hooks, so a
                 # dispatch exception means this tx was NOT admitted —
                 # retrying alone on the direct path is safe and isolates
                 # this request from a bad cohort member
-                res = self.node.txpool.submit(
-                    tx if tx is not None else Transaction.decode(raw))
+                res = self._submit_direct(raw)
         else:
-            res = self.node.txpool.submit(
-                tx if tx is not None else Transaction.decode(raw))
+            res = self._submit_direct(raw)
         if res.status not in (TransactionStatus.OK,
                               TransactionStatus.ALREADY_IN_TXPOOL,
                               TransactionStatus.ALREADY_KNOWN):

@@ -1,6 +1,6 @@
 """IngestLane — continuous-batching front door for the txpool.
 
-The framework's thesis is batch-first validation (`TxPool.submit_batch`
+The framework's thesis is batch-first validation (`TxPool.submit_columns`
 -> ONE device recover per packet), yet the serving edge defeats it when
 every JSON-RPC `sendTransaction` calls `submit(tx)` — a batch of one —
 so each independent client pays a full recover (~162 us native; device
@@ -10,11 +10,13 @@ in front of the verify engine (Blockchain Machine, arXiv:2104.06968;
 FPGA ECDSA engine, arXiv:2112.02229); inference servers call the same
 shape continuous batching. This lane is that aggregation layer:
 
-  * concurrent submitters enqueue (tx, future) into a BOUNDED queue —
-    a full queue rejects with `TxPoolIsFull` instead of growing without
-    bound (admission control, not buffering);
-  * one dispatcher thread drains up to `max_batch` txs per cycle and
-    issues ONE `TxPool.submit_batch` for the drained set, resolving each
+  * concurrent submitters enqueue (wire frame, future, span context) into
+    a BOUNDED queue — a full queue rejects with `TxPoolIsFull` instead of
+    growing without bound (admission control, not buffering); a frame is
+    never decoded into a `Transaction` on the way;
+  * one dispatcher thread drains up to `max_batch` frames per cycle and
+    makes ONE `protocol.columnar.decode_columns` and ONE
+    `TxPool.submit_columns` call for the drained set, resolving each
     submitter's future with its per-tx result;
   * the coalescing window is ADAPTIVE: near-zero when idle (a lone tx is
     dispatched immediately, no latency tax), growing toward
@@ -24,10 +26,12 @@ shape continuous batching. This lane is that aggregation layer:
     bucket's padding for a handful of txs.
 
 Producers wired through the lane: `rpc/server.py` send_transaction (HTTP
-and WS share `JsonRpcImpl`), `net/txsync.py` gossip ingestion, and the
-in-process `Node.send_transaction` surface. `TransactionSync.fetch_missing`
-stays on the direct `submit_batch` path: it already holds a full batch and
-needs its results synchronously inside proposal verification.
+and WS share `JsonRpcImpl`; `submit_wire`, `submit_wire_cohort`),
+`net/txsync.py` gossip ingestion (`submit_many_wire_nowait`), and the
+in-process `Node.send_transaction` surface (`submit`, which queues the
+transaction's frame). `TransactionSync.fetch_missing` calls
+`submit_columns` itself: it already holds a full batch and needs its
+results synchronously inside proposal verification.
 """
 
 from __future__ import annotations
@@ -39,6 +43,7 @@ from typing import Optional, Sequence
 
 from ..analysis import lockcheck as lc
 from ..protocol import Transaction
+from ..protocol.columnar import decode_columns
 from ..utils import otrace
 from ..utils.log import LOG, badge, metric
 from ..utils.metrics import REGISTRY
@@ -87,25 +92,22 @@ class LaneStopped(RuntimeError):
 
 
 class _Entry:
-    __slots__ = ("tx", "task", "t_enq", "ctx", "wire")
+    __slots__ = ("wire", "task", "t_enq", "ctx")
 
-    def __init__(self, tx: Optional[Transaction], task: Optional[Task],
-                 ctx=None, wire: Optional[bytes] = None):
-        self.tx = tx  # None: columnar entry — raw frame in `wire`, never
-        #               decoded into a Transaction (protocol.columnar)
+    def __init__(self, wire: bytes, task: Optional[Task], ctx=None):
+        self.wire = wire  # the raw frame: a column row at dispatch
         self.task = task  # None: fire-and-forget (gossip), nobody awaits
         self.t_enq = time.monotonic()
         # otrace span context of the submitting trace (None when the
         # submission isn't traced): the dispatcher records this entry's
-        # queue-to-admission span under it, and one batch span LINKS all
-        # coalesced traces
+        # queue-to-admission span under it and hands it to the pool with
+        # the row, whose view carries it on to the sealer and gossip
         self.ctx = ctx
-        self.wire = wire
 
 
 class IngestLane:
-    """Coalesces concurrent single-tx submissions into device-sized
-    `submit_batch` calls. Thread-safe; one dispatcher thread."""
+    """Coalesces concurrent submissions into device-sized
+    `submit_columns` calls. Thread-safe; one dispatcher thread."""
 
     def __init__(self, txpool, max_batch: int = 4096,
                  max_wait_ms: float = 15.0, queue_cap: int = 8192,
@@ -168,7 +170,7 @@ class IngestLane:
         if self._thread is not None:
             self._thread.join(timeout=10)
             if self._thread.is_alive():
-                # wedged dispatcher (e.g. stuck inside submit_batch):
+                # wedged dispatcher (e.g. stuck inside admission):
                 # keep the reference so a later start() can't spawn a
                 # SECOND dispatcher over the same queue — the lane stays
                 # stopped and callers use their direct-path fallbacks
@@ -185,39 +187,16 @@ class IngestLane:
 
     # -- producer API ------------------------------------------------------
     def submit_async(self, tx: Transaction) -> Task:
-        """Enqueue one tx; -> Task[TxSubmitResult]. Raises TxPoolIsFull
-        when the queue is at capacity (bounded-memory backpressure)."""
-        ctx = getattr(tx, "_otrace", None) or otrace.current()
-        entry = _Entry(tx, Task(), ctx=ctx)
-        with self._cv:
-            if self._stop:
-                raise LaneStopped("ingest lane stopped")
-            if len(self._q) >= self.queue_cap:
-                self._rejected_total += 1
-                self._reg.inc("bcos_ingest_rejected_total")
-                raise TxPoolIsFull(
-                    f"ingest queue at capacity ({self.queue_cap})")
-            self._q.append(entry)
-            self._queued_locked()
-            depth = len(self._q)
-            self._cv.notify_all()
-        self._reg.set_gauge("bcos_ingest_queue_depth", depth)
-        return entry.task
+        """Enqueue one tx (its frame, under its own trace context or the
+        thread's); -> Task[TxSubmitResult]. Raises TxPoolIsFull when the
+        queue is at capacity (bounded-memory backpressure)."""
+        with otrace.ctx_scope(getattr(tx, "_otrace", None)):
+            return self.submit_wire_cohort([tx.encode()])[0]
 
     def submit(self, tx: Transaction, timeout: float = 30.0
                ) -> TxSubmitResult:
         """Blocking single-tx submission through the batching lane."""
         return self.submit_async(tx).result(timeout)
-
-    def submit_wire_async(self, raw: bytes) -> Task:
-        """Enqueue one RAW wire frame; -> Task[TxSubmitResult].
-
-        The columnar front door (ROADMAP item 1): the frame is never
-        decoded into a `Transaction` — the dispatcher folds all queued
-        wire entries into one `protocol.columnar.decode_columns` +
-        `TxPool.submit_columns` call, so per-tx Python marshalling
-        disappears from the hot path. Raises TxPoolIsFull at capacity."""
-        return self.submit_wire_cohort([raw])[0]
 
     def submit_wire_cohort(self, raws: Sequence[bytes]) -> list[Task]:
         """Enqueue a cohort of raw wire frames UNDER ONE LOCK HOLD; ->
@@ -229,7 +208,7 @@ class IngestLane:
         them device-sized. All-or-nothing: raises TxPoolIsFull when the
         cohort does not fit."""
         ctx = otrace.current()
-        entries = [_Entry(None, Task(), ctx=ctx, wire=raw) for raw in raws]
+        entries = [_Entry(raw, Task(), ctx) for raw in raws]
         with self._cv:
             if self._stop:
                 raise LaneStopped("ingest lane stopped")
@@ -247,13 +226,16 @@ class IngestLane:
 
     def submit_wire(self, raw: bytes, timeout: float = 30.0
                     ) -> TxSubmitResult:
-        """Blocking single-frame submission through the columnar lane."""
-        return self.submit_wire_async(raw).result(timeout)
+        """Blocking single-frame submission (a lone `sendTransaction`)."""
+        return self.submit_wire_cohort([raw])[0].result(timeout)
 
     def submit_many_wire_nowait(self, wires: Sequence[bytes]) -> int:
-        """Fire-and-forget bulk enqueue of RAW wire frames (the gossip
-        decode path): same drop-don't-block contract as
-        submit_many_nowait, but frames ride to admission undecoded."""
+        """Fire-and-forget bulk enqueue of raw wire frames (gossip
+        ingestion): accepts what fits under the cap and DROPS the rest
+        (-> count accepted). Gossip may drop under overload — the pool
+        anti-entropy sweep re-delivers; blocking the p2p reader thread on
+        a full queue would back the network plane up behind the verify
+        engine instead."""
         if not wires:
             return 0
         accepted = 0
@@ -262,39 +244,10 @@ class IngestLane:
                 return 0
             room = self.queue_cap - len(self._q)
             for w in wires[:max(0, room)]:
-                self._q.append(_Entry(None, None, wire=w))
+                self._q.append(_Entry(w, None))
                 accepted += 1
             depth = len(self._q)
             dropped = len(wires) - accepted
-            self._dropped_total += dropped
-            if accepted:
-                self._queued_locked()
-                self._cv.notify_all()
-        if dropped:
-            self._reg.inc("bcos_ingest_dropped_total", dropped)
-            metric("ingest.drop", n=dropped)
-        self._reg.set_gauge("bcos_ingest_queue_depth", depth)
-        return accepted
-
-    def submit_many_nowait(self, txs: Sequence[Transaction]) -> int:
-        """Fire-and-forget bulk enqueue (gossip ingestion): accepts what
-        fits under the cap and DROPS the rest (-> count accepted). Gossip
-        may drop under overload — the pool anti-entropy sweep re-delivers;
-        blocking the p2p reader thread on a full queue would back the
-        network plane up behind the verify engine instead."""
-        if not txs:
-            return 0
-        accepted = 0
-        with self._cv:
-            if self._stop:
-                return 0
-            room = self.queue_cap - len(self._q)
-            for tx in txs[:max(0, room)]:
-                self._q.append(_Entry(tx, None,
-                                      ctx=getattr(tx, "_otrace", None)))
-                accepted += 1
-            depth = len(self._q)
-            dropped = len(txs) - accepted
             self._dropped_total += dropped
             if accepted:
                 self._queued_locked()
@@ -399,57 +352,21 @@ class IngestLane:
                   waited: Optional[otrace.Stage] = None) -> None:
         # latency attribution: the batch's coalesce time ends here
         now = waited.stop() if waited is not None else time.monotonic()
-        # columnar entries (raw wire frames) and object entries dispatch
-        # through their own pool doors; a mixed drain pays two recover
-        # calls, but producers are homogeneous per deployment (wire RPC +
-        # wire gossip, or legacy object submitters), so the mix is a
-        # transition artifact, not the steady state
-        wire_entries = [e for e in batch if e.tx is None]
-        obj_entries = [e for e in batch if e.tx is not None]
-        # deadline shed BEFORE any admission/crypto work: entries whose
-        # block_limit already passed while they sat in the queue can never
-        # commit — settle them with the typed expiry status instead of
-        # spending lane verify + pool slots on work that would be dropped
-        # anyway (they would be rejected by the pool's precheck, but under
-        # overload even carrying them through the batch costs real time).
-        # Wire entries skip this: reading block_limit would mean decoding,
-        # and submit_columns' precheck rejects expired rows BEFORE the
-        # recover anyway (they pay one batched hash slot, nothing more).
-        ledger = getattr(self.txpool, "ledger", None)  # test doubles may
-        current = ledger.current_number() if ledger is not None else None
-        shed = [e for e in obj_entries
-                if current is not None and e.tx.block_limit <= current]
-        if shed:
-            from ..protocol import TransactionStatus, batch_hash
-            hs = batch_hash([e.tx for e in shed], self.txpool.suite)
-            for e, h in zip(shed, hs):
-                if e.task is not None:
-                    e.task.resolve(TxSubmitResult(
-                        h, TransactionStatus.BLOCK_LIMIT_CHECK_FAIL))
-            self._reg.inc("bcos_ingest_deadline_shed_total", len(shed))
-            obj_entries = [e for e in obj_entries
-                           if e.tx.block_limit > current]
-            batch = obj_entries + wire_entries
-            if not batch:
-                return
-        # one pool call per path == one device recover for the drained set
+        # one decode and one pool call == one device recover for the
+        # drained set; a frame that does not parse, or whose block_limit
+        # passed while it sat in the queue, is answered by the pool's
+        # precheck before any crypto
         from ..analysis.profiler import stage as _prof_stage
         t0 = time.perf_counter()
         with _prof_stage("ingest.admit"), self.stages.stage("admit"):
-            if obj_entries:
-                results = self.txpool.submit_batch(
-                    [e.tx for e in obj_entries], broadcast=self.broadcast)
-                for e, res in zip(obj_entries, results):
-                    if e.task is not None:
-                        e.task.resolve(res)
-            if wire_entries:
-                from ..protocol.columnar import decode_columns
-                cols = decode_columns([e.wire for e in wire_entries])
-                results = self.txpool.submit_columns(
-                    cols, broadcast=self.broadcast)
-                for e, res in zip(wire_entries, results):
-                    if e.task is not None:
-                        e.task.resolve(res)
+            cols = decode_columns([e.wire for e in batch])
+            cols.traces = {i: e.ctx for i, e in enumerate(batch)
+                           if e.ctx is not None}
+            results = self.txpool.submit_columns(
+                cols, broadcast=self.broadcast)
+            for e, res in zip(batch, results):
+                if e.task is not None:
+                    e.task.resolve(res)
         dt = time.perf_counter() - t0
         # traced submissions additionally get their own enqueue-to-admitted
         # span (one per traced entry, linked to the shared batch by the

@@ -7,13 +7,13 @@ TxValidator (txpool/validator/TxValidator.cpp:27-68: nonce/chainId/groupId/
 blockLimit checks then the per-tx signature recover at :56).
 
 Design difference (the point of this framework): validation is *batch-first*.
-`submit_batch` runs the cheap host checks per tx, then pushes every
-still-unverified signature through ONE TPU recover call
-(protocol.batch_recover_senders) instead of the reference's
+`submit_columns`, the one admission routine, runs the cheap host checks per
+row, then pushes every still-unverified signature through ONE TPU recover
+call (`TxColumns.ensure_senders`) instead of the reference's
 tbb::parallel_for over scalar verifies (TransactionSync.cpp:516-537).
-The single-tx `submit` is the degenerate case. Duplicate-nonce tracking
-follows the reference's TxPoolNonceChecker: nonces of the last `block_limit`
-committed blocks are a rolling filter.
+`submit_batch` (objects) and the single-tx `submit` are callers of it.
+Duplicate-nonce tracking follows the reference's TxPoolNonceChecker: nonces
+of the last `block_limit` committed blocks are a rolling filter.
 
 Overload control (the serving-stack watermark discipline): admission is no
 longer a hard `TXPOOL_FULL` cliff at `pool_limit`. Below the LOW watermark
@@ -39,12 +39,10 @@ import time
 from collections import OrderedDict
 from typing import Callable, Optional, Sequence
 
-import numpy as np
-
 from ..analysis import lockcheck as lc
 from ..ledger.ledger import Ledger
-from ..protocol import Block, Transaction, TransactionStatus, batch_hash, \
-    batch_recover_senders
+from ..protocol import Block, Transaction, TransactionStatus, batch_hash
+from ..protocol.columnar import columns_from_transactions
 from ..utils import otrace
 from ..utils.log import LOG, badge, metric
 
@@ -120,7 +118,7 @@ class TxPool:
         self._high_mark = max(1, int(pool_limit * high_watermark))
         self._low_mark = min(max(0, int(pool_limit * low_watermark)),
                              self._high_mark - 1)
-        # honor the client-declared priority band (see _band). OFF treats
+        # honor the client-declared priority band (_band_attr). OFF treats
         # every tx as band 0 (eviction order = deadline/age only) — for
         # operators exposing the edge beyond the consortium's own
         # identified clients, where an unauthenticated band would let any
@@ -133,6 +131,10 @@ class TxPool:
         # pre-seal tombstones: hashes of in-flight proposal txs NOT yet in
         # the pool (see mark_sealed) — promoted to _sealed on arrival
         self._presealed: set[bytes] = set()
+        # commits this pool has been told of (on_block_committed, a
+        # snapshot install): what lets an admission know, under the lock,
+        # that its ledger look is older than the chain (submit_columns)
+        self._commits = 0
         # rolling nonce filter: block number -> set of nonces. Seeded from
         # the ledger at construction: after a WAL-replay restart the
         # filter used to come up EMPTY, so a different-hash tx reusing a
@@ -208,7 +210,7 @@ class TxPool:
             except Exception:  # noqa: BLE001 — notifiers run AFTER
                 # admission: a raising sealer callback must not surface
                 # as a submit failure (the ingest lane's fallback treats
-                # submit_batch exceptions as "not admitted")
+                # an exception out of admission as "not admitted")
                 LOG.exception(badge("TXPOOL", "ready-notifier-failed"))
 
     # -- submission --------------------------------------------------------
@@ -218,63 +220,140 @@ class TxPool:
     def submit_batch(self, txs: Sequence[Transaction],
                      broadcast: bool = True,
                      consensus: bool = False) -> list[TxSubmitResult]:
-        """Host checks + one TPU batch recover for the survivors.
+        """The in-process callers' way in (`Node.send_transaction`'s
+        fallback, services/txpool_service.py, `verify_proposal`): the
+        transactions as columns — cached hash and sender carried over,
+        and the pool holds the objects themselves — through
+        `submit_columns`."""
+        return self.submit_columns(columns_from_transactions(txs),
+                                   broadcast, consensus)
 
-        Watermark/capacity verdicts run in the PRE-crypto phase: a full or
-        congested pool answers TXPOOL_FULL / DEADLINE_UNMEETABLE before
-        the batch recover, so rejected load costs zero lane work (the
-        Blockchain-Machine shed-at-the-front-end discipline). The insert
-        phase re-validates against live state — the lock is dropped across
-        the recover — and performs any planned high-watermark evictions.
+    def submit_columns(self, cols, broadcast: bool = True,
+                       consensus: bool = False) -> list[TxSubmitResult]:
+        """The pool's ONE admission routine: every way in — the ingest
+        lane's drain, a gossip packet without a lane, `fetch_missing`,
+        `submit_batch`, `verify_proposal`'s import — is a caller of it.
 
-        `consensus=True` (the fetch-missing import behind proposal
-        verification) BYPASSES watermark/capacity admission entirely: a
-        saturated replica refusing the leader's proposal txs could not
-        prepare and would view-change exactly while overloaded — the
-        same stall the p2p layer's protected-frame classes prevent. The
-        overshoot is bounded by one proposal's tx count, and the txs
+        Two phases around ONE batched recover. The pre-crypto phase runs
+        the prechecks and the watermark/capacity planning under the lock,
+        so a full or congested pool answers TXPOOL_FULL /
+        DEADLINE_UNMEETABLE before the recover and rejected load costs
+        zero lane work (the Blockchain-Machine shed-at-the-front-end
+        discipline). The recover runs off the lock. The insert phase
+        decides again against live state and performs any planned
+        high-watermark evictions. Every check reads straight off the
+        column arrays (`protocol.columnar`): hashing is one `hash_batch`
+        over arena slices, recovery one `recover_addresses` over the
+        batch, and the only per-row Python object is the lazy `TxView`
+        of a row that actually ADMITS.
+
+        Invariant: a transaction that the ledger holds is never inserted,
+        whenever its block committed. The ledger look runs off the lock
+        and the lock is dropped across the recover, so the look and the
+        insert are made one decision by `_commits`: the pool is told of
+        every commit under its own lock AFTER the ledger holds the
+        receipts (`on_block_committed`), so a block the look could not
+        see moves the count noted before the look. Each phase compares
+        under its lock — one integer compare for a batch during which
+        nothing committed — and where the count moved, the rows still
+        undecided are looked up again off the lock and the compare is
+        made again, before any is judged or inserted.
+
+        Per-slice failure isolation: rows whose frames failed decode
+        reject as REQUEST_NOT_BELIEVABLE (tx_hash left empty — there is
+        no trustworthy identity to report), rows with bad signatures
+        reject INVALID_SIGNATURE, and neither poisons its batchmates.
+
+        `consensus=True` (a proposal's transactions: `fetch_missing`,
+        `verify_proposal`) BYPASSES watermark/capacity admission
+        entirely: a saturated replica refusing the leader's proposal txs
+        could not prepare and would view-change exactly while overloaded
+        — the same stall the p2p layer's protected-frame classes prevent.
+        The overshoot is bounded by one proposal's tx count, and the txs
         arrive pre-sealed (mark_sealed tombstones), so they are not
         eviction candidates either."""
         t0 = time.monotonic()
-        hashes = batch_hash(txs, self.suite)
-        results: list[Optional[TxSubmitResult]] = [None] * len(txs)
+        n = len(cols)
+        results: list[Optional[TxSubmitResult]] = [None] * n
+        rows: list[int] = []
+        for i in range(n):
+            if cols.decode_ok[i]:
+                rows.append(i)
+            else:
+                results[i] = TxSubmitResult(
+                    b"", TransactionStatus.REQUEST_NOT_BELIEVABLE)
+        hashes = cols.ensure_hashes(self.suite)
+        high = min(self._high_mark, self.pool_limit)
         need_verify: list[int] = []
-        # ledger reads OUTSIDE txpool.state: with a remote ledger/storage
-        # frontend these are RPCs, and even in-process they are GIL-held
-        # time every other submitter serialises behind (bcosflow:
-        # lock-blocking-interproc on the txpool.state hot lock)
-        current = self.ledger.current_number()
-        on_chain = [self.ledger.receipt(h) is not None for h in hashes]
-        with self._lock:
-            seen_batch: set[bytes] = set()
-            occupancy = len(self._pending)
-            victims: Optional[list] = None
-            vi = 0
-            for i, (tx, h) in enumerate(zip(txs, hashes)):
-                st = self._precheck(tx, h, current, on_chain[i])
-                if st is None and h in seen_batch:
-                    st = TransactionStatus.ALREADY_IN_TXPOOL
-                if st is None and not consensus:
-                    if victims is None and occupancy >= min(
-                            self._high_mark, self.pool_limit):
-                        victims = self._victims_locked()
-                    st, _victim, vi, occupancy = self._plan_admission_locked(
-                        occupancy, self._band(tx), tx.block_limit, current,
-                        victims, vi)
-                if st is not None:
-                    results[i] = TxSubmitResult(h, st)
-                else:
-                    seen_batch.add(h)
-                    need_verify.append(i)
+        while True:
+            mark = self._commits  # noted BEFORE the look (the invariant)
+            # ledger reads OUTSIDE txpool.state: with a remote ledger/
+            # storage frontend these are RPCs, and even in-process they
+            # are GIL-held time every other submitter serialises behind
+            # (bcosflow: lock-blocking-interproc on the hot lock)
+            current = self.ledger.current_number()
+            on_chain = {i: self.ledger.receipt(hashes[i]) is not None
+                        for i in rows}
+            with self._lock:
+                if self._commits != mark:
+                    continue  # a block landed under the look: look again
+                seen_batch: set[bytes] = set()
+                occupancy = len(self._pending)
+                victims: Optional[list] = None
+                vi = 0
+                for i in rows:
+                    h = hashes[i]
+                    st = self._precheck_fields(
+                        h, cols.chain_id[i], cols.group_id[i],
+                        int(cols.block_limit[i]), cols.nonce[i], current,
+                        on_chain[i])
+                    if st is None and h in seen_batch:
+                        st = TransactionStatus.ALREADY_IN_TXPOOL
+                    if st is None and not consensus:
+                        if victims is None and occupancy >= high:
+                            victims = self._victims_locked()
+                        st, _victim, vi, occupancy = \
+                            self._plan_admission_locked(
+                                occupancy,
+                                self._band_attr(int(cols.attribute[i])),
+                                int(cols.block_limit[i]), current,
+                                victims, vi)
+                    if st is not None:
+                        results[i] = TxSubmitResult(h, st)
+                    else:
+                        seen_batch.add(h)
+                        need_verify.append(i)
+            break
         drops: list[tuple[bytes, TransactionStatus, object]] = []
+        accepted: list = []
+        todo: list[int] = []
         if need_verify:
-            sub = [txs[i] for i in need_verify]
             # per-batch signature-recover time -> the latency attribution
-            # plane's "crypto" stage (covers the lane AND direct paths)
+            # plane's "crypto" stage (the lane's and the direct callers')
             with self.stages.stage("crypto"):
-                senders, ok = batch_recover_senders(sub, self.suite)
+                ok_mask = cols.ensure_senders(self.suite, rows=need_verify)
+            for i in need_verify:
+                if ok_mask[i]:
+                    todo.append(i)
+                else:
+                    results[i] = TxSubmitResult(
+                        hashes[i], TransactionStatus.INVALID_SIGNATURE)
+        told = False  # of a commit since the look, by the compare below
+        while todo:
+            if told:
+                # what the ledger holds by now answers ALREADY_KNOWN; the
+                # rest meets the compare again
+                mark = self._commits
+                for i in todo:
+                    if self.ledger.receipt(hashes[i]) is not None:
+                        results[i] = TxSubmitResult(
+                            hashes[i], TransactionStatus.ALREADY_KNOWN)
+                todo = [i for i in todo if results[i] is None]
             current = self.ledger.current_number()  # off-lock, as above
             with self._lock:
+                told = self._commits != mark
+                if told:
+                    continue  # look again before any row is inserted
                 occupancy = len(self._pending)
                 # the pre-crypto phase's eviction-ordered list carries
                 # over: re-sorting ~pool_limit entries under the lock
@@ -287,20 +366,22 @@ class TxPool:
                 # evicting something protected). Consumption restarts at
                 # 0: the pre-phase only SIMULATED its evictions.
                 vi = 0
-                for j, i in enumerate(need_verify):
-                    tx, h = txs[i], hashes[i]
-                    if not ok[j]:
-                        results[i] = TxSubmitResult(h, TransactionStatus.INVALID_SIGNATURE)
+                for i in todo:
+                    h = hashes[i]
+                    if h in self._pending:  # a second copy got in meanwhile
+                        results[i] = TxSubmitResult(
+                            h, TransactionStatus.ALREADY_IN_TXPOOL)
                         continue
                     victim = None
                     if not consensus:
-                        if victims is None and occupancy >= min(
-                                self._high_mark, self.pool_limit):
+                        if victims is None and occupancy >= high:
                             victims = self._victims_locked()
                         st, victim, vi, occupancy = \
                             self._plan_admission_locked(
-                                occupancy, self._band(tx), tx.block_limit,
-                                current, victims, vi)
+                                occupancy,
+                                self._band_attr(int(cols.attribute[i])),
+                                int(cols.block_limit[i]), current,
+                                victims, vi)
                         if st is not None:
                             results[i] = TxSubmitResult(h, st)
                             continue
@@ -312,163 +393,38 @@ class TxPool:
                         drops.append((victim,
                                       TransactionStatus.TXPOOL_EVICTED,
                                       task))
-                    self._pending[h] = tx
-                    self._dropped.pop(h, None)  # re-admission voids a
-                    #                             stale drop record
-                    if h in self._presealed:  # already in an in-flight
-                        self._presealed.discard(h)  # proposal: arrive sealed
-                        self._sealed.add(h)
-                    if tx.nonce:
-                        self._known_nonces.add(tx.nonce)
-                    # the batch recover above already produced the
-                    # sender — re-deriving via tx.sender(suite) under
-                    # txpool.state puts a suite_batch recover on the
-                    # hot lock's worst-case path (cache miss = crypto
-                    # under the lock every submitter waits on)
-                    results[i] = TxSubmitResult(h, TransactionStatus.OK,
-                                                senders[j])
-        self._settle_dropped(drops)
-        n_ok = sum(1 for r in results
-                   if r.status == TransactionStatus.OK)
-        metric("txpool.submit_batch", n=len(txs), ok=n_ok,
-               ms=int((time.monotonic() - t0) * 1000))
-        # traced submissions: one admission span per sampled tx context
-        # (cheap: touched only when a context is actually attached)
-        for tx in txs:
-            ctx = getattr(tx, "_otrace", None)
-            if ctx is not None and ctx.sampled:
-                otrace.TRACER.record(
-                    "txpool.admit", ctx, t0,
-                    attrs={"n": len(txs), "ok": n_ok,
-                           "group": self.group_id})
-        self._update_pending_gauge()
-        if need_verify:
-            self._notify_ready()
-        if broadcast and self._broadcast_hooks:
-            accepted = [txs[i] for i, r in enumerate(results)
-                        if r.status == TransactionStatus.OK]
-            if accepted:
-                for fn in self._broadcast_hooks:
-                    try:
-                        fn(accepted)
-                    except Exception:  # noqa: BLE001 — the txs ARE admitted
-                        # a gossip-hook failure must not surface as a
-                        # submit failure: callers (and the ingest lane's
-                        # whole coalesced cohort) would misread an
-                        # admitted batch as rejected; anti-entropy
-                        # re-gossips what this hook dropped
-                        LOG.exception(badge("TXPOOL", "broadcast-hook-failed",
-                                            n=len(accepted)))
-        return [r for r in results]
-
-    def submit_columns(self, cols, broadcast: bool = True
-                       ) -> list[TxSubmitResult]:
-        """Columnar admission: the wire-ingest hot path (ROADMAP item 1).
-
-        Mirrors `submit_batch`'s two phases — pre-crypto prechecks +
-        watermark planning under the lock, ONE batched recover off it,
-        insert phase re-validating against live state — but every check
-        reads straight off the column arrays (`protocol.columnar`): no
-        `Transaction` construction, no per-field bytes copies, no Reader
-        walks. Hashing is one `hash_batch` over arena slices and recovery
-        is one `recover_addresses` over the batch; the only per-row
-        Python object the path allocates is the lazy `TxView` for rows
-        that actually ADMIT (rejected rows never materialise anything).
-
-        Per-slice failure isolation: rows whose frames failed decode
-        reject as REQUEST_NOT_BELIEVABLE (tx_hash left empty — there is
-        no trustworthy identity to report), rows with bad signatures
-        reject INVALID_SIGNATURE, and neither poisons its batchmates."""
-        t0 = time.monotonic()
-        n = len(cols)
-        results: list[Optional[TxSubmitResult]] = [None] * n
-        rows: list[int] = []
-        for i in range(n):
-            if cols.decode_ok[i]:
-                rows.append(i)
-            else:
-                results[i] = TxSubmitResult(
-                    b"", TransactionStatus.REQUEST_NOT_BELIEVABLE)
-        hashes = cols.ensure_hashes(self.suite)
-        # ledger reads OUTSIDE txpool.state (same rationale as
-        # submit_batch: GIL-held / possibly-RPC work off the hot lock)
-        current = self.ledger.current_number()
-        on_chain = {i: self.ledger.receipt(hashes[i]) is not None
-                    for i in rows}
-        need_verify: list[int] = []
-        with self._lock:
-            seen_batch: set[bytes] = set()
-            occupancy = len(self._pending)
-            victims: Optional[list] = None
-            vi = 0
-            for i in rows:
-                h = hashes[i]
-                st = self._precheck_fields(
-                    h, cols.chain_id[i], cols.group_id[i],
-                    int(cols.block_limit[i]), cols.nonce[i], current,
-                    on_chain[i])
-                if st is None and h in seen_batch:
-                    st = TransactionStatus.ALREADY_IN_TXPOOL
-                if st is None:
-                    if victims is None and occupancy >= min(
-                            self._high_mark, self.pool_limit):
-                        victims = self._victims_locked()
-                    st, _victim, vi, occupancy = self._plan_admission_locked(
-                        occupancy, self._band_attr(int(cols.attribute[i])),
-                        int(cols.block_limit[i]), current, victims, vi)
-                if st is not None:
-                    results[i] = TxSubmitResult(h, st)
-                else:
-                    seen_batch.add(h)
-                    need_verify.append(i)
-        drops: list[tuple[bytes, TransactionStatus, object]] = []
-        accepted: list = []
-        if need_verify:
-            with self.stages.stage("crypto"):
-                ok_mask = cols.ensure_senders(self.suite, rows=need_verify)
-            current = self.ledger.current_number()  # off-lock, as above
-            with self._lock:
-                occupancy = len(self._pending)
-                vi = 0  # stale-list carryover: see submit_batch
-                for i in need_verify:
-                    h = hashes[i]
-                    if not ok_mask[i]:
-                        results[i] = TxSubmitResult(
-                            h, TransactionStatus.INVALID_SIGNATURE)
-                        continue
-                    if victims is None and occupancy >= min(
-                            self._high_mark, self.pool_limit):
-                        victims = self._victims_locked()
-                    st, victim, vi, occupancy = self._plan_admission_locked(
-                        occupancy, self._band_attr(int(cols.attribute[i])),
-                        int(cols.block_limit[i]), current, victims, vi)
-                    if st is not None:
-                        results[i] = TxSubmitResult(h, st)
-                        continue
-                    if victim is not None:
-                        task = self._drop_locked(
-                            victim, TransactionStatus.TXPOOL_EVICTED)
-                        drops.append((victim,
-                                      TransactionStatus.TXPOOL_EVICTED,
-                                      task))
                     # the FIRST (and only) per-row object on this path:
                     # the pool's pending map holds tx-shaped things, and
                     # everything downstream of admission (seal, execute,
                     # prewrite, gossip re-encode) runs on the lazy view
                     v = cols.view(i)
                     self._pending[h] = v
-                    self._dropped.pop(h, None)
-                    if h in self._presealed:
-                        self._presealed.discard(h)
-                        self._sealed.add(h)
+                    self._dropped.pop(h, None)  # re-admission voids a
+                    #                             stale drop record
+                    if h in self._presealed:  # already in an in-flight
+                        self._presealed.discard(h)  # proposal: arrive
+                        self._sealed.add(h)  # sealed
                     if cols.nonce[i]:
                         self._known_nonces.add(cols.nonce[i])
                     accepted.append(v)
-                    results[i] = TxSubmitResult(h, TransactionStatus.OK,
-                                                cols.senders[i])
+                    # the recover above already produced the sender: no
+                    # tx.sender(suite) under txpool.state (a cache miss
+                    # there is crypto under the lock every submitter
+                    # waits on)
+                    results[i] = TxSubmitResult(
+                        h, TransactionStatus.OK, cols.senders[i])
+            break
         self._settle_dropped(drops)
         metric("txpool.submit_columns", n=n, ok=len(accepted),
                ms=int((time.monotonic() - t0) * 1000))
+        # traced submissions: one admission span per sampled context
+        # (cheap: touched only when a context is actually attached)
+        for ctx in cols.traces.values():
+            if ctx.sampled:
+                otrace.TRACER.record(
+                    "txpool.admit", ctx, t0,
+                    attrs={"n": n, "ok": len(accepted),
+                           "group": self.group_id})
         self._update_pending_gauge()
         if need_verify:
             self._notify_ready()
@@ -476,30 +432,27 @@ class TxPool:
             for fn in self._broadcast_hooks:
                 try:
                     fn(accepted)
-                except Exception:  # noqa: BLE001 — same contract as
-                    # submit_batch: admitted txs must not read as rejected
+                except Exception:  # noqa: BLE001 — the txs ARE admitted
+                    # a gossip-hook failure must not surface as a submit
+                    # failure: callers (and the ingest lane's whole
+                    # coalesced cohort) would misread an admitted batch
+                    # as rejected; anti-entropy re-gossips what this
+                    # hook dropped
                     LOG.exception(badge("TXPOOL", "broadcast-hook-failed",
                                         n=len(accepted)))
         return [r for r in results]
 
-    def _precheck(self, tx: Transaction, h: bytes, current: int,
-                  on_chain: bool) -> Optional[TransactionStatus]:
-        """Cheap host-side validation (TxValidator.cpp:33-51 semantics).
+    def _precheck_fields(self, h: bytes, chain_id: str, group_id: str,
+                         block_limit: int, nonce: str, current: int,
+                         on_chain: bool) -> Optional[TransactionStatus]:
+        """Cheap host-side validation (TxValidator.cpp:33-51 semantics),
+        straight off the column arrays: a rejected row never materialises
+        a tx object at all.
 
         `on_chain` is the ledger dup-check verdict, computed by the
         caller BEFORE acquiring txpool.state: the ledger read may be a
         storage lookup (or, split-service, an RPC) and must not run
         under the pool's hot lock."""
-        return self._precheck_fields(h, tx.chain_id, tx.group_id,
-                                     tx.block_limit, tx.nonce, current,
-                                     on_chain)
-
-    def _precheck_fields(self, h: bytes, chain_id: str, group_id: str,
-                         block_limit: int, nonce: str, current: int,
-                         on_chain: bool) -> Optional[TransactionStatus]:
-        """Scalar-argument core of `_precheck`: the columnar path calls
-        this straight off the column arrays, so a rejected row never
-        materialises a tx object at all."""
         if h in self._pending or h in self._sealed:
             return TransactionStatus.ALREADY_IN_TXPOOL
         if on_chain:
@@ -516,7 +469,7 @@ class TxPool:
         return None
 
     # -- watermark admission (overload control) ----------------------------
-    def _band(self, tx: Transaction) -> int:
+    def _band_attr(self, attribute: int) -> int:
         """Client-declared priority band: the `attribute` word's top byte
         (0-255, default 0). The gas-price-band analogue — this chain has
         no fee market, so priority rides the tx attribute instead.
@@ -530,11 +483,6 @@ class TxPool:
         `[txpool] priority_bands = false` (bands ignored, eviction by
         deadline/age only), because a forged band-255 flood could
         otherwise evict other clients' pending txs for free."""
-        return self._band_attr(tx.attribute)
-
-    def _band_attr(self, attribute: int) -> int:
-        """`_band` off the raw attribute word — the columnar path reads
-        it straight from the attribute column."""
         if not self.priority_bands:
             return 0
         return (attribute >> 24) & 0xFF
@@ -545,7 +493,7 @@ class TxPool:
         soonest-expiring, insertion order breaking ties (sort stability
         over the OrderedDict scan keeps the OLDEST first). Sealed txs are
         untouchable: they ride in-flight proposals."""
-        return sorted(((self._band(t), t.block_limit, h)
+        return sorted(((self._band_attr(t.attribute), t.block_limit, h)
                        for h, t in self._pending.items()
                        if h not in self._sealed),
                       key=lambda v: (v[0], v[1]))
@@ -771,29 +719,26 @@ class TxPool:
         todo = [by_hash[h] for h in missing if h in by_hash]
         if len(todo) != len(missing):
             return False
-        _, ok = batch_recover_senders(todo, self.suite)
-        if not bool(np.all(ok)):
+        # import them, sealed, so commit can prune them. Tombstones
+        # first: each row then enters the pool already sealed, and no
+        # sealer can take it in between
+        self.mark_sealed(missing)
+        results = self.submit_batch(todo, broadcast=False, consensus=True)
+        if any(r.status == TransactionStatus.INVALID_SIGNATURE
+               for r in results):
+            self.unseal(missing)  # what did verify stays, unsealed
             return False
-        # import the newly-verified txs so commit can prune them; the
-        # ledger reads and hashing stay OFF the txpool.state hot lock
-        todo_hashes = batch_hash(todo, self.suite)
-        current = self.ledger.current_number()
-        todo_known = [self.ledger.receipt(h) is not None
-                      for h in todo_hashes]
-        with self._lock:
-            for tx, h, known in zip(todo, todo_hashes, todo_known):
-                if self._precheck(tx, h, current, known) is None:
-                    self._pending[h] = tx
-                    self._sealed.add(h)
-                    self._presealed.discard(h)
-                    if tx.nonce:
-                        self._known_nonces.add(tx.nonce)
+        with self._lock:  # rows the precheck refused leave no tombstone
+            self._presealed.difference_update(
+                r.tx_hash for r in results
+                if r.status != TransactionStatus.OK)
         return True
 
     # -- commit notification (prune + nonce window) ------------------------
     def on_block_committed(self, number: int, tx_hashes: Sequence[bytes],
                            nonces: Sequence[str]) -> None:
         with self._lock:
+            self._commits += 1  # the ledger holds the receipts by now
             for h in tx_hashes:
                 self._pending.pop(h, None)
                 self._sealed.discard(h)
@@ -829,6 +774,7 @@ class TxPool:
                      if self.ledger.receipt(h) is not None]
         nonce_window = self._fetch_nonce_window(number)  # off-lock too
         with self._lock:
+            self._commits += 1
             for h in committed:
                 self._pending.pop(h, None)
                 self._sealed.discard(h)

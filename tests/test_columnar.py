@@ -174,7 +174,11 @@ def test_columns_from_transactions_carries_caches(suite, kp):
 
 # -- admission integration ---------------------------------------------------
 
-def test_submit_columns_statuses_and_batched_crypto():
+@pytest.mark.parametrize("way_in", ["frames", "objects"])
+def test_submit_columns_statuses_and_batched_crypto(way_in):
+    """The per-row verdicts of the one admission body, for both of its
+    callers: frames through `submit_columns`, objects (as decoded off a
+    wire, nothing cached) through `submit_batch`."""
     counting = CountingSuite(make_suite(False, backend="host"))
     pool = _make_pool(counting)
     kp = counting.generate_keypair(b"columnar-admit")
@@ -182,23 +186,36 @@ def test_submit_columns_statuses_and_batched_crypto():
     bad = _tx(counting, kp, 98, valid=False)
     wires = [t.encode() for t in good[:2]] + [bad.encode(), b"junk"] + \
         [t.encode() for t in good[2:]]
+    want = [TransactionStatus.OK, TransactionStatus.OK,
+            TransactionStatus.INVALID_SIGNATURE,
+            TransactionStatus.REQUEST_NOT_BELIEVABLE,
+            TransactionStatus.OK, TransactionStatus.OK, TransactionStatus.OK]
+    if way_in == "objects":  # junk has no object form
+        del wires[3], want[3]
+
+    def submit(ws):
+        if way_in == "frames":
+            return pool.submit_columns(decode_columns(ws))
+        return pool.submit_batch([Transaction.decode(w) for w in ws])
+
     counting.recover_calls = counting.hash_batch_calls = 0
-    res = pool.submit_columns(decode_columns(wires))
-    assert [r.status for r in res] == [
-        TransactionStatus.OK, TransactionStatus.OK,
-        TransactionStatus.INVALID_SIGNATURE,
-        TransactionStatus.REQUEST_NOT_BELIEVABLE,
-        TransactionStatus.OK, TransactionStatus.OK, TransactionStatus.OK]
-    assert res[3].tx_hash == b""  # no trustworthy identity to report
+    res = submit(wires)
+    assert [r.status for r in res] == want
+    if way_in == "frames":
+        assert res[3].tx_hash == b""  # no trustworthy identity to report
     assert counting.hash_batch_calls == 1 and counting.recover_calls == 1
     assert pool.pending_count() == 5
-    # duplicate wire batch dedupes without a second recover
+    sender = good[0].sender(counting)
+    assert [r.sender for r in res if r.status == TransactionStatus.OK] == \
+        [sender] * 5
+    assert res[0].tx_hash == good[0].hash(counting)
+    # a duplicate batch dedupes without a second recover
     counting.recover_calls = 0
-    res2 = pool.submit_columns(decode_columns([t.encode() for t in good]))
+    res2 = submit([t.encode() for t in good])
     assert all(r.status == TransactionStatus.ALREADY_IN_TXPOOL
                for r in res2)
     assert counting.recover_calls == 0
-    # sealed set returns views whose re-encode is byte-identical
+    # sealed set returns tx-shaped things whose re-encode is byte-identical
     txs, hashes = pool.seal(10)
     assert sorted(t.encode() for t in txs) == \
         sorted(t.encode() for t in good)

@@ -1,7 +1,7 @@
 """Continuous-batching ingest lane (txpool/ingest.py).
 
 Asserts the lane's contract: N concurrent submitters cost FAR fewer
-device/native recover calls than N (one `submit_batch` per drained set),
+device/native recover calls than N (one `submit_columns` per drained set),
 every submitter gets its OWN admission result (including invalid-signature
 mixes), a full queue rejects with `TxPoolIsFull` instead of blocking
 forever, an idle lane adds no coalescing latency, and the tx-hash cache
@@ -47,18 +47,18 @@ class CountingSuite:
 
 
 class _GatedPool:
-    """Pool stub whose submit_batch parks on `gate` — backpressure tests
+    """Pool stub whose submit_columns parks on `gate` — backpressure tests
     use it to hold the dispatcher mid-dispatch while the queue fills."""
 
     def __init__(self):
         self.gate = threading.Event()
         self.entered = threading.Event()
 
-    def submit_batch(self, txs, broadcast=True):
+    def submit_columns(self, cols, broadcast=True):
         self.entered.set()
         assert self.gate.wait(30)
         return [TxSubmitResult(b"\x00" * 32, TransactionStatus.OK)
-                for _ in txs]
+                for _ in range(len(cols))]
 
 
 def _make_pool(suite):
@@ -163,7 +163,7 @@ def test_full_queue_rejects_not_blocks():
     lane = IngestLane(pool, max_batch=64, max_wait_ms=0.0, queue_cap=4)
     lane.start()
     try:
-        # first tx occupies the dispatcher inside the gated submit_batch
+        # first tx occupies the dispatcher inside the gated submit_columns
         first = lane.submit_async(_tx(suite, kp, 0))
         assert pool.entered.wait(10)
         # fill the queue to its cap behind the blocked dispatch
@@ -195,8 +195,8 @@ def test_idle_submit_has_no_coalescing_tax(counting_lane):
 
 
 def test_gossip_bulk_enqueue_drops_over_cap():
-    """submit_many_nowait accepts what fits and drops the rest (gossip is
-    fire-and-forget; anti-entropy re-delivers)."""
+    """submit_many_wire_nowait accepts what fits and drops the rest (gossip
+    is fire-and-forget; anti-entropy re-delivers)."""
     pool = _GatedPool()
     gate = pool.gate
     suite = make_suite(False, backend="host")
@@ -206,8 +206,8 @@ def test_gossip_bulk_enqueue_drops_over_cap():
     try:
         lane.submit_async(_tx(suite, kp, 0))
         assert pool.entered.wait(10)
-        txs = [_tx(suite, kp, 1 + i) for i in range(12)]
-        accepted = lane.submit_many_nowait(txs)
+        wires = [_tx(suite, kp, 1 + i).encode() for i in range(12)]
+        accepted = lane.submit_many_wire_nowait(wires)
         assert accepted == 8
         assert lane.stats()["dropped_total"] == 4
     finally:
@@ -291,23 +291,23 @@ def test_rpc_concurrent_clients_share_batches():
                 ).sign(counting, kp)
                 wire[c].append("0x" + tx.encode().hex())
         counting.recover_calls = 0
-        # deterministic readiness: the dispatcher's first submit_batch
+        # deterministic readiness: the dispatcher's first submit_columns
         # parks until every client's first tx is in the lane queue (or a
         # generous deadline), so the cohort coalesces regardless of how
         # the scheduler interleaves 8 client threads on 2 cores
-        orig_sb = node.txpool.submit_batch
+        orig_sb = node.txpool.submit_columns
         state = {"first": True}
 
-        def gated_submit(txs, broadcast=True):
+        def gated_submit(cols, broadcast=True):
             if state["first"]:
                 state["first"] = False
                 deadline = time.monotonic() + 10
                 while (time.monotonic() < deadline
-                       and len(txs) + len(node.ingest._q) < n_clients):
+                       and len(cols) + len(node.ingest._q) < n_clients):
                     time.sleep(0.002)
-            return orig_sb(txs, broadcast)
+            return orig_sb(cols, broadcast)
 
-        node.txpool.submit_batch = gated_submit
+        node.txpool.submit_columns = gated_submit
         receipts: dict[int, list] = {}
         errors: list[str] = []
         barrier = threading.Barrier(n_clients)
@@ -332,7 +332,7 @@ def test_rpc_concurrent_clients_share_batches():
         assert not any(th.is_alive() for th in threads), \
             "client wedged past join deadline"
         assert not errors, errors
-        node.txpool.submit_batch = orig_sb
+        node.txpool.submit_columns = orig_sb
         flat = [r for rs in receipts.values() for r in rs]
         assert len(flat) == n_clients * per_client
         assert all(r["status"] == 0 for r in flat)
@@ -362,24 +362,24 @@ def test_node_send_transaction_contract_survives_lane_conditions():
         # wedge the dispatcher, fill the 1-slot queue, then submit: the
         # lane's TxPoolIsFull must surface as a status, not an exception.
         # Deterministic readiness: `entered` proves the dispatcher is
-        # parked INSIDE submit_batch (no sleep guessing on a loaded host).
+        # parked INSIDE submit_columns (no sleep guessing on a loaded host).
         gate = threading.Event()
         entered = threading.Event()
-        orig = node.txpool.submit_batch
+        orig = node.txpool.submit_columns
 
-        def gated(txs, broadcast=True):
+        def gated(cols, broadcast=True):
             entered.set()
             gate.wait(20)
-            return orig(txs, broadcast)
+            return orig(cols, broadcast)
 
-        node.txpool.submit_batch = gated
+        node.txpool.submit_columns = gated
         node.ingest.submit_async(_tx(node.suite, kp, 1))
         assert entered.wait(10), "dispatcher never picked up the tx"
         node.ingest.submit_async(_tx(node.suite, kp, 2))  # fills cap=1
         res = node.send_transaction(_tx(node.suite, kp, 3))
         assert res.status == TransactionStatus.TXPOOL_FULL
         gate.set()
-        node.txpool.submit_batch = orig
+        node.txpool.submit_columns = orig
         # stopped lane: falls back to the pool, still a result
         node.ingest.stop()
         res = node.send_transaction(_tx(node.suite, kp, 4))

@@ -207,7 +207,7 @@ def test_seal_drops_expired_for_target_height_with_typed_status(suite, kp):
     assert [t.nonce for t in txs] == ["ov-1"]
 
 
-# -- ingest dispatcher: pre-crypto deadline shed ----------------------------
+# -- ingest dispatcher: an expired frame is answered before any crypto ------
 
 def test_ingest_dispatcher_sheds_expired_before_crypto(suite, kp):
     from fisco_bcos_tpu.txpool.ingest import _Entry
@@ -218,18 +218,18 @@ def test_ingest_dispatcher_sheds_expired_before_crypto(suite, kp):
     lane = IngestLane(pool)  # not started: dispatch driven directly
     expired = _tx(suite, kp, 0, block_limit=0)  # <= current height (0)
     live = _tx(suite, kp, 1, block_limit=50)
-    e1, e2 = _Entry(expired, Task()), _Entry(live, Task())
+    e1, e2 = _Entry(expired.encode(), Task()), _Entry(live.encode(), Task())
     before = counting.recover_calls
     lane._dispatch([e1, e2])
     r1 = e1.task.result(1.0)
     assert r1.status == TransactionStatus.BLOCK_LIMIT_CHECK_FAIL
     assert e2.task.result(1.0).status == TransactionStatus.OK
-    # exactly ONE recover: the live tx's batch; the shed entry never
-    # reached admission or the lane
+    # exactly ONE recover, of the live row alone: the pool's precheck
+    # answered the expired one before the batch verify
     assert counting.recover_calls == before + 1
 
-    # an all-expired batch costs zero crypto and zero submit_batch calls
-    e3 = _Entry(_tx(suite, kp, 2, block_limit=0), Task())
+    # an all-expired batch costs zero crypto
+    e3 = _Entry(_tx(suite, kp, 2, block_limit=0).encode(), Task())
     before = counting.recover_calls
     lane._dispatch([e3])
     assert e3.task.result(1.0).status == \
