@@ -150,6 +150,36 @@ def _pad_rows(a: np.ndarray, n: int) -> np.ndarray:
     return np.concatenate([a, pad], axis=0)
 
 
+def _frame(rows: Sequence[bytes], width: int, fix) -> np.ndarray:
+    """The rows as one uint8[N, width] array: one join, one view, no copy
+    per row. A row of another length is malformed (served traffic has
+    none) and goes through `fix` first, which pads or cuts it to `width`
+    or raises."""
+    lens = np.fromiter(map(len, rows), np.intp, len(rows))
+    odd = np.flatnonzero(lens != width)
+    if odd.size:
+        rows = list(rows)
+        for i in odd.tolist():
+            rows[i] = fix(bytes(rows[i]))
+    return np.frombuffer(b"".join(rows), np.uint8).reshape(len(rows), width)
+
+
+def _fix_digest(d: bytes) -> bytes:
+    """A digest of another length as the 32-byte row of the same integer:
+    left-padded with zeros; one that does not fit 256 bits is an error of
+    the call (`bigint.to_limbs` raises the same)."""
+    if any(d[:-DIGEST]):
+        raise ValueError(f"out of range for {bigint.NLIMBS} limbs: "
+                         f"digest of {len(d)} bytes")
+    return d[-DIGEST:].rjust(DIGEST, b"\x00")
+
+
+def _fix_pub(p: bytes) -> bytes:
+    """A key of another length as qx = p[:32], qy = p[32:64], each the
+    32-byte row of the same integer."""
+    return p[:32].rjust(32, b"\x00") + p[32:64].rjust(32, b"\x00")
+
+
 @dataclasses.dataclass(frozen=True)
 class KeyPair:
     """Node/account key pair. secret stays host-side (signing is host-only)."""
@@ -289,8 +319,10 @@ class CryptoSuite:
     def _on_device(self, op: str, n: int, lanes: int, t_in: float, call,
                    unpack=None):
         """Run one device-path call of `n` items in `lanes` lanes, packed
-        since `t_in`: `call()` issues the kernel and returns its outputs
-        as numpy, `unpack(outputs)` makes the values the caller returns.
+        since `t_in` (for the EC ops that is `_stage`, where bytes become
+        limbs, and `_pad_chunks`): `call()` issues the kernel and returns
+        its outputs as numpy, `unpack(outputs)` makes the values the caller
+        returns (for recover, where limbs become bytes).
         Inputs were validated and packed on the host, so whatever `call`
         raises is a compile, lowering or device failure: wrapped as
         DeviceError and reported to the observers. One clock read per
@@ -536,13 +568,34 @@ class CryptoSuite:
         return max(b, mk.n_devices) if mk is not None else b
 
     def _split_sigs(self, sigs: Sequence[bytes]):
-        """r, s scalars per sig; malformed (short) sigs become r=s=0, which
-        every verify/recover path rejects as invalid."""
+        """r, s scalars per sig, for the host door's integer calls (the
+        device path stages arrays: `_stage`); malformed (short) sigs become
+        r=s=0, which every verify/recover path rejects as invalid."""
         rs = [int.from_bytes(g[:32], "big") if len(g) >= self.signature_size
               else 0 for g in sigs]
         ss = [int.from_bytes(g[32:64], "big") if len(g) >= self.signature_size
               else 0 for g in sigs]
         return rs, ss
+
+    def _stage(self, digests: Sequence[bytes], sigs: Sequence[bytes],
+               pubs: Sequence[bytes] | None = None) -> list[np.ndarray]:
+        """The EC kernels' operands for a batch, where bytes become limbs:
+        e, r, s as uint32[N, 16] (`bigint.rows_to_limbs` over the joined
+        rows) and then v as uint32[N] (recover) or qx, qy from `pubs`
+        (verify). Array for array what `bigint.batch_to_limbs` gives for
+        the integers of `_split_sigs`: a signature shorter than
+        `signature_size` stages as r = s = 0 (v = 255), a longer one is
+        cut, a short digest or key is the same integer."""
+        ssz = self.signature_size
+        bad = bytes(64) + b"\xff" + bytes(ssz - 65)  # r = s = 0, v = 255
+        e = _frame(digests, DIGEST, _fix_digest)
+        g = _frame(sigs, ssz, lambda x: x[:ssz] if len(x) > ssz else bad)
+        cols = [bigint.rows_to_limbs(c) for c in (e, g[:, :32], g[:, 32:64])]
+        if pubs is None:
+            return cols + [g[:, 64].astype(np.uint32)]
+        q = _frame(pubs, 64, _fix_pub)
+        return cols + [bigint.rows_to_limbs(q[:, :32]),
+                       bigint.rows_to_limbs(q[:, 32:])]
 
     def _pad_chunks(self, n: int, cols: list) -> list:
         """The kernel's operands for n rows: one bucket-padded set up to
@@ -573,21 +626,22 @@ class CryptoSuite:
                      pubs: Sequence[bytes]) -> np.ndarray:
         """-> bool[N]. For ecdsa, pubs are 64-byte uncompressed keys; sigs may
         carry a trailing v byte (ignored for verify). For sm, the pub embedded
-        in the signature is ignored in favour of the explicit pubs arg."""
+        in the signature is ignored in favour of the explicit pubs arg.
+        The host door takes integers (`_split_sigs`); the device door takes
+        the rows as whole arrays (`_stage`), the same values limb for limb."""
         n = len(digests)
         assert len(sigs) == n and len(pubs) == n
         if n == 0:
             return np.zeros((0,), bool)
         _lc.note_blocking("suite_batch", "verify_batch")
-        t_in = time.monotonic()
-        rs, ss = self._split_sigs(sigs)
-        qx = [int.from_bytes(p[:32], "big") for p in pubs]
-        qy = [int.from_bytes(p[32:64], "big") for p in pubs]
-        es = [int.from_bytes(d, "big") for d in digests]
         if not self._use_device(n):
             from . import nativeec
 
             self._count_host("verify", n, nativeec.parts_of(n))
+            rs, ss = self._split_sigs(sigs)
+            qx = [int.from_bytes(p[:32], "big") for p in pubs]
+            qy = [int.from_bytes(p[32:64], "big") for p in pubs]
+            es = [int.from_bytes(d, "big") for d in digests]
             if self.kind == "ecdsa":
                 native = nativeec.ecdsa_verify_batch(es, rs, ss, qx, qy)
                 if native is not None:
@@ -603,14 +657,14 @@ class CryptoSuite:
                 refimpl.sm2_verify((x, y), d, r, s)
                 for x, y, d, r, s in zip(qx, qy, digests, rs, ss)
             ])
+        t_in = time.monotonic()
         mk = self._mesh()
         if mk is not None:
             fn = (mk.verify if self.kind == "ecdsa" else mk.sm2_verify)
         else:
             fn = (ec.ecdsa_verify_batch if self.kind == "ecdsa"
                   else ec.sm2_verify_batch)
-        chunks = self._pad_chunks(n, [bigint.batch_to_limbs(c)
-                                      for c in (es, rs, ss, qx, qy)])
+        chunks = self._pad_chunks(n, self._stage(digests, sigs, pubs))
         return self._on_device(
             "verify", n, self._lanes(chunks), t_in,
             lambda: self._run_chunks(fn, chunks))[0]
@@ -622,19 +676,22 @@ class CryptoSuite:
         The reference's tx hot path (Transaction.h:68-82): recover sender key
         from signature. For sm suites the signature carries the pubkey
         (SignatureDataWithPub.h) — recovery degenerates to verify + extract.
+
+        Neither door makes an integer of a well-formed row: the host door
+        hands the joined rows to the C side, the device door makes limbs of
+        them in `_stage` and bytes of the recovered limbs in `pub_bytes`
+        (`bigint.rows_to_limbs` / `limbs_to_rows`), a whole column a pass.
         """
         n = len(digests)
         assert len(sigs) == n
         _lc.note_blocking("suite_batch", "recover_batch")
         if n == 0:
             return [], np.zeros((0,), bool)
-        t_in = time.monotonic()
         if self.kind == "sm":
             pubs = [g[64:128] if len(g) >= 128 else b"\x00" * 64 for g in sigs]
             ok = self.verify_batch(digests, sigs, pubs)
             return [p if o else None for p, o in zip(pubs, ok)], ok
-        device = self._use_device(n)
-        if not device:
+        if not self._use_device(n):
             from . import nativeec
 
             self._count_host("recover", n, nativeec.parts_of(n))
@@ -646,7 +703,9 @@ class CryptoSuite:
                 # both sides of the FFI) disappears — slices of the
                 # columnar arena feed the join directly. Malformed rows
                 # degrade to r=s=0 / v=255, rejected by the C side the
-                # same way _split_sigs' zeros are.
+                # same way _split_sigs' zeros are. The device door below
+                # reads the same rows as arrays (`_stage`): no integer
+                # per signature on either door.
                 ssz = self.signature_size
                 native = nativeec.ecdsa_recover_batch_rows(
                     b"".join(digests),
@@ -657,10 +716,9 @@ class CryptoSuite:
                     bytes(g[64] if len(g) >= 65 else 255 for g in sigs))
                 if native is not None:
                     return native[0], np.array(native[1])
-        rs, ss = self._split_sigs(sigs)
-        vs = [g[64] if len(g) >= 65 else 255 for g in sigs]
-        es = [int.from_bytes(d, "big") for d in digests]
-        if not device:
+            rs, ss = self._split_sigs(sigs)
+            vs = [g[64] if len(g) >= 65 else 255 for g in sigs]
+            es = [int.from_bytes(d, "big") for d in digests]
             native = nativeec.ecdsa_recover_batch(es, rs, ss, vs)
             if native is not None:
                 return native[0], np.array(native[1])
@@ -672,17 +730,18 @@ class CryptoSuite:
                 out.append(Q[0].to_bytes(32, "big") + Q[1].to_bytes(32, "big")
                            if good else None)
             return out, np.array(okl)
-        cols = [bigint.batch_to_limbs(c) for c in (es, rs, ss)]
-        cols.append(np.array(vs, np.uint32))
+        t_in = time.monotonic()
         mk = self._mesh()
         rec = mk.recover if mk is not None else ec.ecdsa_recover_batch
-        chunks = self._pad_chunks(n, cols)
+        chunks = self._pad_chunks(n, self._stage(digests, sigs))
 
         def pub_bytes(outs):
+            # limbs become bytes here: one uint8[N, 64] array, cut by row
             qx, qy, ok = outs
-            return [bigint.from_limbs(qx[i]).to_bytes(32, "big")
-                    + bigint.from_limbs(qy[i]).to_bytes(32, "big")
-                    if ok[i] else None for i in range(n)], ok
+            buf = np.concatenate([bigint.limbs_to_rows(qx),
+                                  bigint.limbs_to_rows(qy)], axis=1).tobytes()
+            return [buf[o:o + 64] if good else None
+                    for o, good in zip(range(0, 64 * n, 64), ok.tolist())], ok
 
         return self._on_device(
             "recover", n, self._lanes(chunks), t_in,

@@ -82,6 +82,20 @@ def batch_to_limbs(xs) -> np.ndarray:
     return np.stack([to_limbs(int(x)) for x in xs], axis=0)
 
 
+def rows_to_limbs(rows: np.ndarray) -> np.ndarray:
+    """uint8[N, 32] big-endian rows -> [N, NLIMBS] uint32, `to_limbs`'
+    layout, in one pass over the array (no integer per row). `rows` may be
+    a column slice of a wider frame."""
+    return rows.view(">u2")[:, ::-1].astype(np.uint32, order="C")
+
+
+def limbs_to_rows(a: np.ndarray) -> np.ndarray:
+    """[N, NLIMBS] canonical limbs, in any memory layout (a device output
+    may come back column-major) -> C-contiguous uint8[N, 32] big-endian
+    rows: the inverse of `rows_to_limbs`."""
+    return np.asarray(a)[:, ::-1].astype(">u2", order="C").view(np.uint8)
+
+
 # ---------------------------------------------------------------------------
 # raw 256-bit ops (vectorised over leading axes)
 # ---------------------------------------------------------------------------
