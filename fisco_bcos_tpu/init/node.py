@@ -89,8 +89,11 @@ class NodeConfig:
     overload_busy_write_factor: float = 0.25  # write-rate shrink while busy
     # compaction-debt backpressure: debt bytes (engine levels over target)
     # scoring 1.0 on the overload plane — a compaction-starved node goes
-    # busy and sheds writes instead of silently drowning in L0 segments
-    overload_compact_debt_mb: int = 256
+    # busy and sheds writes instead of silently drowning in L0 segments.
+    # 0 = from the engine's geometry, `compact_debt_cap_mb`: the debt jumps
+    # to a whole L0 each time a routine merge triggers, and that is the
+    # compactor at work, not starved
+    overload_compact_debt_mb: int = 0
     client_write_rate: float = 0.0
     client_write_burst: float = 0.0  # 0 -> 2x rate
     client_read_rate: float = 0.0
@@ -232,6 +235,20 @@ class NodeConfig:
     failpoints: str = ""
 
 
+def compact_debt_cap_mb(cfg: NodeConfig) -> int:
+    """Compaction debt (MB) that scores 1.0 on the overload plane where
+    `overload_compact_debt_mb` is 0: four times what L0 holds when a
+    routine merge triggers (`compact_segments` + 1 flushed memtables). The
+    engine counts a whole over-full L0 as debt, so every routine merge
+    shows one such L0 for the seconds it takes: with a fixed cap of 256 MB
+    a node at the default geometry (9 x 64 MB) went busy — no gossip
+    imported, writes shed — at each of them, and a chain whose four nodes
+    merge together stalled for seconds (PERF.md, PR 34). RocksDB compacts
+    at 4 L0 files and slows writes at 20: the same ratio of five."""
+    return 4 * (cfg.storage_compact_segments + 1) * max(
+        1, cfg.storage_memtable_mb)
+
+
 class Node:
     def __init__(self, config: NodeConfig | None = None,
                  keypair=None, suite: CryptoSuite | None = None,
@@ -346,7 +363,8 @@ class Node:
             # simply contribute nothing.
             debt_fn = getattr(self.storage, "compaction_debt_bytes", None)
             if debt_fn is not None:
-                debt_norm = max(1, cfg.overload_compact_debt_mb) << 20
+                debt_norm = (cfg.overload_compact_debt_mb
+                             or compact_debt_cap_mb(cfg)) << 20
                 self.overload.add_signal(
                     "compaction_debt",
                     lambda: debt_fn() / debt_norm)

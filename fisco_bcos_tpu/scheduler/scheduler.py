@@ -604,9 +604,11 @@ class Scheduler:
             self._commit_busy = True
             try:
                 fp.fire("scheduler.2pc.prepare")
-                self.storage.prepare(number, changes)
+                with blk.stage("storage_prepare"):
+                    self.storage.prepare(number, changes)
                 fp.fire("scheduler.2pc.commit")
-                self.storage.commit(number)
+                with blk.stage("storage_commit"):
+                    self.storage.commit(number)
             except Exception as exc:
                 LOG.exception(badge("SCHED", "commit-2pc-failed",
                                     number=number))

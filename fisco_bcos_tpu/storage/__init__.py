@@ -7,7 +7,8 @@ bcos-storage/RocksDBStorage.h:64-68) and the StateStorage/KeyPageStorage
 overlays (bcos-table/src/). The persistent slot has two fills: WalStorage
 (snapshot + full-log replay, small states) and DiskStorage (log-structured
 segments + manifest, storage/engine.py — restart flat in chain length,
-datasets beyond RAM), selected by the `[storage] backend` ini knob.
+datasets beyond RAM: the page layer over it, storage/keypage.py, holds a
+bounded LRU of pages), selected by the `[storage] backend` ini knob.
 """
 
 from typing import Optional
@@ -29,7 +30,9 @@ def __getattr__(name):  # lazy: engine pulls in sstable/compact machinery
     raise AttributeError(name)
 
 
-DEFAULT_KEY_PAGE_SIZE = 8 << 10  # auto page size for the disk backend
+# auto page size for the disk backend: what upstream's build_chain.sh
+# writes into every config.ini (`[storage] key_page_size=10240`)
+DEFAULT_KEY_PAGE_SIZE = 10240
 
 
 def make_storage(backend: str, path: Optional[str],
@@ -47,6 +50,8 @@ def make_storage(backend: str, path: Optional[str],
     default, ini `key_page_size = auto`) turns paging ON for the disk
     backend — wide tables are the norm at production scale, and the page
     layout is what keeps their range scans at O(pages) backend reads.
+    The page layer caches at most `keypage.PAGE_CACHE_BYTES` of pages and
+    leaves `keypage.UNPAGED_TABLES` row by row.
     `level_base_mb`/`level_fanout` shape the disk engine's leveled
     compaction (L1 byte target and per-level growth factor).
     `health` (utils/health.py) receives the persistent backends' ENOSPC /

@@ -49,6 +49,7 @@ engine through storage/namespace.py unchanged.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import struct
 import time
@@ -161,6 +162,16 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
         self._bloom_probes = 0
         self._bloom_skips = 0
         self._bloom_pub = (0, 0)
+        # what the engine's own work costs, read as deltas over a window
+        # (stats()): a stamp a flush or a merge, and a wait for the state
+        # lock only where it is contended — never a clock read a row
+        self._t_open = time.monotonic()
+        self._flushes = 0
+        self._flush_secs = 0.0
+        self._merges = 0
+        self._merge_secs = 0.0
+        self._stall_secs = 0.0  # commits and reads waiting on those two
+        self._at_edge = False
         # test fail-points: names added here raise _FailPoint when crossed
         self._failpoints: set[str] = set()
         self._recover()
@@ -180,6 +191,27 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
         fp.fire("storage.engine." + name.replace("-", "_"))
         if name in self._failpoints:
             raise DiskStorage._FailPoint(name)
+
+    def _enter(self) -> None:
+        """Take the state lock for a commit or a read; where a flush's or
+        a merge's manifest edge holds it, the wait is a stall."""
+        if not self._lock.acquire(blocking=False):
+            at_edge = self._at_edge
+            t0 = time.monotonic()
+            self._lock.acquire()
+            if at_edge:
+                self._stall_secs += time.monotonic() - t0
+
+    @contextlib.contextmanager
+    def _edge(self):
+        """The state lock for a manifest edge of a flush or a merge: the
+        segment lists change and the manifest is fsynced under it."""
+        with self._lock:
+            self._at_edge = True
+            try:
+                yield
+            finally:
+                self._at_edge = False
 
     def _wal_append(self, block_number: int, cs: ChangeSet) -> None:
         """WAL append with the ENOSPC -> health edge: a full disk reports
@@ -354,7 +386,8 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
     def get(self, table: str, key: bytes) -> Optional[bytes]:
         ck = composite_key(table, key)
         for _ in range(3):  # retry if a compaction closed a reader mid-read
-            with self._lock:
+            self._enter()
+            try:
                 if ck in self._mem:
                     v = self._mem[ck]
                     return v
@@ -362,6 +395,8 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
                     if ck in frozen:
                         return frozen[ck]
                 segs = self._flat_locked()
+            finally:
+                self._lock.release()
             probes = skips = 0
             try:
                 for r in reversed(segs):
@@ -502,10 +537,13 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
                                 for k in ks})
 
     def _write_direct(self, cs: ChangeSet) -> None:
-        with self._lock:
+        self._enter()
+        try:
             self._wal_append(0, cs)
             self._apply_changeset_locked(cs)
             need_flush = self._mem_bytes >= self.memtable_bytes
+        finally:
+            self._lock.release()
         if need_flush:
             self._flush_after_write()
 
@@ -515,12 +553,15 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
             self._prepared[block_number] = dict(changes)
 
     def commit(self, block_number: int) -> None:
-        with self._lock:
+        self._enter()
+        try:
             cs = self._prepared.pop(block_number)
             self._wal_append(block_number, cs)
             self._apply_changeset_locked(cs)
             need_flush = self._mem_bytes >= self.memtable_bytes
             self._publish_commit_gauges_locked()
+        finally:
+            self._lock.release()
         if need_flush:
             self._flush_after_write()
 
@@ -528,7 +569,10 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
         """Watermark-crossing flush AFTER a durable WAL append. A flush
         failure here must NOT surface as a commit/write failure — the data
         is already durable in the un-retired WAL; report `storage.flush`
-        degraded and keep retrying via the health probe until it lands."""
+        degraded and keep retrying via the health probe until it lands.
+        The writer runs the flush itself (none is deferred): its whole
+        wait is a stall."""
+        t0 = time.monotonic()
         try:
             self.flush()
         except Exception as exc:  # noqa: BLE001 — deliberate containment
@@ -536,6 +580,8 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
             if self.health is not None:
                 self.health.degraded("storage.flush", repr(exc),
                                      probe=self._flush_probe)
+        finally:
+            self._stall_secs += time.monotonic() - t0
 
     def _flush_probe(self) -> bool:
         self.flush()  # raises while the fault persists -> stays degraded
@@ -556,6 +602,7 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
             with self._lock:
                 if not self._mem:
                     return False
+                t0 = time.monotonic()
                 frozen = self._mem
                 self._mem = {}
                 self._mem_bytes = 0
@@ -573,7 +620,7 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
                 reader = SSTableReader(self._seg_path(seg_id))
                 reader.seg_id = seg_id
                 reader.level = 0
-                with self._lock:
+                with self._edge():
                     self._levels[0].append(reader)
                     self._frozen.remove(frozen)
                     self._wal_floor = floor
@@ -594,8 +641,13 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
                             len(ck) + (len(v) if v else 0) + 16
                             for ck, v in frozen.items())
                 raise
+            secs = time.monotonic() - t0
+            with self._lock:
+                self._flushes += 1
+                self._flush_secs += secs
             LOG.info(badge("ENGINE", "flushed", segment=seg_id,
-                           records=stats["records"], bytes=stats["bytes"]))
+                           records=stats["records"], bytes=stats["bytes"],
+                           ms=int(secs * 1000)))
             self._publish_gauges()
             return True
 
@@ -747,7 +799,7 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
                     reader.seg_id = seg_id
                     outputs.append(reader)
                 self._maybe_fail("compact-before-manifest")
-                with self._lock:
+                with self._edge():
                     flat = self._flat_locked()
                     if any(s not in flat for s in inputs):
                         # install_rows swapped the state mid-merge: the
@@ -818,6 +870,8 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
                     "inputs": len(inputs), "outputs": len(outputs),
                     "src_level": src_level}
                 self._max_merge_secs = max(self._max_merge_secs, secs)
+                self._merges += 1
+                self._merge_secs += secs
             self._reg.inc("bcos_storage_compactions_total")
             self._reg.observe("bcos_storage_compaction_seconds", secs)
             LOG.info(badge("ENGINE", "compacted", level=src_level,
@@ -1024,6 +1078,12 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
             mem_bytes = self._mem_bytes
             last_merge = dict(self._last_merge)
             max_merge_secs = round(self._max_merge_secs, 4)
+            work = {"flushes": self._flushes,
+                    "flush_seconds": self._flush_secs,
+                    "merges": self._merges,
+                    "merge_seconds": self._merge_secs,
+                    "stall_seconds": self._stall_secs,
+                    "open_seconds": time.monotonic() - self._t_open}
         probes, skips = self._bloom_probes, self._bloom_skips
         return {
             "backend": "disk",
@@ -1039,6 +1099,7 @@ class DiskStorage(TransactionalStorage, _SpaceHealth):
             "bloom_probes": probes,
             "bloom_skips": skips,
             "bloom_skip_rate": round(skips / probes, 4) if probes else None,
+            **work,
         }
 
     def _publish_commit_gauges_locked(self) -> None:

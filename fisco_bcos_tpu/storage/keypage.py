@@ -12,18 +12,25 @@ Layout in the backend:
   * per table, a meta row ``_kp_/meta`` holds the sorted list of page-start
     keys (u32 count, then length-prefixed keys);
   * each page lives at ``_kp_/p/<start-key>`` and holds its rows sorted
-    (u32 count, then (u32 klen, key, u32 vlen, val)*).
+    (u32 count, then (u32 klen, key, u32 vlen, val)*);
+  * the tables of `UNPAGED_TABLES` keep their rows as they are.
 
 Row-level 2PC changesets are translated into page-level changesets at
 `prepare`, so the wrapped TransactionalStorage (WalStorage / NativeStorage /
-DiskStorage) commits pages atomically with everything else.
+DiskStorage) commits pages atomically with everything else. A translation
+stages only the pages and page indexes its rows touch (`_Staged`): its cost
+follows the block, not what the node has read or written before.
+
+Parsed pages are held in an LRU bounded by `PAGE_CACHE_BYTES` of packed
+page bytes (the reference keeps an LRU `CacheStorage` over RocksDB); a
+state larger than that is served from the backend, page by page.
 
 As the disk engine's value layout (`[storage] key_page_size > 0`,
 storage/__init__.py make_storage) this is what makes wide tables cheap:
 a `keys(prefix)` range scan touches the pages covering the prefix range —
 typically ONE backend read — instead of a per-row walk, and the engine
 sees few large values (better block packing, fewer bloom probes).
-`stats()` exposes the backend read counters the unit tests pin down.
+`stats()` exposes the counters the unit tests and the benchmark read.
 """
 
 from __future__ import annotations
@@ -31,35 +38,62 @@ from __future__ import annotations
 import bisect
 import struct
 import threading
-from typing import Iterator, Optional
+import time
+from collections import OrderedDict
+from typing import Iterable, Iterator, Optional
 
 from .interface import ChangeSet, Entry, EntryStatus, TransactionalStorage
 
 META_KEY = b"_kp_/meta"
 PAGE_PREFIX = b"_kp_/p/"
 
+# Packed bytes of parsed pages a node keeps (LRU). Not an ini key: the
+# reference sizes its CacheStorage in code too. 16 MiB is ~3,000 pages of
+# accounts: a block of 1,000 transfers touches ~2,000 pages at most, so one
+# block's working set stays resident between `execute` and `prepare`,
+# while a state of millions of accounts (chipbench's air4-transfer-disk:
+# 72 MB of rows) does not fit and is read from the engine.
+PAGE_CACHE_BYTES = 16 << 20
+
+# Tables that stay row by row under the page layer: keyed by a hash or a
+# block number, read by point lookups, never range-scanned by prefix over
+# neighbouring rows. Paging them would cost a page read and a ~10 KB page
+# rewrite for every random key of a block. The reference's Initializer
+# hands KeyPageStorage such an ignore list of ledger tables (written from
+# memory of it: s_hash_2_tx, s_hash_2_receipt, s_number_2_txs, the
+# number/hash/header/nonce tables, s_config, s_consensus; no reference
+# tree is on this machine). The names are ledger/ledger.py's, the zk
+# plane's and consensus/pbft/storage.py's; storage imports none of them.
+UNPAGED_TABLES = frozenset({
+    "s_number_2_header", "s_hash_2_number", "s_number_2_txs", "s_hash_2_tx",
+    "s_hash_2_receipt", "s_number_2_nonces", "s_number_2_statehash",
+    "s_current_state", "s_config", "s_consensus", "s_snapshot_state",
+    "c_pbft_log"})
+
+_U32 = struct.Struct("<I")
+_EMPTY_PAGE_BYTES = 4  # the row count alone
+
 
 def _pack_page(rows: dict[bytes, bytes]) -> bytes:
-    parts = [struct.pack("<I", len(rows))]
+    pack = _U32.pack
+    parts = [pack(len(rows))]
     for k in sorted(rows):
         v = rows[k]
-        parts.append(struct.pack("<I", len(k)))
-        parts.append(k)
-        parts.append(struct.pack("<I", len(v)))
-        parts.append(v)
+        parts += (pack(len(k)), k, pack(len(v)), v)
     return b"".join(parts)
 
 
 def _unpack_page(data: bytes) -> dict[bytes, bytes]:
-    (n,) = struct.unpack_from("<I", data, 0)
+    unpack = _U32.unpack_from
+    (n,) = unpack(data, 0)
     off = 4
     rows: dict[bytes, bytes] = {}
     for _ in range(n):
-        (kl,) = struct.unpack_from("<I", data, off)
+        (kl,) = unpack(data, off)
         off += 4
         k = data[off:off + kl]
         off += kl
-        (vl,) = struct.unpack_from("<I", data, off)
+        (vl,) = unpack(data, off)
         off += 4
         rows[k] = data[off:off + vl]
         off += vl
@@ -67,40 +101,92 @@ def _unpack_page(data: bytes) -> dict[bytes, bytes]:
 
 
 def _pack_meta(starts: list[bytes]) -> bytes:
-    parts = [struct.pack("<I", len(starts))]
+    pack = _U32.pack
+    parts = [pack(len(starts))]
     for s in starts:
-        parts.append(struct.pack("<I", len(s)))
-        parts.append(s)
+        parts += (pack(len(s)), s)
     return b"".join(parts)
 
 
 def _unpack_meta(data: bytes) -> list[bytes]:
-    (n,) = struct.unpack_from("<I", data, 0)
+    (n,) = _U32.unpack_from(data, 0)
     off = 4
     out = []
     for _ in range(n):
-        (sl,) = struct.unpack_from("<I", data, off)
+        (sl,) = _U32.unpack_from(data, off)
         off += 4
         out.append(data[off:off + sl])
         off += sl
     return out
 
 
+class _Page:
+    """A parsed page and the bytes it packs to."""
+
+    __slots__ = ("rows", "size")
+
+    def __init__(self, rows: dict[bytes, bytes], size: Optional[int] = None):
+        self.rows = rows
+        self.size = size if size is not None else _EMPTY_PAGE_BYTES + sum(
+            8 + len(k) + len(v) for k, v in rows.items())
+
+    def copy(self) -> "_Page":
+        return _Page(dict(self.rows), self.size)
+
+    def put(self, key: bytes, value: Optional[bytes]) -> None:
+        """Set a row, or drop it where `value` is None."""
+        old = self.rows.get(key)
+        if value is None:
+            if old is not None:
+                del self.rows[key]
+                self.size -= 8 + len(key) + len(old)
+            return
+        self.rows[key] = value
+        self.size += len(value) - len(old) if old is not None \
+            else 8 + len(key) + len(value)
+
+
+class _Staged:
+    """What one translation touched, over the committed state: private
+    copies of the touched tables' page indexes and of the touched pages,
+    the pages it dropped, and the rows of unpaged tables it passes on."""
+
+    __slots__ = ("meta", "pages", "dropped", "dirty_meta", "rows")
+
+    def __init__(self):
+        self.meta: dict[str, list[bytes]] = {}
+        self.pages: dict[tuple[str, bytes], _Page] = {}
+        self.dropped: set[tuple[str, bytes]] = set()
+        self.dirty_meta: set[str] = set()
+        self.rows: ChangeSet = {}
+
+
 class KeyPageStorage(TransactionalStorage):
     """Row-level TransactionalStorage over a page-level backend."""
 
     def __init__(self, backend: TransactionalStorage,
-                 page_size: int = 10 * 1024):
+                 page_size: int = 10 * 1024,
+                 cache_bytes: int = PAGE_CACHE_BYTES):
         self.backend = backend
         self.page_size = page_size
+        self.cache_bytes = cache_bytes
         self._lock = threading.RLock()
         self._meta: dict[str, list[bytes]] = {}  # table -> page starts
-        self._pages: dict[tuple[str, bytes], dict[bytes, bytes]] = {}  # cache
-        self._staged: dict[int, tuple[dict, dict]] = {}  # block -> (meta, pages)
+        # parsed pages, least recently used first
+        self._pages: OrderedDict[tuple[str, bytes], _Page] = OrderedDict()
+        self._cached_bytes = 0
+        self._staged: dict[int, _Staged] = {}  # block -> its translation
         # read-amplification accounting: backend reads vs rows served —
         # the property the page layout exists for, pinned by unit tests
         self._backend_reads = 0
         self._cache_hits = 0
+        self._read_seconds = 0.0   # backend page reads, parse included
+        self._evictions = 0
+        self._page_bytes_written = 0  # pages + page indexes to the backend
+
+    def _paged(self, table: str) -> bool:
+        # a group's view prefixes its tables `g/<group>/` (namespace.py)
+        return table.rpartition("/")[2] not in UNPAGED_TABLES
 
     # -- page plumbing -----------------------------------------------------
     def _meta_for(self, table: str) -> list[bytes]:
@@ -112,60 +198,103 @@ class KeyPageStorage(TransactionalStorage):
             self._meta[table] = m
         return m
 
-    def _page_rows(self, table: str, start: bytes) -> dict[bytes, bytes]:
+    def _page(self, table: str, start: bytes, reading: bool = True) -> _Page:
+        """The committed page, from the cache or the backend. `cache_hits`
+        counts row reads (`get`, `keys`) a cached page answered; a
+        translation's look at the page it is about to rewrite (`reading`
+        False) is no read, and counts only where it has to go to the
+        backend."""
         ck = (table, start)
-        rows = self._pages.get(ck)
-        if rows is None:
-            raw = self.backend.get(table, PAGE_PREFIX + start)
-            self._backend_reads += 1
-            rows = _unpack_page(raw) if raw else {}
-            self._pages[ck] = rows
-        else:
-            self._cache_hits += 1
-        return rows
+        page = self._pages.get(ck)
+        if page is not None:
+            self._cache_hits += reading
+            self._pages.move_to_end(ck)
+            return page
+        t0 = time.perf_counter()
+        raw = self.backend.get(table, PAGE_PREFIX + start)
+        page = _Page(_unpack_page(raw), len(raw)) if raw else _Page({})
+        self._read_seconds += time.perf_counter() - t0
+        self._backend_reads += 1
+        self._cache_put(ck, page)
+        return page
 
-    @staticmethod
-    def _page_index(meta: list[bytes], key: bytes) -> int:
-        """Index of the page whose range covers `key` (-1 if none)."""
-        i = bisect.bisect_right(meta, key) - 1
-        return i
+    def _cache_put(self, ck: tuple[str, bytes], page: _Page) -> None:
+        """Hold `page` as the most recently used, then drop the least
+        recently used ones down to the budget (never the one just put)."""
+        self._cache_drop(ck)
+        self._pages[ck] = page
+        self._cached_bytes += page.size
+        while self._cached_bytes > self.cache_bytes and len(self._pages) > 1:
+            _, gone = self._pages.popitem(last=False)
+            self._cached_bytes -= gone.size
+            self._evictions += 1
+
+    def _cache_drop(self, ck: tuple[str, bytes]) -> None:
+        gone = self._pages.pop(ck, None)
+        if gone is not None:
+            self._cached_bytes -= gone.size
 
     # -- row-level ops (direct, non-transactional path) --------------------
     def get(self, table: str, key: bytes) -> Optional[bytes]:
+        if not self._paged(table):
+            return self.backend.get(table, key)
         with self._lock:
             meta = self._meta_for(table)
-            i = self._page_index(meta, key)
+            i = bisect.bisect_right(meta, key) - 1
             if i < 0:
                 return None
-            return self._page_rows(table, meta[i]).get(key)
+            return self._page(table, meta[i]).rows.get(key)
 
     def set(self, table: str, key: bytes, value: bytes) -> None:
-        with self._lock:
-            cs = self._translate(
-                {(table, key): Entry(value, EntryStatus.NORMAL)},
-                self._meta, self._pages)
-            for (t, k), e in cs.items():
-                if e.deleted:
-                    self.backend.remove(t, k)
-                else:
-                    self.backend.set(t, k, e.value)
+        self.set_batch(table, ((key, value),))
 
     def remove(self, table: str, key: bytes) -> None:
+        self.remove_batch(table, (key,))
+
+    def set_batch(self, table: str,
+                  items: Iterable[tuple[bytes, bytes]]) -> None:
+        if not self._paged(table):
+            self.backend.set_batch(table, items)
+            return
+        self._write_rows(table, items)
+
+    def remove_batch(self, table: str, ks: Iterable[bytes]) -> None:
+        if not self._paged(table):
+            self.backend.remove_batch(table, ks)
+            return
+        self._write_rows(table, ((k, None) for k in ks))
+
+    def _write_rows(self, table: str, items) -> None:
+        """Apply rows in the order given, each as the row-by-row path would
+        (a page splits the moment it overflows), translated once: the
+        backend gets one batch of the pages as they stand at the end."""
         with self._lock:
-            cs = self._translate(
-                {(table, key): Entry(b"", EntryStatus.DELETED)},
-                self._meta, self._pages)
-            for (t, k), e in cs.items():
-                if e.deleted:
-                    self.backend.remove(t, k)
-                else:
-                    self.backend.set(t, k, e.value)
+            st = _Staged()
+            for key, value in items:
+                start = self._apply(st, table, key, value)
+                if start is not None:
+                    self._settle(st, table, start)
+            cs = self._emit(st)
+            if not cs:
+                return
+            # the new pages and the index before the dropped pages go: a
+            # crash between the two leaves unreferenced pages, never a
+            # reference to a page that is gone
+            self.backend.set_batch(
+                table, [(k, e.value) for (_, k), e in cs.items()
+                        if not e.deleted])
+            gone = [k for (_, k), e in cs.items() if e.deleted]
+            if gone:
+                self.backend.remove_batch(table, gone)
+            self._absorb(st)
 
     def keys(self, table: str, prefix: bytes = b"") -> Iterator[bytes]:
+        if not self._paged(table):
+            return self.backend.keys(table, prefix)
         with self._lock:
             meta = self._meta_for(table)
             out = []
-            start_i = max(0, self._page_index(meta, prefix))
+            start_i = max(0, bisect.bisect_right(meta, prefix) - 1)
             for s in meta[start_i:]:
                 # a page whose start is already past the prefix range can
                 # hold no matching row (its rows are >= start) — stop
@@ -173,8 +302,7 @@ class KeyPageStorage(TransactionalStorage):
                 # the pages covering the prefix
                 if prefix and s > prefix and not s.startswith(prefix):
                     break
-                rows = self._page_rows(table, s)
-                for k in rows:
+                for k in self._page(table, s).rows:
                     if k.startswith(prefix):
                         out.append(k)
             return iter(sorted(out))
@@ -187,14 +315,20 @@ class KeyPageStorage(TransactionalStorage):
         return [] if base_tables is None else base_tables()
 
     def stats(self) -> dict:
-        """Read-amplification counters (direct unit-test surface), merged
-        with the wrapped backend's stats under `backend_stats` so the ops
-        surface (getSystemStatus, storage_tool) still sees the engine's
-        level/debt/segment detail when keypage is the default layout."""
+        """The page layer's counters (unit tests; the benchmark reads them
+        as window deltas), merged with the wrapped backend's stats under
+        `backend_stats` so the ops surface (getSystemStatus, storage_tool)
+        still sees the engine's level/debt/segment detail when keypage is
+        the default layout."""
         with self._lock:
             out = {"backend_reads": self._backend_reads,
                    "cache_hits": self._cache_hits,
+                   "read_seconds": self._read_seconds,
+                   "evictions": self._evictions,
                    "cached_pages": len(self._pages),
+                   "cached_bytes": self._cached_bytes,
+                   "cache_budget_bytes": self.cache_bytes,
+                   "page_bytes_written": self._page_bytes_written,
                    "tables_cached": len(self._meta),
                    "key_page_size": self.page_size}
         backend_stats = getattr(self.backend, "stats", None)
@@ -233,97 +367,116 @@ class KeyPageStorage(TransactionalStorage):
         self.flush_caches()
 
     # -- changeset translation ---------------------------------------------
-    def _translate(self, changes: ChangeSet,
-                   meta_state: dict[str, list[bytes]],
-                   page_state: dict[tuple[str, bytes], dict[bytes, bytes]]
-                   ) -> ChangeSet:
-        """Apply row changes to (meta_state, page_state) in place; return the
-        page-level backend changeset."""
-        out: ChangeSet = {}
-        touched: dict[str, set[bytes]] = {}
-        for (table, key), e in sorted(changes.items()):
-            if table not in meta_state:
-                meta_state[table] = list(self._meta_for(table))
-            meta = meta_state[table]
-            i = self._page_index(meta, key)
-            if i < 0:
-                if not meta:
-                    if e.deleted:
-                        continue
-                    meta.insert(0, key)
-                    page_state[(table, key)] = {}
-                    touched.setdefault(table, set()).add(key)
-                    out[(table, META_KEY)] = Entry(_pack_meta(meta))
-                    i = 0
-                else:
-                    # key sorts before the first page: extend page 0 downward
-                    old0 = meta[0]
-                    if (table, old0) not in page_state:
-                        page_state[(table, old0)] = dict(
-                            self._page_rows(table, old0))
-                    page_state[(table, key)] = page_state.pop((table, old0))
-                    meta[0] = key
-                    out[(table, PAGE_PREFIX + old0)] = Entry(
-                        b"", EntryStatus.DELETED)
-                    out[(table, META_KEY)] = Entry(_pack_meta(meta))
-                    touched.setdefault(table, set()).add(key)
-                    i = 0
-            start = meta[i]
-            if (table, start) not in page_state:
-                page_state[(table, start)] = dict(self._page_rows(table, start))
-            rows = page_state[(table, start)]
-            if e.deleted:
-                rows.pop(key, None)
-            else:
-                rows[key] = e.value
-            touched.setdefault(table, set()).add(start)
+    def _stage_page(self, st: _Staged, table: str, start: bytes) -> _Page:
+        ck = (table, start)
+        page = st.pages.get(ck)
+        if page is None:
+            page = st.pages[ck] = self._page(table, start, False).copy()
+        return page
 
-        # split oversized pages / drop empty ones, then emit page writes
-        for table, starts in touched.items():
-            meta = meta_state[table]
-            for start in list(starts):
-                rows = page_state.get((table, start), {})
-                if not rows and len(meta) > 1:
-                    meta.remove(start)
-                    page_state.pop((table, start), None)
-                    out[(table, PAGE_PREFIX + start)] = Entry(
-                        b"", EntryStatus.DELETED)
-                    out[(table, META_KEY)] = Entry(_pack_meta(meta))
-                    continue
-                packed = _pack_page(rows)
-                if len(packed) > self.page_size and len(rows) > 1:
-                    ks = sorted(rows)
-                    mid = len(ks) // 2
-                    hi_start = ks[mid]
-                    hi_rows = {k: rows[k] for k in ks[mid:]}
-                    lo_rows = {k: rows[k] for k in ks[:mid]}
-                    page_state[(table, start)] = lo_rows
-                    page_state[(table, hi_start)] = hi_rows
-                    bisect.insort(meta, hi_start)
-                    out[(table, PAGE_PREFIX + start)] = Entry(
-                        _pack_page(lo_rows))
-                    out[(table, PAGE_PREFIX + hi_start)] = Entry(
-                        _pack_page(hi_rows))
-                    out[(table, META_KEY)] = Entry(_pack_meta(meta))
-                else:
-                    out[(table, PAGE_PREFIX + start)] = Entry(packed)
+    def _apply(self, st: _Staged, table: str, key: bytes,
+               value: Optional[bytes]) -> Optional[bytes]:
+        """Put one row (None: delete it) into its page in `st` -> the
+        page's start, or None where there was nothing to do."""
+        meta = st.meta.get(table)
+        if meta is None:
+            meta = st.meta[table] = list(self._meta_for(table))
+        i = bisect.bisect_right(meta, key) - 1
+        if i < 0:
+            if not meta:
+                if value is None:
+                    return None
+                meta.append(key)
+                st.pages[(table, key)] = _Page({})
+            else:
+                # key sorts before the first page: extend page 0 downward
+                old0 = meta[0]
+                page = self._stage_page(st, table, old0)
+                del st.pages[(table, old0)]
+                st.dropped.add((table, old0))
+                st.pages[(table, key)] = page
+                meta[0] = key
+            st.dropped.discard((table, key))
+            st.dirty_meta.add(table)
+            i = 0
+        start = meta[i]
+        self._stage_page(st, table, start).put(key, value)
+        return start
+
+    def _settle(self, st: _Staged, table: str, start: bytes) -> None:
+        """Drop a touched page that is left empty, split one that outgrew
+        the page size (once, in halves)."""
+        ck = (table, start)
+        page = st.pages[ck]
+        meta = st.meta[table]
+        if not page.rows:
+            if len(meta) > 1:
+                del meta[bisect.bisect_left(meta, start)]
+                del st.pages[ck]
+                st.dropped.add(ck)
+                st.dirty_meta.add(table)
+        elif page.size > self.page_size and len(page.rows) > 1:
+            ks = sorted(page.rows)
+            mid = len(ks) // 2
+            hi_start = ks[mid]
+            rows = page.rows
+            st.pages[ck] = _Page({k: rows[k] for k in ks[:mid]})
+            st.pages[(table, hi_start)] = _Page({k: rows[k]
+                                                 for k in ks[mid:]})
+            st.dropped.discard((table, hi_start))
+            bisect.insort(meta, hi_start)
+            st.dirty_meta.add(table)
+
+    def _emit(self, st: _Staged) -> ChangeSet:
+        """-> the page-level backend changeset of `st`."""
+        out: ChangeSet = dict(st.rows)
+        written = 0
+        for table, start in st.dropped:
+            out[(table, PAGE_PREFIX + start)] = Entry(
+                b"", EntryStatus.DELETED)
+        for (table, start), page in st.pages.items():
+            packed = _pack_page(page.rows)
+            written += len(packed)
+            out[(table, PAGE_PREFIX + start)] = Entry(packed)
+        for table in st.dirty_meta:
+            packed = _pack_meta(st.meta[table])
+            written += len(packed)
+            out[(table, META_KEY)] = Entry(packed)
+        self._page_bytes_written += written
         return out
+
+    def _absorb(self, st: _Staged) -> None:
+        """The backend holds `st`: make it the committed state."""
+        self._meta.update(st.meta)
+        for ck in st.dropped:
+            self._cache_drop(ck)
+        for ck, page in st.pages.items():
+            self._cache_put(ck, page)
 
     # -- 2PC ---------------------------------------------------------------
     def prepare(self, block_number: int, changes: ChangeSet) -> None:
         with self._lock:
-            meta_state = {t: list(m) for t, m in self._meta.items()}
-            page_state = {k: dict(v) for k, v in self._pages.items()}
-            translated = self._translate(changes, meta_state, page_state)
-            self._staged[block_number] = (meta_state, page_state)
-            self.backend.prepare(block_number, translated)
+            st = _Staged()
+            touched = []
+            for (table, key), e in sorted(changes.items()):
+                if not self._paged(table):
+                    st.rows[(table, key)] = e
+                    continue
+                start = self._apply(st, table, key,
+                                    None if e.deleted else e.value)
+                if start is not None:
+                    touched.append((table, start))
+            # in key order, so that every node that drops or splits pages
+            # of one table ends with the same ones
+            for table, start in sorted(set(touched)):
+                self._settle(st, table, start)
+            self._staged[block_number] = st
+            self.backend.prepare(block_number, self._emit(st))
 
     def commit(self, block_number: int) -> None:
         with self._lock:
             self.backend.commit(block_number)
-            meta_state, page_state = self._staged.pop(block_number)
-            self._meta.update(meta_state)
-            self._pages.update(page_state)
+            self._absorb(self._staged.pop(block_number))
 
     def rollback(self, block_number: int) -> None:
         with self._lock:
@@ -337,3 +490,4 @@ class KeyPageStorage(TransactionalStorage):
         with self._lock:
             self._meta.clear()
             self._pages.clear()
+            self._cached_bytes = 0

@@ -42,6 +42,7 @@ register txs instead, at a smaller `accounts` setting.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import random
 import time
 from typing import Callable, Optional
@@ -50,6 +51,7 @@ from fisco_bcos_tpu.executor import precompiled as pc
 
 ACCOUNT_BALANCE = 1_000_000
 FUNDER_BALANCE = 1 << 56
+PREFUND_BATCH = 1 << 16  # rows a storage.set_batch of the prefund
 
 SCENARIOS = {
     "mint-storm": "fresh-account register storm (append-only state growth)",
@@ -84,32 +86,36 @@ def _acct(spec: ScenarioSpec, i: int) -> bytes:
     return b"acct-%07d" % i
 
 
+def _prefund_tables(spec: ScenarioSpec):
+    """(table, rows) of `prefund_rows`, each table's rows an iterator in
+    key order: 2,000,000 accounts are never one list."""
+    bal = ACCOUNT_BALANCE.to_bytes(16, "big")
+    fb = FUNDER_BALANCE.to_bytes(16, "big")
+    accounts = spec.accounts if spec.name in ("hot-key", "xshard-heavy") \
+        else 0
+    funders = spec.funders if spec.name == "airdrop-sweep" else 0
+    if accounts or funders:
+        yield pc.T_BALANCE, itertools.chain(
+            ((_acct(spec, i), bal) for i in range(accounts)),
+            ((b"funder-%d" % i, fb) for i in range(funders)))
+    if spec.name == "wide-table":
+        yield pc.T_USER_PREFIX + "gd", iter([(b"\x00__meta__", b"kv")])
+
+
 def prefund_rows(spec: ScenarioSpec) -> dict[str, list[tuple[bytes, bytes]]]:
     """table -> [(key, value)] rows that make the scenario's sources
     spendable, for DIRECT injection into every node's storage before the
     first block (bench path). Deterministic for a given spec."""
-    bal = ACCOUNT_BALANCE.to_bytes(16, "big")
-    rows: list[tuple[bytes, bytes]] = []
-    if spec.name in ("hot-key", "xshard-heavy"):
-        rows += [(_acct(spec, i), bal) for i in range(spec.accounts)]
-    if spec.name == "airdrop-sweep":
-        fb = FUNDER_BALANCE.to_bytes(16, "big")
-        rows += [(b"funder-%d" % i, fb) for i in range(spec.funders)]
-    out: dict[str, list[tuple[bytes, bytes]]] = {}
-    if rows:
-        out[pc.T_BALANCE] = rows
-    if spec.name == "wide-table":
-        out[pc.T_USER_PREFIX + "gd"] = [(b"\x00__meta__", b"kv")]
-    return out
+    return {table: list(rows) for table, rows in _prefund_tables(spec)}
 
 
 def prefund_storage(storage, spec: ScenarioSpec) -> int:
     """Inject `prefund_rows` into one node's storage (call on EVERY node
-    of an in-process chain, before load). Returns rows written."""
+    of an in-process chain, before load), streamed in batches: a page
+    layer translates each batch once. Returns rows written."""
     n = 0
-    for table, rows in prefund_rows(spec).items():
-        for s in range(0, len(rows), 4096):
-            chunk = rows[s:s + 4096]
+    for table, rows in _prefund_tables(spec):
+        while chunk := list(itertools.islice(rows, PREFUND_BATCH)):
             storage.set_batch(table, chunk)
             n += len(chunk)
     return n

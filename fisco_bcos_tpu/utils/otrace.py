@@ -463,7 +463,10 @@ def configure(sample_rate: Optional[float] = None,
 STAGES = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "gossip",
           "crypto", "round_wait", "seal_wait", "consensus_pre", "fill",
           "execute", "roots", "consensus_wait", "commit", "notify",
-          "rpc_respond", "prime")
+          "rpc_respond", "prime",
+          # inside `commit`: the changeset staged in the storage (a page
+          # layer translates it first), then made durable and applied
+          "storage_prepare", "storage_commit")
 # what the edge counts beside its stages, per cohort and never per stamp:
 # receipts a `sendTransaction` batch was answered, and those of them taken
 # from the committed block's shared fragments; batches of `sendTransaction`

@@ -223,7 +223,11 @@ class Chain:
     """Four in-process PBFT nodes with host crypto over a FakeGateway,
     prefunded like a chipbench cluster; node0 has the RPC edge."""
 
-    def __init__(self, limit: int, pool: int, accounts: int = 512, **cfg):
+    def __init__(self, limit: int, pool: int, accounts: int = 512,
+                 per_node=None, **cfg):
+        """`per_node(i)` -> more NodeConfig fields of node i (a data path
+        of its own); nodes that open a chain they find there are neither
+        prefunded nor given a genesis again."""
         suite = make_suite(False, backend="host")
         kps = [suite.generate_keypair(bytes([i + 1]) * 16) for i in range(4)]
         self.gw = FakeGateway()
@@ -235,11 +239,13 @@ class Chain:
                 consensus="pbft", crypto_backend="host",
                 tx_count_limit=limit, txpool_limit=pool,
                 rpc_max_batch=2 * limit, trace_sample_rate=0.0,
-                rpc_port=0 if i == 0 else None, **cfg),
+                rpc_port=0 if i == 0 else None, **cfg,
+                **(per_node(i) if per_node else {})),
                 keypair=kp, gateway=self.gw)
-            prefund_storage(node.storage,
-                            ScenarioSpec("hot-key", accounts=accounts))
-            node.build_genesis([ConsensusNode(k.pub_bytes) for k in kps])
+            if node.ledger.current_number() < 0:
+                prefund_storage(node.storage,
+                                ScenarioSpec("hot-key", accounts=accounts))
+                node.build_genesis([ConsensusNode(k.pub_bytes) for k in kps])
             self.nodes.append(node)
         for node in self.nodes:
             node.start()

@@ -225,13 +225,15 @@ def test_storage_tool_leveled_disk_and_keypage(tmp_path):
     assert type(st).__name__ == "KeyPageStorage"  # auto default for disk
     engine = st.backend
     engine._compactor.pause()       # leave debt for the tool to drain
-    for i in range(8):
+    # one backend write (so, at memtable_mb=0, one L0 segment) a row: the
+    # tool opens with the default trigger of 8 segments, so leave more
+    for i in range(12):
         st.set("t_wide", b"row%04d" % i, b"v%d" % i)
     assert engine.compaction_debt_bytes() > 0
     st.close()
 
     stats = json.loads(_run_tool("storage_tool.py", "stats", path))
-    assert stats["t_wide"]["rows"] == 8  # logical rows, not _kp_ pages
+    assert stats["t_wide"]["rows"] == 12  # logical rows, not _kp_ pages
     eng = stats["_engine"]
     assert "backend_reads" in eng        # page layer detected
     levels = eng["backend_stats"]["levels"]
@@ -249,4 +251,4 @@ def test_storage_tool_leveled_disk_and_keypage(tmp_path):
     assert drained["debt_bytes_after"] == 0
     stats = json.loads(_run_tool("storage_tool.py", "stats", path))
     assert stats["_engine"]["backend_stats"]["compaction_debt_bytes"] == 0
-    assert stats["t_wide"]["rows"] == 8
+    assert stats["t_wide"]["rows"] == 12

@@ -832,3 +832,45 @@ def test_compaction_debt_backpressure_ok_busy_ok(tmp_path):
     finally:
         node.stop()
         node.storage.close()
+
+
+def test_a_routine_merge_is_not_compaction_starvation(tmp_path):
+    """The debt cap follows the engine's geometry unless the operator sets
+    one: the whole L0 a routine merge finds (the debt signal's jump at
+    every trigger) scores a quarter, far from busy; an L0 left to grow to
+    four such loads scores 1.0."""
+    from fisco_bcos_tpu.init.node import (Node, NodeConfig,
+                                          compact_debt_cap_mb)
+
+    cfg = NodeConfig(consensus="solo", crypto_backend="host",
+                     storage_backend="disk",
+                     storage_path=str(tmp_path / "data"),
+                     storage_memtable_mb=1, storage_compact_segments=2,
+                     overload_hold_s=0.0)
+    assert cfg.overload_compact_debt_mb == 0
+    assert compact_debt_cap_mb(cfg) == 12
+    assert compact_debt_cap_mb(NodeConfig()) == 4 * 9 * 64
+    node = Node(cfg)
+    try:
+        engine = node.storage.backend
+        engine._compactor.pause()
+        rows = [(b"k%05d" % i, b"x" * 1000) for i in range(1100)]
+        for n in range(3):               # three flushed memtables: L0 over
+            engine.set_batch("t%d" % n, rows)
+        assert len(engine._levels[0]) == 3
+        debt = engine.compaction_debt_bytes()
+        assert 3 << 20 <= debt < 4 << 20
+        for _ in range(12):
+            node.overload.sample_once()
+        st = node.overload.stats()
+        assert 0.2 < st["signals"]["compaction_debt"] < 0.35
+        assert not node.overload.busy()
+        for n in range(3, 12):           # starved: four such loads pile up
+            engine.set_batch("t%d" % n, rows)
+        for _ in range(12):
+            node.overload.sample_once()
+        assert node.overload.stats()["signals"]["compaction_debt"] >= 0.95
+        assert node.overload.busy()
+    finally:
+        node.stop()
+        node.storage.close()

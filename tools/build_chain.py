@@ -62,9 +62,13 @@ def build_chain(out_dir: str, n_nodes: int, sm_crypto: bool = False,
                 p2p_base_port: int | None = None,
                 p2p_ports: list[int] | None = None,
                 host: str = "127.0.0.1",
-                block_tx_count_limit: int = 1000) -> dict:
+                block_tx_count_limit: int = 1000,
+                key_page_size: int = -1) -> dict:
     if block_tx_count_limit < 1:
         raise ValueError("block_tx_count_limit must be >= 1")
+    if 0 < key_page_size < 4096:
+        raise ValueError("key_page_size must be 0 (off), auto (-1) or at "
+                         "least 4096 bytes, as upstream's")
     # one value for every node, or one per node ("auto,host,host,host"): a
     # chip belongs to one process at a time, so on a one-chip host exactly
     # one daemon may be anything but `host`
@@ -105,6 +109,7 @@ def build_chain(out_dir: str, n_nodes: int, sm_crypto: bool = False,
             # node obeys (tool/config.py _load_node_parts)
             tx_count_limit=block_tx_count_limit,
             storage_backend=storage_backend,
+            storage_key_page_size=key_page_size,
             crypto_backend=backends[i],
             rpc_port=(rpc_base_port + i) if rpc_base_port is not None else None,
             metrics_port=(metrics_base_port + i)
@@ -184,6 +189,12 @@ def main() -> None:
                     help="[storage] backend: auto = WAL-backed; disk = "
                          "log-structured engine (restart flat in chain "
                          "length, datasets beyond RAM)")
+    ap.add_argument("--key-page-size", type=int, default=-1,
+                    help="[storage] key_page_size in every node's "
+                         "config.ini: the bytes at which a page of rows "
+                         "splits (upstream's build_chain.sh writes 10240). "
+                         "Default: `auto` = 10240 on disk, no paging on "
+                         "wal/memory; 0 turns paging off")
     ap.add_argument("--crypto-backend", default="auto",
                     help="[crypto] backend: auto|host|device, one value "
                          "for all nodes or a comma list, one per node "
@@ -212,6 +223,7 @@ def main() -> None:
         metrics_base_port=args.metrics_base_port, sm_tls=args.sm_tls,
         storage_backend=args.storage, crypto_backend=args.crypto_backend,
         block_tx_count_limit=args.block_tx_count_limit,
+        key_page_size=args.key_page_size,
         encrypt_passphrase=args.encrypt_key.encode() if args.encrypt_key else None)
     if args.mode == "max":
         info["max_cluster"] = build_max_cluster(
