@@ -21,9 +21,12 @@ DiskStorage) commits pages atomically with everything else. A translation
 stages only the pages and page indexes its rows touch (`_Staged`): its cost
 follows the block, not what the node has read or written before.
 
-Parsed pages are held in an LRU bounded by `PAGE_CACHE_BYTES` of packed
-page bytes (the reference keeps an LRU `CacheStorage` over RocksDB); a
-state larger than that is served from the backend, page by page.
+Pages are held in an LRU bounded by `PAGE_CACHE_BYTES` of packed page
+bytes (the reference keeps an LRU `CacheStorage` over RocksDB); a state
+larger than that is served from the backend, page by page. A page whose
+rows all have one key width and one value width (an account ledger) stays
+packed in memory, the bytes the backend holds, and is read and written at
+byte offsets; any other page is parsed into a dict (`_Page`).
 
 As the disk engine's value layout (`[storage] key_page_size > 0`,
 storage/__init__.py make_storage) this is what makes wide tables cheap:
@@ -120,30 +123,183 @@ def _unpack_meta(data: bytes) -> list[bytes]:
     return out
 
 
+def _fixed_widths(data: bytes) -> Optional[tuple[int, int, int]]:
+    """-> (rows, key width, value width) where every row of the packed
+    page `data` has the first row's two widths, else None. The total
+    length alone proves nothing (two rows may trade a byte): each of the
+    eight bytes of the two length fields is compared down the stride."""
+    size = len(data)
+    if size < 4:
+        return None
+    (n,) = _U32.unpack_from(data, 0)
+    if n == 0:
+        return (0, 0, 0) if size == 4 else None
+    if size < 12:
+        return None
+    (kl,) = _U32.unpack_from(data, 4)
+    if size < 12 + kl:
+        return None
+    (vl,) = _U32.unpack_from(data, 8 + kl)
+    stride = 8 + kl + vl
+    if size != 4 + n * stride:
+        return None
+    if n > 1:
+        for at in (4, 5, 6, 7, 8 + kl, 9 + kl, 10 + kl, 11 + kl):
+            if data[at::stride] != data[at:at + 1] * n:
+                return None
+    return n, kl, vl
+
+
 class _Page:
-    """A parsed page and the bytes it packs to."""
+    """One page in memory, in the form its bytes show.
 
-    __slots__ = ("rows", "size")
+    Packed: `buf` holds the page as it lies in the backend and every row
+    has one key width `kl` and one value width `vl`, so row `i` starts at
+    `4 + i * (8 + kl + vl)`: a row is found by a bisect over the bytes and
+    no row is ever made an object. Row-wise: `rows` is the parsed dict
+    (`buf` is None): a page of mixed widths, or one that received a key or
+    a value of another width, takes that form and stays in it. `size` is
+    the packed byte count in both.
 
-    def __init__(self, rows: dict[bytes, bytes], size: Optional[int] = None):
+    `buf` is `bytes` on a page read from the backend and on one handed to
+    it (`packed()`), a `bytearray` on a staged copy in between: a cached
+    page cannot be written through."""
+
+    __slots__ = ("rows", "size", "buf", "n", "kl", "vl")
+
+    def __init__(self, rows: Optional[dict[bytes, bytes]], buf=None,
+                 widths: tuple[int, int, int] = (0, 0, 0),
+                 size: Optional[int] = None):
         self.rows = rows
-        self.size = size if size is not None else _EMPTY_PAGE_BYTES + sum(
-            8 + len(k) + len(v) for k, v in rows.items())
+        self.buf = buf
+        self.n, self.kl, self.vl = widths  # of the packed form alone
+        if size is None:
+            size = len(buf) if rows is None else _EMPTY_PAGE_BYTES + sum(
+                8 + len(k) + len(v) for k, v in rows.items())
+        self.size = size
+
+    @classmethod
+    def load(cls, raw: Optional[bytes] = None) -> "_Page":
+        """The page of the backend's bytes `raw`; of none, an empty one."""
+        if not raw:
+            return cls(None, bytearray(_U32.pack(0)))
+        widths = _fixed_widths(raw)
+        if widths is None:
+            return cls(_unpack_page(raw), size=len(raw))
+        return cls(None, raw, widths)
+
+    def __len__(self) -> int:
+        return self.n if self.rows is None else len(self.rows)
 
     def copy(self) -> "_Page":
-        return _Page(dict(self.rows), self.size)
+        """A page of its own to write to."""
+        if self.rows is not None:
+            return _Page(dict(self.rows), size=self.size)
+        return _Page(None, bytearray(self.buf), (self.n, self.kl, self.vl))
+
+    def _find(self, key: bytes) -> tuple[int, bool]:
+        """Packed form -> (offset of the row `key` has or would take,
+        whether it is there)."""
+        buf, kl = self.buf, self.kl
+        stride = 8 + kl + self.vl
+        lo, hi = 0, self.n
+        while lo < hi:
+            mid = (lo + hi) >> 1
+            at = 8 + mid * stride
+            if buf[at:at + kl] < key:
+                lo = mid + 1
+            else:
+                hi = mid
+        at = 4 + lo * stride
+        return at, lo < self.n and buf[at + 4:at + 4 + kl] == key
+
+    def get(self, key: bytes) -> Optional[bytes]:
+        if self.rows is not None:
+            return self.rows.get(key)
+        at, found = self._find(key)
+        if not found:
+            return None
+        at += 8 + self.kl
+        return bytes(self.buf[at:at + self.vl])
+
+    def keys(self) -> list[bytes]:
+        if self.rows is not None:
+            return list(self.rows)
+        buf, kl = self.buf, self.kl
+        return [bytes(buf[at:at + kl])
+                for at in range(8, len(buf), 8 + kl + self.vl)]
 
     def put(self, key: bytes, value: Optional[bytes]) -> None:
         """Set a row, or drop it where `value` is None."""
-        old = self.rows.get(key)
+        if self.rows is not None:
+            return self._put_row(key, value)
+        buf, kl, vl = self.buf, self.kl, self.vl
+        if value is None:
+            at, found = self._find(key)
+            if found:
+                del buf[at:at + 8 + kl + vl]
+                self.n -= 1
+                _U32.pack_into(buf, 0, self.n)
+                self.size -= 8 + kl + vl
+            return
+        if not self.n:
+            kl, vl = self.kl, self.vl = len(key), len(value)
+        elif len(key) != kl or len(value) != vl:
+            # a row of other widths: the dict form from here on
+            self.rows = _unpack_page(bytes(buf))
+            self.buf = None
+            return self._put_row(key, value)
+        pack = _U32.pack
+        if self.n and buf[-4 - vl - kl:-4 - vl] < key:
+            # past the last row, as rows loaded in key order all are
+            buf += pack(kl) + key + pack(vl) + value
+        else:
+            at, found = self._find(key)
+            if found:
+                buf[at + 8 + kl:at + 8 + kl + vl] = value
+                return
+            buf[at:at] = pack(kl) + key + pack(vl) + value
+        self.n += 1
+        _U32.pack_into(buf, 0, self.n)
+        self.size += 8 + kl + vl
+
+    def _put_row(self, key: bytes, value: Optional[bytes]) -> None:
+        rows = self.rows
+        old = rows.get(key)
         if value is None:
             if old is not None:
-                del self.rows[key]
+                del rows[key]
                 self.size -= 8 + len(key) + len(old)
             return
-        self.rows[key] = value
+        rows[key] = value
         self.size += len(value) - len(old) if old is not None \
             else 8 + len(key) + len(value)
+
+    def split(self) -> tuple["_Page", bytes, "_Page"]:
+        """-> (the lower half of the rows, the upper half's first key, the
+        upper half), cut at the middle row."""
+        if self.rows is not None:
+            rows, ks = self.rows, sorted(self.rows)
+            mid = len(ks) // 2
+            return (_Page({k: rows[k] for k in ks[:mid]}), ks[mid],
+                    _Page({k: rows[k] for k in ks[mid:]}))
+        mid = self.n // 2
+        kl, vl = self.kl, self.vl
+        cut = 4 + mid * (8 + kl + vl)
+        lo = bytearray(self.buf[:cut])
+        lo[0:4] = _U32.pack(mid)
+        hi = bytearray(_U32.pack(self.n - mid)) + self.buf[cut:]
+        return (_Page(None, lo, (mid, kl, vl)), bytes(hi[8:8 + kl]),
+                _Page(None, hi, (self.n - mid, kl, vl)))
+
+    def packed(self) -> bytes:
+        """The page as the backend holds it. A packed page's buffer is
+        handed over as it is, sealed against further writes."""
+        if self.rows is not None:
+            return _pack_page(self.rows)
+        if type(self.buf) is not bytes:
+            self.buf = bytes(self.buf)
+        return self.buf
 
 
 class _Staged:
@@ -172,7 +328,7 @@ class KeyPageStorage(TransactionalStorage):
         self.cache_bytes = cache_bytes
         self._lock = threading.RLock()
         self._meta: dict[str, list[bytes]] = {}  # table -> page starts
-        # parsed pages, least recently used first
+        # committed pages, least recently used first
         self._pages: OrderedDict[tuple[str, bytes], _Page] = OrderedDict()
         self._cached_bytes = 0
         self._staged: dict[int, _Staged] = {}  # block -> its translation
@@ -183,6 +339,9 @@ class KeyPageStorage(TransactionalStorage):
         self._read_seconds = 0.0   # backend page reads, parse included
         self._evictions = 0
         self._page_bytes_written = 0  # pages + page indexes to the backend
+        # pages loaded or staged, by the form they took (`_Page`)
+        self._pages_packed = 0
+        self._pages_rowwise = 0
 
     def _paged(self, table: str) -> bool:
         # a group's view prefixes its tables `g/<group>/` (namespace.py)
@@ -212,11 +371,18 @@ class KeyPageStorage(TransactionalStorage):
             return page
         t0 = time.perf_counter()
         raw = self.backend.get(table, PAGE_PREFIX + start)
-        page = _Page(_unpack_page(raw), len(raw)) if raw else _Page({})
+        page = _Page.load(raw)
         self._read_seconds += time.perf_counter() - t0
         self._backend_reads += 1
+        self._count_form(page)
         self._cache_put(ck, page)
         return page
+
+    def _count_form(self, page: _Page) -> None:
+        if page.rows is None:
+            self._pages_packed += 1
+        else:
+            self._pages_rowwise += 1
 
     def _cache_put(self, ck: tuple[str, bytes], page: _Page) -> None:
         """Hold `page` as the most recently used, then drop the least
@@ -243,7 +409,7 @@ class KeyPageStorage(TransactionalStorage):
             i = bisect.bisect_right(meta, key) - 1
             if i < 0:
                 return None
-            return self._page(table, meta[i]).rows.get(key)
+            return self._page(table, meta[i]).get(key)
 
     def set(self, table: str, key: bytes, value: bytes) -> None:
         self.set_batch(table, ((key, value),))
@@ -302,9 +468,8 @@ class KeyPageStorage(TransactionalStorage):
                 # the pages covering the prefix
                 if prefix and s > prefix and not s.startswith(prefix):
                     break
-                for k in self._page(table, s).rows:
-                    if k.startswith(prefix):
-                        out.append(k)
+                out += [k for k in self._page(table, s).keys()
+                        if k.startswith(prefix)]
             return iter(sorted(out))
 
     def tables(self) -> list[str]:
@@ -329,6 +494,8 @@ class KeyPageStorage(TransactionalStorage):
                    "cached_bytes": self._cached_bytes,
                    "cache_budget_bytes": self.cache_bytes,
                    "page_bytes_written": self._page_bytes_written,
+                   "pages_packed": self._pages_packed,
+                   "pages_rowwise": self._pages_rowwise,
                    "tables_cached": len(self._meta),
                    "key_page_size": self.page_size}
         backend_stats = getattr(self.backend, "stats", None)
@@ -387,7 +554,7 @@ class KeyPageStorage(TransactionalStorage):
                 if value is None:
                     return None
                 meta.append(key)
-                st.pages[(table, key)] = _Page({})
+                st.pages[(table, key)] = _Page.load()
             else:
                 # key sorts before the first page: extend page 0 downward
                 old0 = meta[0]
@@ -409,20 +576,15 @@ class KeyPageStorage(TransactionalStorage):
         ck = (table, start)
         page = st.pages[ck]
         meta = st.meta[table]
-        if not page.rows:
+        if page.size == _EMPTY_PAGE_BYTES:  # no row left
             if len(meta) > 1:
                 del meta[bisect.bisect_left(meta, start)]
                 del st.pages[ck]
                 st.dropped.add(ck)
                 st.dirty_meta.add(table)
-        elif page.size > self.page_size and len(page.rows) > 1:
-            ks = sorted(page.rows)
-            mid = len(ks) // 2
-            hi_start = ks[mid]
-            rows = page.rows
-            st.pages[ck] = _Page({k: rows[k] for k in ks[:mid]})
-            st.pages[(table, hi_start)] = _Page({k: rows[k]
-                                                 for k in ks[mid:]})
+        elif page.size > self.page_size and len(page) > 1:
+            st.pages[ck], hi_start, hi = page.split()
+            st.pages[(table, hi_start)] = hi
             st.dropped.discard((table, hi_start))
             bisect.insort(meta, hi_start)
             st.dirty_meta.add(table)
@@ -435,7 +597,8 @@ class KeyPageStorage(TransactionalStorage):
             out[(table, PAGE_PREFIX + start)] = Entry(
                 b"", EntryStatus.DELETED)
         for (table, start), page in st.pages.items():
-            packed = _pack_page(page.rows)
+            packed = page.packed()
+            self._count_form(page)
             written += len(packed)
             out[(table, PAGE_PREFIX + start)] = Entry(packed)
         for table in st.dirty_meta:

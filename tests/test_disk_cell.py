@@ -21,7 +21,7 @@ NEW_METRICS = (
     "page_write_kb_per_block", "flushes_per_100_blocks",
     "compaction_busy_share", "storage_stall_ms_per_block",
     "compaction_debt_mb", "page_evictions_per_block", "page_cache_mb",
-    "flush_ms_per_block", "merges_per_100_blocks")
+    "flush_ms_per_block", "merges_per_100_blocks", "page_packed_share")
 
 
 def _doc() -> dict:
@@ -127,3 +127,5 @@ def test_rehearsal_ends_correct_and_reads_every_storage_metric():
         <= layers["commit_ms_per_block"]
     assert layers["page_write_kb_per_block"] > 0.0
     assert 0.0 < layers["page_cache_mb"] <= 16.0
+    # the account ledger's pages are of one width: none parsed into a dict
+    assert layers["page_packed_share"] >= 95.0

@@ -14,7 +14,7 @@ from fisco_bcos_tpu.storage.engine import DiskStorage
 from fisco_bcos_tpu.storage.interface import Entry, EntryStatus
 from fisco_bcos_tpu.storage.keypage import (META_KEY, PAGE_PREFIX,
                                             UNPAGED_TABLES, KeyPageStorage,
-                                            _unpack_page)
+                                            _Page)
 from fisco_bcos_tpu.storage.memory import MemoryStorage
 from fisco_bcos_tpu.testing.scenario import (ScenarioSpec, prefund_rows,
                                              prefund_storage)
@@ -141,7 +141,8 @@ def test_prepare_stages_only_what_the_changeset_touches(tmp_path):
         assert set(st.meta) == {"c_balance"}
         assert len(st.pages) <= 5 and not st.dropped
         for (table, start), page in st.pages.items():
-            assert table == "c_balance" and any(k in page.rows for k in hot)
+            assert table == "c_balance" and any(
+                page.get(k) is not None for k in hot)
         assert st.rows == {("s_hash_2_tx", b"h%d" % number): Entry(b"tx")}
         staged_per_block.append(len(st.pages))
         kp.commit(number)
@@ -175,7 +176,7 @@ def test_batches_give_the_pages_the_row_by_row_path_gives(
     want = _raw(one)
     assert _raw(batch) == want and _raw(streamed) == want
     pages = [v for _, k, v in want if k.startswith(PAGE_PREFIX)]
-    assert sum(len(_unpack_page(p)) for p in pages) == accounts
+    assert sum(len(_Page.load(p)) for p in pages) == accounts
     assert all(len(p) <= one.page_size for p in pages)
     assert one.page_size == 10240        # upstream's key_page_size
     # deletes and overwrites in one batch, any order
