@@ -1,7 +1,9 @@
 """Read back from the nodes what the comparison needs, once the window has
 closed: every header hash from every node, the chain's transaction lists
 and seal counts from node0, and — for samples drawn from the seed — the
-receipts from every node and the balances from node0 and one host replica.
+receipts from every node and the state of the touched keys from node0 and
+one host replica (which keys an operation touched, the call that reads one
+back and what its answer says are the kind's, `maker.kind`).
 """
 
 from __future__ import annotations
@@ -49,14 +51,13 @@ def gather(cluster, maker, sent: list[dict], seed: int) -> dict:
             for s, rc in zip(pick, got):
                 receipts[s["hash"]][k] = rc
 
-        touched = sorted({a for s in acked for a in s["move"][:2]})
+        kind = maker.kind
+        touched = sorted({a for s in acked for a in kind.touched(s["move"])})
         accts = rng.sample(touched, min(SAMPLE_ACCOUNTS, len(touched)))
         balances = {}
         for k in (0, 1 + seed % (len(nodes) - 1)):
-            got = clis[k].results([maker.balance_call(group, a)
-                                   for a in accts])
-            balances[k] = {a: int(r["output"][2:], 16)
-                           for a, r in zip(accts, got)}
+            got = clis[k].results([kind.read_call(group, a) for a in accts])
+            balances[k] = {a: kind.decode(r) for a, r in zip(accts, got)}
     finally:
         for c in clis.values():
             c.close()

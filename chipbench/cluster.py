@@ -19,6 +19,7 @@ import subprocess
 import sys
 import time
 
+from manifest import workload
 from rpc import Rpc, RpcError
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -105,27 +106,21 @@ class Cluster:
         return info
 
     def _prefund(self, info: dict) -> None:
-        """The prefunded users, written into every node's storage before
-        its first block (upstream's perf test registers them first)."""
+        """What the configuration's transaction kind wants in every
+        node's storage before its first block."""
         from fisco_bcos_tpu.storage import make_storage
-        from fisco_bcos_tpu.testing.scenario import (ACCOUNT_BALANCE,
-                                                     ScenarioSpec,
-                                                     prefund_storage)
         from fisco_bcos_tpu.tool.config import _load_node_parts
 
-        if ACCOUNT_BALANCE != self.config["prefund_balance"]:
-            raise ClusterError("the program prefunds another balance than "
-                               "the configuration states")
-        spec = ScenarioSpec("hot-key", accounts=self.config["accounts"])
+        kind = workload(self.config)
         for n in info["nodes"]:
             cfg = _load_node_parts(n["dir"], None)[0]
             st = make_storage(cfg.storage_backend, cfg.storage_path)
             try:
-                rows = prefund_storage(st, spec)
+                kind.prefund(st, self.config)
+            except ValueError as exc:
+                raise ClusterError(str(exc)) from exc
             finally:
                 st.close()
-            if rows != self.config["accounts"]:
-                raise ClusterError(f"prefunded {rows} accounts")
 
     # -- processes -----------------------------------------------------------
     def _env(self, chip: bool) -> dict:

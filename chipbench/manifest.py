@@ -3,8 +3,11 @@
 A cell is `<config>.<traffic>`; its configuration is
 `configs/<config>.json`, its traffic mix `traffic/<traffic>.json`, and each
 per-layer metric `metrics/<metric>.json`, which names its reader module
-under `readers/`. Nothing here lists a name: a later PR adds a cell, a
-configuration, a traffic mix or a metric by adding files and entries.
+under `readers/`. What a transaction is — the configuration's `workload`
+— is the pair `workloads/<kind>.py` (the client's side) and
+`workloads/<kind>_reference.py` (its plain reference). Nothing here lists
+a name: a later PR adds a cell, a configuration, a traffic mix, a metric
+or a transaction kind by adding files and entries.
 """
 
 from __future__ import annotations
@@ -19,6 +22,8 @@ ROOT = os.path.dirname(HERE)
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# the kind of a configuration file that names none: the first four did not
+DEFAULT_WORKLOAD = "dagtransfer"
 
 
 class ManifestError(Exception):
@@ -33,6 +38,33 @@ def _load_json(path: str) -> dict:
         raise ManifestError(f"cannot read {path}: {exc}") from exc
     except ValueError as exc:
         raise ManifestError(f"{path} is not JSON: {exc}") from exc
+
+
+def _module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_KINDS: dict = {}  # path -> the module, loaded once a process
+
+
+def workload(config: dict, side: str = "client", bench_dir: str = HERE):
+    """The module of the configuration's transaction kind: `side` "client"
+    is `workloads/<kind>.py`, which may import the program; "reference" is
+    `workloads/<kind>_reference.py`, which imports nothing of it. What a
+    kind reads of its configuration, and refuses there, is its own."""
+    kind = config.get("workload", DEFAULT_WORKLOAD)
+    if not isinstance(kind, str) or not NAME_RE.match(kind):
+        raise ManifestError(f"bad workload name {kind!r}")
+    stem = kind if side == "client" else f"{kind}_{side}"
+    path = os.path.join(bench_dir, "workloads", f"{stem}.py")
+    if path not in _KINDS:
+        if not os.path.exists(path):
+            raise ManifestError(f"no workload {kind!r}: {path} is missing")
+        _KINDS[path] = _module(path, f"chipbench_workload_{stem}")
+    return _KINDS[path]
 
 
 class Manifest:
@@ -93,13 +125,15 @@ class Manifest:
         if not NAME_RE.match(module):
             raise ManifestError(f"bad reader name {module!r}")
         path = os.path.join(self.bench_dir, "readers", f"{module}.py")
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_reader_{module}", path)
-        if spec is None or not os.path.exists(path):
+        if not os.path.exists(path):
             raise ManifestError(f"no reader {path}")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _module(path, f"chipbench_reader_{module}").read
+
+    def workload(self, config: dict) -> None:
+        """Both files of the configuration's kind are there and load: said
+        before anything starts."""
+        for side in ("client", "reference"):
+            workload(config, side, self.bench_dir)
 
 
 def peaks(device_kind: str, bench_dir: str | None = None) -> dict:

@@ -167,6 +167,7 @@ def main() -> int:
         cell = man.cell(args.workload)
         config = man.config(cell["config"])
         traffic = man.traffic(cell["traffic"])
+        man.workload(config)  # both files of its kind
         e2e = man.end_to_end(cell["name"])
         per_layer = man.per_layer(cell["name"])
     except (ImportError, ManifestError, KeyError) as exc:
@@ -267,14 +268,16 @@ def run_cell(args, cell, config, traffic, e2e, per_layer, workdir,
         obs.snap["end"]["status"]["0"]["crypto"]["platform"]
         != c0["platform"])})
     correct = reference.is_correct(numbers)
+    right = reference.receipts_right(config, sent, ans)
     controls = reference.run_controls(config, sent, ans) \
         if args.controls else None
     t_judge = time.monotonic() - t
 
     # -- the client's numbers --------------------------------------------------
     t0, t1 = load.t0, load.t1
-    ok = [r for r in measured if r.receipt is not None
-          and r.receipt.get("status") == 0]
+    # a receipt that says what it has to: the operation done or, where the
+    # kind's semantics demand it, refused
+    ok = [r for r in measured if r.hash in right]
     in_window = [r for r in ok if r.done <= t1]
     horizon = t_closed
     lat = [(r.done if r.done is not None else horizon) - r.due

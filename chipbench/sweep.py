@@ -88,6 +88,7 @@ def main() -> int:
     args = ap.parse_args()
     man = Manifest()
     config, traffic = man.config(args.config), man.traffic(args.traffic)
+    man.workload(config)  # both files of its kind
     if args.rehearse_cpu:
         config["accounts"] = 256
     from txgen import TxMaker

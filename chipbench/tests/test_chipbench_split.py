@@ -20,7 +20,6 @@ def test_the_split_share_loads_for_every_cell(cell):
     assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
         "%", "higher", "crypto seam", "receipt_p50_ms")
     assert m["workloads"] == CELLS
-    assert MAN.doc["per_layer"][-1]["name"] == "replica_ec_split_share"
     small = {"hostItems": 7, "hostSplitItems": 0}
     ev = {"status": {
         "before": _status({"hostItems": 5000, "hostSplitItems": 5000}, small),
