@@ -61,6 +61,7 @@ from typing import Optional
 
 from ..protocol import LogEntry
 from ..storage.state import StateStorage
+from ..utils import otrace
 
 U256 = 1 << 256
 M256 = U256 - 1
@@ -416,8 +417,12 @@ def _analyze_jumpdests(code: bytes) -> frozenset[int]:
 class EVM:
     """Interpreter bound to a state overlay + crypto suite."""
 
-    def __init__(self, suite, registry=None, native: Optional[bool] = None):
+    def __init__(self, suite, registry=None, native: Optional[bool] = None,
+                 stages=None):
         self.suite = suite
+        # the node's stage table: evm_frames / evm_native_frames, counted
+        # once a frame where `_run` picks the interpreter
+        self.stages = stages if stages is not None else otrace.stages()
         # per-transaction access set (EIP-2929 warm/cold + refunds),
         # thread-local: the executor runs concurrent txs on one EVM
         self._tls = threading.local()
@@ -853,7 +858,9 @@ class EVM:
             self.begin_tx_access(env.origin, address, env.coinbase)
         acc = self.access()
         jumpdests = _analyze_jumpdests(code)
+        self.stages.count("evm_frames", 1)
         if self.native:
+            self.stages.count("evm_native_frames", 1)
             from . import nevm
             return nevm.run_frame(self, state, env, code, caller, address,
                                   value, calldata, gas, depth, static,

@@ -20,9 +20,11 @@ import os
 import time
 from typing import Optional, Sequence
 
+from ..codec import abi as abi_mod
 from ..protocol import Receipt, Transaction, TransactionStatus
 from ..storage.interface import ChangeSet
 from ..storage.state import StateStorage
+from ..utils import otrace
 from ..utils.log import metric
 from .precompiled import (
     PRECOMPILED_REGISTRY,
@@ -116,13 +118,18 @@ class WasmHostContext:
 
 
 class TransactionExecutor:
-    def __init__(self, suite, registry: Optional[dict[bytes, Precompile]] = None):
+    def __init__(self, suite,
+                 registry: Optional[dict[bytes, Precompile]] = None,
+                 trace_label: str = ""):
         self.suite = suite
         self.registry = dict(PRECOMPILED_REGISTRY if registry is None else registry)
+        # the node's stage table (utils/otrace.py): `dag_plan` and the
+        # dag_* counters here, the evm_* frame counters in the EVM
+        self.stages = otrace.stages(trace_label)
         from .evm import EVM
-        self.evm = EVM(suite, registry=self.registry)
-        # parallel-annotation cache: address -> (abi bytes, {sel: nparams})
-        self._parallel_cache: dict[bytes, tuple[bytes, dict[bytes, int]]] = {}
+        self.evm = EVM(suite, registry=self.registry, stages=self.stages)
+        # parallel-annotation cache: address -> (abi bytes, {sel: heads})
+        self._parallel_cache: dict[bytes, tuple[bytes, dict]] = {}
         self._dag_pool: Optional[tuple] = None  # cached wave thread pool
         # block-start compatibility_version snapshot (block_number, version):
         # taken BEFORE any tx of the block executes so a same-block
@@ -445,55 +452,91 @@ class TransactionExecutor:
                            ) -> Optional[list[bytes]]:
         """Parallel-contract annotation for EVM txs: an ABI function entry
         carrying ``"parallel": N`` declares that two calls conflict iff
-        they share any of the first N (static) argument words — the
+        they share the value of any of their first N parameters — the
         reference's ParallelConfigPrecompiled registration scheme
         (bcos-executor/src/dag/CriticalFields.h:45-60, critical fields =
-        leading params of registered methods). Keys are address||argword
-        so different annotated methods touching the same account still
-        conflict with each other."""
+        leading params of registered methods). A static parameter's value
+        is its head word(s); a ``string``/``bytes`` one's is its contents,
+        read at the offset its head word gives (its head word is an offset
+        that says nothing of the value). Keys are address||value so
+        different annotated methods touching the same account still
+        conflict with each other. Calldata shorter than the declared
+        parameters, or an offset or length pointing outside it, is
+        opaque (None)."""
         try:
             raw = state.get(self.T_ABI, tx.to)
             if not raw:
                 return None
-            sel = tx.input[:4]
+            data = tx.input
+            sel = data[:4]
             if len(sel) != 4:
                 return None
-            sel_map = self._parallel_selectors(tx.to, raw)
-            n = sel_map.get(sel)
-            if not n:
+            heads = self._parallel_selectors(tx.to, raw).get(sel)
+            if not heads:
                 return None
-            keys = [tx.to + tx.input[4 + 32 * i:4 + 32 * (i + 1)]
-                    for i in range(n)]
-            if any(len(k) != 52 for k in keys):
-                return None  # calldata shorter than declared params
+            keys = []
+            for at, size, contents in heads:
+                value = data[at:at + size]
+                if len(value) != size:
+                    return None
+                if contents:
+                    start = 4 + int.from_bytes(value, "big")
+                    if start + 32 > len(data):
+                        return None
+                    end = start + 32 + int.from_bytes(
+                        data[start:start + 32], "big")
+                    if end > len(data):
+                        return None
+                    value = data[start + 32:end]
+                keys.append(tx.to + value)
             return keys
         except Exception:
             return None
 
     def _parallel_selectors(self, address: bytes, raw_abi: bytes
-                            ) -> dict[bytes, int]:
-        """{selector: parallel-param-count} for a contract's annotated
-        functions, cached per (address, abi bytes) so block planning does
-        one JSON parse + selector-hash pass per contract, not per tx."""
+                            ) -> dict[bytes, Optional[tuple]]:
+        """{selector: the critical parameters' (calldata offset of the
+        head, head bytes, contents?)} for a contract's annotated
+        functions (None: an annotation the planner cannot read, so the
+        call is opaque), cached per (address, abi bytes) so block planning
+        does one JSON parse + selector-hash pass per contract, not per
+        tx."""
         cached = self._parallel_cache.get(address)
         if cached is not None and cached[0] == raw_abi:
             return cached[1]
         import json
 
-        from ..codec import abi as abi_mod
-
-        sel_map: dict[bytes, int] = {}
+        sel_map: dict[bytes, Optional[tuple]] = {}
         for e in json.loads(raw_abi):
             if e.get("type") != "function" or not e.get("parallel"):
                 continue
-            sig = e["name"] + "(" + ",".join(
-                i["type"] for i in e.get("inputs", [])) + ")"
+            inputs = [i["type"] for i in e.get("inputs", [])]
+            sig = e["name"] + "(" + ",".join(inputs) + ")"
             sel_map[abi_mod.selector(sig, self.suite.hash)] = \
-                int(e["parallel"])
+                self._critical_heads(inputs, int(e["parallel"]))
         if len(self._parallel_cache) >= 256:
             self._parallel_cache.pop(next(iter(self._parallel_cache)))
         self._parallel_cache[address] = (raw_abi, sel_map)
         return sel_map
+
+    @staticmethod
+    def _critical_heads(inputs: list[str], n: int) -> Optional[tuple]:
+        """Where the first `n` parameters' values sit: a static type's
+        head words, or (``string``/``bytes``) the contents its head word
+        points at. None where one is an array or tuple of dynamic size,
+        or `n` passes the parameters."""
+        heads = []
+        at = 4
+        for typ in inputs[:n]:
+            t = abi_mod.parse_type(typ)
+            if t.kind in ("string", "bytes"):
+                heads.append((at, 32, True))
+            elif t.dynamic:
+                return None
+            else:
+                heads.append((at, 32 * t.head_words(), False))
+            at += 32 * t.head_words()
+        return tuple(heads) if len(heads) == n else None
 
     def execute_block_dag(self, txs: Sequence[Transaction],
                           state: StateStorage, block_number: int,
@@ -506,17 +549,19 @@ class TransactionExecutor:
         reference's tbb wave execution, TransactionExecutor.cpp:143):
         each tx gets its own overlay over the block state, and overlays
         merge back in tx order — disjoint by the planner's guarantee, so
-        the merge order is cosmetic. With the native frame interpreter
-        the ctypes calls release the GIL, so waves genuinely use
-        multiple cores; workers=1 (or single-tx waves) keeps the serial
-        fast path."""
+        the merge order is cosmetic. Only native-EVM waves are pooled
+        (`_wave_parallelizable`); workers=1 (or single-tx waves) keeps
+        the serial fast path. Counted once a block into the node's stage
+        table: `dag_plan` (the planner, conflict keys included) and
+        dag_blocks / dag_waves / dag_txs / dag_pooled_txs."""
         t0 = time.monotonic()
         # snapshot the feature-gate version from block-START state, before
         # any tx (possibly a governance raise) dirties the overlay
         from .evm import EVM as _EVM
         self._compat_snapshot = (block_number,
                                  _EVM.read_compat_version(state))
-        waves = self.plan_dag(txs, state)
+        with self.stages.stage("dag_plan"):
+            waves = self.plan_dag(txs, state)
         if workers is None:
             try:  # ops knob (e.g. pin to 1 on oversubscribed hosts);
                 # tolerant parse: a bad value must not kill block execution
@@ -525,6 +570,7 @@ class TransactionExecutor:
                 workers = 0
             workers = workers or min(8, os.cpu_count() or 1)
         receipts: list[Optional[Receipt]] = [None] * len(txs)
+        pooled = 0
         pool = None
         if workers > 1 and any(len(w) > 1 for w in waves):
             pool = self._wave_pool(workers)
@@ -536,6 +582,7 @@ class TransactionExecutor:
                         receipts[i] = self.execute_transaction(
                             txs[i], state, block_number, timestamp)
                     continue
+                pooled += len(wave)
 
                 def run_one(i: int):
                     overlay = StateStorage(state)
@@ -558,19 +605,27 @@ class TransactionExecutor:
                 pool.shutdown(wait=False, cancel_futures=True)
                 self._dag_pool = None
             raise
+        for name, n in (("dag_blocks", 1), ("dag_waves", len(waves)),
+                        ("dag_txs", len(txs)), ("dag_pooled_txs", pooled)):
+            self.stages.count(name, n)
         metric("executor.dag", n=len(txs), waves=len(waves),
                workers=workers, ms=int((time.monotonic() - t0) * 1000))
         return [r for r in receipts]
 
     def _wave_parallelizable(self, wave: list[int],
                              txs: Sequence[Transaction]) -> bool:
-        """Threads only help a wave whose execution RELEASES the GIL — the
-        native frame interpreter's ctypes calls (contract-code txs with
-        native/nevm loaded). Pure-Python precompile waves hold the GIL for
-        their whole body: pooling them buys zero parallelism and charges
-        per-tx overlay + merge + pool-dispatch overhead, which under a
-        multi-node-per-host bench turned a ~80 ms wave into seconds of
-        thread thrash. Those waves run serially on the block state."""
+        """Pool only waves of contract-code txs with native/nevm loaded:
+        the interpreter loop runs in C with the GIL released by ctypes.
+        Every SLOAD/SSTORE/log is a host callback that takes the GIL
+        back, though, and the overlay, merge and dispatch are Python, so
+        whether the pool pays is measured, not assumed: the air4-parallelok
+        cell reads `dag_pooled_share` and `execute_ms_per_block`, and
+        FBTPU_DAG_WORKERS=1 is the serial reading (PERF.md). Pure-Python
+        precompile waves hold the GIL for their whole body: pooling them
+        buys zero parallelism and charges per-tx overlay + merge +
+        pool-dispatch overhead, which under a multi-node-per-host bench
+        turned a ~80 ms wave into seconds of thread thrash. Those waves
+        run serially on the block state."""
         if not self.evm.native:
             return False
         return any(txs[i].to and txs[i].to not in self.registry

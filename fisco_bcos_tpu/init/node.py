@@ -368,7 +368,8 @@ class Node:
                 self.overload.add_signal(
                     "compaction_debt",
                     lambda: debt_fn() / debt_norm)
-        self.executor = TransactionExecutor(self.suite)
+        self.executor = TransactionExecutor(self.suite,
+                                            trace_label=self.trace_label)
         self.scheduler = Scheduler(self.storage, self.ledger, self.executor,
                                    self.suite, self.txpool,
                                    pipeline=cfg.pipeline_commit,

@@ -466,13 +466,20 @@ STAGES = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "gossip",
           "rpc_respond", "prime",
           # inside `commit`: the changeset staged in the storage (a page
           # layer translates it first), then made durable and applied
-          "storage_prepare", "storage_commit")
+          "storage_prepare", "storage_commit",
+          # inside `execute`: the DAG planner, conflict keys included
+          "dag_plan")
 # what the edge counts beside its stages, per cohort and never per stamp:
 # receipts a `sendTransaction` batch was answered, and those of them taken
 # from the committed block's shared fragments; batches of `sendTransaction`
-# received, and those the lane took as one piece (rpc/server.py)
+# received, and those the lane took as one piece (rpc/server.py). The
+# executor's, once a block: blocks run through the DAG path, their waves,
+# transactions, and transactions run in a thread-pooled wave
+# (executor.py); and once a frame, EVM frames and those of them the native
+# interpreter ran (evm.py `_run`)
 COUNTERS = ("cohort_receipts", "cohort_receipts_shared", "cohorts",
-            "cohorts_whole")
+            "cohorts_whole", "dag_blocks", "dag_waves", "dag_txs",
+            "dag_pooled_txs", "evm_frames", "evm_native_frames")
 STAGE_HISTOGRAM = "bcos_tx_stage_seconds"
 # two series of the histogram are older than the stage names
 _HISTOGRAM_LABEL = {"lane_wait": "ingest", "seal_wait": "queueing"}
