@@ -473,10 +473,11 @@ STAGES = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "gossip",
 # receipts a `sendTransaction` batch was answered, and those of them taken
 # from the committed block's shared fragments; batches of `sendTransaction`
 # received, and those the lane took as one piece (rpc/server.py). The
-# executor's, once a block: blocks run through the DAG path, their waves,
-# transactions, and transactions run in a thread-pooled wave
-# (executor.py); and once a frame, EVM frames and those of them the native
-# interpreter ran (evm.py `_run`)
+# executor's, once a block: blocks run through the DAG path, their waves and
+# transactions (executor.py), and `dag_pooled_txs`, transactions run in a
+# thread-pooled wave, which reads 0 since every wave runs serially; and once
+# a frame, EVM frames and those of them the native interpreter ran (evm.py
+# `_run`)
 COUNTERS = ("cohort_receipts", "cohort_receipts_shared", "cohorts",
             "cohorts_whole", "dag_blocks", "dag_waves", "dag_txs",
             "dag_pooled_txs", "evm_frames", "evm_native_frames")

@@ -148,9 +148,10 @@ def test_mixed_block_dag_equals_serial():
     assert sorted(st1.changeset().items()) == sorted(st2.changeset().items())
 
 
-def test_parallel_wave_execution_equals_serial():
-    """Thread-pooled wave execution (per-tx overlays merged back) must be
-    bit-identical to workers=1 serial execution — receipts AND state."""
+def test_wide_wave_execution_equals_serial():
+    """Waves of six independent transactions, precompile and EVM, run in
+    wave order must be bit-identical to block-order serial execution —
+    receipts AND state."""
     contract = b"\x55" * 20
 
     def build(ex, st, kp):
@@ -164,10 +165,14 @@ def test_parallel_wave_execution_equals_serial():
         return txs
 
     results = []
-    for workers in (1, 4):
+    for dag in (True, False):
         ex, st, kp = fresh()
         txs = build(ex, st, kp)
-        rcs = ex.execute_block_dag(txs, st, 1, 0, workers=workers)
+        if dag:
+            assert max(map(len, ex.plan_dag(txs, st))) >= 6
+            rcs = ex.execute_block_dag(txs, st, 1, 0)
+        else:
+            rcs = [ex.execute_transaction(t, st, 1, 0) for t in txs]
         results.append((
             [(r.status, r.gas_used, r.output) for r in rcs],
             sorted(st.changeset().items()),
@@ -177,17 +182,16 @@ def test_parallel_wave_execution_equals_serial():
 
 def test_create_table_then_set_same_block():
     """createTable must act as a barrier: a set to the just-created table
-    later in the same block sees it, parallel or serial."""
-    for workers in (1, 4):
-        ex, st, kp = fresh()
-        txs = [make_tx(SUITE, kp, pc.KV_TABLE_ADDRESS,
-                       pc.encode_call("createTable",
-                                      lambda w: w.text("tnew")), "ct"),
-               kv_tx(kp, "cs1", "tnew", b"k1", b"v1"),
-               kv_tx(kp, "cs2", "tnew", b"k2", b"v2")]
-        rcs = ex.execute_block_dag(txs, st, 1, 0, workers=workers)
-        assert [r.status for r in rcs] == [0, 0, 0], \
-            [(r.status, r.message) for r in rcs]
-        assert st.get("u_tnew", b"k1") == b"v1"
-        waves = ex.plan_dag(txs, st)
-        assert waves[0] == [0]  # createTable is a barrier wave
+    later in the same block sees it."""
+    ex, st, kp = fresh()
+    txs = [make_tx(SUITE, kp, pc.KV_TABLE_ADDRESS,
+                   pc.encode_call("createTable",
+                                  lambda w: w.text("tnew")), "ct"),
+           kv_tx(kp, "cs1", "tnew", b"k1", b"v1"),
+           kv_tx(kp, "cs2", "tnew", b"k2", b"v2")]
+    rcs = ex.execute_block_dag(txs, st, 1, 0)
+    assert [r.status for r in rcs] == [0, 0, 0], \
+        [(r.status, r.message) for r in rcs]
+    assert st.get("u_tnew", b"k1") == b"v1"
+    waves = ex.plan_dag(txs, st)
+    assert waves[0] == [0]  # createTable is a barrier wave

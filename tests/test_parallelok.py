@@ -12,6 +12,7 @@ import sys
 
 import pytest
 
+from fisco_bcos_tpu.codec import abi as abi_mod
 from fisco_bcos_tpu.crypto.suite import make_suite
 from fisco_bcos_tpu.executor import nevm
 from fisco_bcos_tpu.executor.evm import EVM, T_STORE, TxEnv
@@ -223,19 +224,24 @@ def _block_state(users: list) -> StateStorage:
     return st
 
 
-@pytest.mark.parametrize("workers", [1, 4])
+NATIVE = [pytest.param(True, marks=pytest.mark.skipif(
+    not nevm.available(), reason="libnevm.so not built")), False]
+
+
+@pytest.mark.parametrize("native", NATIVE)
 @pytest.mark.parametrize("hot", [6, 40])
-def test_block_equals_reference_replay_and_serial(workers, hot):
+def test_block_equals_reference_replay_and_serial(native, hot):
     users = [b"acct-%07d" % i for i in range(hot)]
     moves = _moves(100 + hot, 300, hot)
     kp = SUITE.generate_keypair(b"parallelok-block")
     txs = [_tx(_call("transfer", *m), f"b{i}").sign(SUITE, kp)
            for i, m in enumerate(moves)]
-    label = f"po-{workers}-{hot}"
+    label = f"po-{native}-{hot}"
     otrace.stages(label).reset()
     ex = TransactionExecutor(SUITE, trace_label=label)
+    ex.evm.native = native
     st = _block_state(users)
-    rcs = ex.execute_block_dag(txs, st, 1, 0, workers=workers)
+    rcs = ex.execute_block_dag(txs, st, 1, 0)
     assert [(r.status, r.output, r.logs) for r in rcs] == [(0, b"", [])] * 300
 
     want, untouched, refused = REFERENCE.expected(
@@ -255,12 +261,59 @@ def test_block_equals_reference_replay_and_serial(workers, hot):
     waves = len(ex.plan_dag(txs, st))
     assert (counts["dag_blocks"], counts["dag_txs"], counts["dag_waves"]) \
         == (1, 300, waves)
-    assert counts["dag_pooled_txs"] == (
-        0 if workers == 1 or not ex.evm.native else
-        sum(len(w) for w in ex.plan_dag(txs, st) if len(w) > 1))
+    assert counts["dag_pooled_txs"] == 0  # every wave runs serially
     assert counts["evm_frames"] == 300
-    assert counts["evm_native_frames"] == (300 if ex.evm.native else 0)
+    assert counts["evm_native_frames"] == (300 if native else 0)
     assert otrace.stages(label).snapshot()["dag_plan"]["count"] == 1
+
+
+SPIN = b"\x5a" * 20
+SPIN_ABI = json.dumps([{"type": "function", "name": "spin",
+                        "inputs": [{"name": "key", "type": "uint256"}],
+                        "outputs": [], "parallel": 1}])
+
+
+def _spin_state(rounds: int) -> StateStorage:
+    """`spin(uint256 key)`: `acc = key`, then `rounds` times `acc = acc *
+    acc + n` with `n` counting down, then `s_store[key] = acc`: one SSTORE
+    behind a long loop in the interpreter."""
+    st = StateStorage(MemoryStorage())
+    st.set("s_code", SPIN, po.assemble([
+        po._p(4), po.CALLDATALOAD, po.DUP1, po._p(rounds),  # [key, acc, n]
+        po._l("loop"),
+        po.DUP2, po.DUP1, 0x02, po.DUP2, po.ADD,  # MUL: acc * acc + n
+        po.SWAP2, po.POP,
+        po._p(1), po.SWAP1, po.SUB,                   # n - 1
+        po.DUP1, po._r("loop"), po.JUMPI,
+        po.POP, po.SWAP1, po.SSTORE, po.STOP,
+    ]))
+    st.set(TransactionExecutor.T_ABI, SPIN, SPIN_ABI.encode())
+    return st
+
+
+@pytest.mark.skipif(not nevm.available(), reason="libnevm.so not built")
+def test_compute_bound_wave_equals_the_python_interpreters_serial_replay():
+    kp = SUITE.generate_keypair(b"spin")
+    txs = [Transaction(to=SPIN, input=abi_mod.encode_call(
+        "spin(uint256)", [k + 1], H), nonce=f"spin{k}",
+        block_limit=100).sign(SUITE, kp) for k in range(12)]
+    label = "po-spin"
+    otrace.stages(label).reset()
+    ex = TransactionExecutor(SUITE, trace_label=label)
+    st = _spin_state(2_000)
+    assert ex.plan_dag(txs, st) == [list(range(12))]
+    rcs = ex.execute_block_dag(txs, st, 1, 0)
+    counts = otrace.stages(label).counters()
+    assert (counts["dag_pooled_txs"], counts["evm_native_frames"]) == (0, 12)
+
+    serial = _spin_state(2_000)
+    ex2 = TransactionExecutor(SUITE)
+    ex2.evm.native = False
+    rcs2 = [ex2.execute_transaction(t, serial, 1, 0) for t in txs]
+    assert [(r.status, r.gas_used, r.output, r.logs) for r in rcs] == \
+        [(r.status, r.gas_used, r.output, r.logs) for r in rcs2]
+    assert all(r.status == 0 for r in rcs)
+    assert _dump(st) == _dump(serial)
 
 
 def test_reference_replay_is_unchecked_uint256():
