@@ -26,8 +26,9 @@ in `analysis/lockorder.py`.
 The package also hosts the **continuous-profiling plane** (ISSUE 15):
 
   * **profiler** — always-on low-hz sampling profiler (folded stacks by
-    thread role + pipeline stage, per-thread GIL-held CPU attribution
-    from /proc, slow-span burst captures linked to trace ids, the
+    thread role + otrace stage, per-thread GIL-held CPU attribution
+    from /proc, the process's CPU by thread role from each thread's own
+    clock, slow-span burst captures linked to trace ids, the
     zero-dependency flamegraph renderer behind `GET /profile`);
   * **hostweather** — the PSI/steal/spin-score stamp every bench row
     carries, consumed by `tools/perf_gate.py`'s noise-aware bands.

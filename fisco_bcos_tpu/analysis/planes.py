@@ -68,25 +68,21 @@ PLANE_CONTRACTS: dict[str, frozenset] = {
     "outbox": frozenset({"fsync", "subprocess", "suite_batch"}),
 }
 
-# Thread-name prefixes NOT in analysis/profiler._ROLE_PREFIXES, or whose
-# profiler role is too coarse for contract purposes. Consulted FIRST (the
-# profiler folds sched-notify into "commit" and every "ws-" thread into
-# "edge", which is right for flamegraphs but too coarse here: the notifier
-# must not send, the per-session WS reader may).
+# Thread-name prefixes whose profiler role (analysis/profiler._ROLE_PREFIXES,
+# the subsystem a thread's CPU is charged to) is too coarse for contract
+# purposes. Consulted FIRST: the profiler puts every "ws-" thread and the
+# subscription fan-out under "edge" and the crypto lane's dispatcher under
+# "crypto", which is right for CPU by role but not here: the event loop
+# must not send, the per-session WS reader and the fan-out may, and the
+# dispatcher carries the lane's own contract.
 EXTRA_ROLE_PREFIXES: tuple[tuple[str, str], ...] = (
-    ("sched-notify", "notify"),
     ("ws-push", "outbox"),
     ("ws-dispatch", "worker"),
     ("ws-", "ws-session"),
+    ("sub-fanout", "other"),
     ("tx-sync", "sync"),
-    ("snapshot", "sync"),
-    ("block-sync", "sync"),
-    ("sealer", "seal"),
-    ("xshard", "control"),
-    ("election-", "control"),
     ("svc-", "worker"),
-    ("max-activate", "control"),
-    ("remote-front", "net"),
+    ("crypto-lane", "lane"),
 )
 
 # Roots whose thread name is dynamic at the spawn site (name=self._name

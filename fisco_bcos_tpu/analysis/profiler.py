@@ -12,10 +12,16 @@ blind. This module is the missing instrument, stdlib-only:
     each thread's stack into `role;stage;file:func;...` lines and
     aggregates them in a bounded epoch ring (recent-window semantics, hard
     entry cap — a long-lived node never grows the profile without bound).
-  * per-thread ROLE classification by thread name (ingest / commit / pbft /
-    edge / lane / compaction / ...), so a flamegraph's first split answers
-    "which subsystem", not "which anonymous thread".
-  * per-thread CPU accounting via `/proc/self/task/<tid>/stat`: each
+  * per-thread ROLE classification by thread name (ingest / execute /
+    commit / notify / pbft / edge / crypto / compaction / ...), so a
+    flamegraph's first split answers "which subsystem", not "which
+    anonymous thread"; the next are the otrace stages the thread is inside
+    (`utils/otrace.py` `THREAD_STAGES`), under the stage table's names.
+  * the process's CPU by role, exact at the time of reading
+    (`cpu_by_role`): every live Python thread's own CPU clock, and
+    `native`, the rest of the process's (runtime threads with no Python
+    frame, threads that have exited). No sampler needed.
+  * per-thread CPU attribution via `/proc/self/task/<tid>/stat`: each
     sampling tick reads every OS thread's utime+stime and attributes the
     delta to the function at the top of that thread's sampled Python stack.
     CPU burned by a *Python* thread is GIL-held time except inside
@@ -31,10 +37,10 @@ blind. This module is the missing instrument, stdlib-only:
   * a zero-dependency flamegraph renderer (`flame_html`) — self-contained
     HTML+JS, served by `GET /profile?fmt=flame` on the rpc/ops edge.
 
-Cost contract: DISARMED (hz<=0) there is no sampler thread and the only
-hot-path residue is the `stage(...)` markers — two dict writes per *block*
-(not per tx). Armed at the default 5 hz the sampler's own CPU is measured
-and exported (`bcos_profile_overhead_seconds_total`); the chain_bench
+Cost contract: DISARMED (hz<=0) there is no sampler thread and this
+module costs the hot path nothing (the stage labels are otrace's). Armed
+at the default 5 hz the sampler's own CPU is measured and exported
+(`bcos_profile_overhead_seconds_total`); the chain_bench
 `--profile-attrib` A/B pins the end-to-end cost under 3%.
 """
 
@@ -55,31 +61,46 @@ except (AttributeError, ValueError, OSError):  # non-POSIX fallback
 
 # -- thread-role classification -------------------------------------------
 # prefix -> role; first match wins. Matches the repo's thread-naming
-# convention (every subsystem names its threads at spawn).
+# convention (every subsystem names its threads at spawn); a node's
+# threads all have a role here, none falls to `other`.
 _ROLE_PREFIXES = (
     ("tx-ingest", "ingest"),
     ("sched-commit", "commit"),
-    ("sched-notify", "commit"),
-    ("pbft", "pbft"),          # worker + pbft-exec pool
+    ("sched-notify", "notify"),   # commit observers: `prime`, the fan-out
+    ("pbft-exec", "execute"),     # fill / execute / roots of agreed blocks
+    ("pbft", "pbft"),
+    ("dmc", "execute"),
+    ("exec-", "execute"),         # out-of-process pool's pumps
     ("sealer", "seal"),
-    ("crypto-lane", "lane"),   # dispatcher + crypto-lane-w fan-out pool
+    ("crypto-lane", "crypto"),    # dispatcher + crypto-lane-w fan-out pool
+    ("nativeec", "crypto"),       # the host door's concurrent native calls
     ("storage-compact", "compaction"),
     ("block-sync", "sync"),
-    ("dag", "execute"),        # DAG executor pool (executor/executor.py)
-    ("dmc", "execute"),
+    ("snapshot", "sync"),
     ("rpc-worker", "edge"),
     ("ops-worker", "edge"),
     ("ops-http", "edge"),
     ("jsonrpc-http", "edge"),
     ("ws-", "edge"),
+    ("sub-fanout", "edge"),
+    ("svc-", "edge"),
+    ("tx-sync", "net"),
     ("gw-", "net"),
     ("p2p-", "net"),
     ("remote-front", "net"),
     ("health-probe", "control"),
     ("overload-ctl", "control"),
+    ("xshard", "control"),
+    ("election-", "control"),
+    ("qelection-", "control"),
+    ("max-activate", "control"),
     ("profile-", "profiler"),
     ("MainThread", "main"),
 )
+# every role `cpu_by_role` reports, from the start: those of the map,
+# `other`, and `native` (the process's CPU that no live Python thread holds)
+ROLES = tuple(dict.fromkeys(r for _p, r in _ROLE_PREFIXES)) \
+    + ("other", "native")
 
 
 def classify(thread_name: str) -> str:
@@ -90,41 +111,18 @@ def classify(thread_name: str) -> str:
     return "other"
 
 
-# -- per-thread stage markers ---------------------------------------------
-# {thread ident: stage name} — written by the stage() scopes the scheduler/
-# ingest/sealer hot loops hold around block-level work. A plain dict is
-# enough: CPython dict item writes are atomic under the GIL, and a sampler
-# reading a torn moment at worst mislabels ONE sample's stage.
-_THREAD_STAGE: dict[int, str] = {}
-
-
-class stage:
-    """`with profiler.stage("execute"): ...` — labels the calling thread's
-    samples with a pipeline stage. Disarmed cost: two dict ops per scope
-    (block-level, never per-tx)."""
-
-    __slots__ = ("name", "prev", "ident")
-
-    def __init__(self, name: str):
-        self.name = name
-
-    def __enter__(self):
-        ident = threading.get_ident()
-        self.ident = ident
-        self.prev = _THREAD_STAGE.get(ident)
-        _THREAD_STAGE[ident] = self.name
-        return self
-
-    def __exit__(self, *exc):
-        if self.prev is None:
-            _THREAD_STAGE.pop(self.ident, None)
-        else:
-            _THREAD_STAGE[self.ident] = self.prev
-        return False
+def _stage_labels() -> dict:
+    """otrace's {thread ident: open stage names, innermost last}. Imported
+    here, not at the top: tools/bcosflow.py loads this file alone."""
+    from ..utils.otrace import THREAD_STAGES
+    return THREAD_STAGES
 
 
 def current_stage(ident: int) -> Optional[str]:
-    return _THREAD_STAGE.get(ident)
+    """The innermost otrace stage open on thread `ident` (a stage that
+    starts and stops on one thread labels it while it is open), or None."""
+    names = _stage_labels().get(ident)
+    return names[-1] if names else None
 
 
 # -- folded-stack aggregation ---------------------------------------------
@@ -156,10 +154,11 @@ class _Folded:
             out[k] = out.get(k, 0) + v
 
 
-def _fold_frame(frame, role: str, stg: Optional[str],
+def _fold_frame(frame, role: str, stages: tuple = (),
                 max_depth: int = 48) -> str:
-    """One thread's live frame -> `role;stage;file:func;...` (root first,
-    leaf last — the flamegraph convention). Over-deep stacks keep both
+    """One thread's live frame -> `role;stage.<s>;...;file:func;...` (root
+    first, leaf last — the flamegraph convention; the open stages outermost
+    first, so `stage.roots;stage.state_root` nests). Over-deep stacks keep both
     ENDS around an elision marker: dropping the root frames would give
     the line a mid-stack root that can't merge with the same code path
     sampled shallower, and dropping the leaf would lose the one frame
@@ -175,10 +174,7 @@ def _fold_frame(frame, role: str, stg: Optional[str],
         keep_head = max_depth // 2
         keep_tail = max_depth - keep_head - 1
         parts = parts[:keep_head] + ["(...)"] + parts[-keep_tail:]
-    head = [role]
-    if stg:
-        head.append(f"stage.{stg}")
-    return ";".join(head + parts)
+    return ";".join([role] + [f"stage.{s}" for s in stages] + parts)
 
 
 def _leaf_of(frame) -> str:
@@ -202,13 +198,57 @@ def read_task_cpu() -> dict[int, float]:
                 raw = f.read()
         except OSError:
             continue  # thread exited between listdir and open
-        # comm may contain spaces/parens: fields start after the LAST ')'
         try:
-            rest = raw[raw.rindex(b")") + 2:].split()
-            # rest[11] = utime, rest[12] = stime (stat fields 14/15)
-            out[int(tid)] = (int(rest[11]) + int(rest[12])) / _CLK_TCK
+            out[int(tid)] = _stat_cpu(raw)
         except (ValueError, IndexError):
             continue
+    return out
+
+
+def _stat_cpu(raw: bytes) -> float:
+    """utime + stime seconds of one `/proc/.../stat` line."""
+    # comm may contain spaces/parens: fields start after the LAST ')'
+    rest = raw[raw.rindex(b")") + 2:].split()
+    # rest[11] = utime, rest[12] = stime (stat fields 14/15)
+    return (int(rest[11]) + int(rest[12])) / _CLK_TCK
+
+
+def _thread_cpu(native_id: int) -> Optional[float]:
+    """One thread's cumulative CPU seconds, from its own clock: the clock
+    id `time.pthread_getcpuclockid` gives on Linux, made from the kernel's
+    thread id, so that a thread that has just exited is an `OSError` and
+    never a read of its freed `pthread_t`. Its stat file where that
+    raises; None where neither is there."""
+    try:
+        return time.clock_gettime((~native_id << 3) | 6)
+    except (OSError, OverflowError):
+        pass
+    try:
+        with open(f"/proc/self/task/{native_id}/stat", "rb") as f:
+            return _stat_cpu(f.read())
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def cpu_by_role() -> dict[str, float]:
+    """{role: cumulative CPU seconds of this process} at the time of
+    reading, every role of `ROLES` from the start: each live Python
+    thread's own CPU clock under its name's role, and `native`, the
+    process's user + system CPU (`time.process_time`) that those threads
+    do not hold: runtime threads with no Python frame (XLA, libtpu) and
+    threads that have exited. The roles sum to the process's CPU. Reads
+    one clock a thread, only when asked; needs no sampler."""
+    out = dict.fromkeys(ROLES, 0.0)
+    held = 0.0
+    for th in threading.enumerate():
+        nid = th.native_id
+        sec = _thread_cpu(nid) if nid is not None else None
+        if sec is None:
+            continue  # not started, or gone: its CPU stays in `native`
+        out[classify(th.name)] += sec
+        held += sec
+    # read last: the process's clock then covers every thread read above
+    out["native"] = max(0.0, time.process_time() - held)
     return out
 
 
@@ -363,6 +403,7 @@ class SamplingProfiler:
         # ident -> (role, stage, leaf) for CPU attribution below
         attrib: dict[int, tuple] = {}
         by_native: dict[int, int] = {}
+        labels = _stage_labels()
         with self._lock:
             fold = self._epochs[-1]
             now_m = time.monotonic()
@@ -376,9 +417,10 @@ class SamplingProfiler:
                     continue
                 name = th.name if th is not None else "?"
                 role = classify(name)
-                stg = _THREAD_STAGE.get(ident)
-                fold.add(_fold_frame(frame, role, stg))
-                attrib[ident] = (role, stg or "", _leaf_of(frame))
+                stages = labels.get(ident, ())
+                fold.add(_fold_frame(frame, role, stages))
+                attrib[ident] = (role, stages[-1] if stages else "",
+                                 _leaf_of(frame))
                 nid = getattr(th, "native_id", None) if th else None
                 if nid is not None:
                     by_native[nid] = ident
@@ -394,8 +436,8 @@ class SamplingProfiler:
             self._overhead_s += dt
             n_threads = len(frames)
         # metrics ride the CPU-scan cadence (<= 1/s), not every tick: the
-        # per-role rollup iterates the whole attribution dict and the
-        # registry lock contends with hot-path metric writers
+        # per-role read takes one clock read a thread and the registry
+        # lock contends with hot-path metric writers
         if not due:
             return
         try:
@@ -408,7 +450,7 @@ class SamplingProfiler:
             REGISTRY.inc("bcos_profile_samples_total", d_samples)
             REGISTRY.inc("bcos_profile_overhead_seconds_total", d_over)
             REGISTRY.set_gauge("bcos_profile_threads", n_threads)
-            for role, sec in self.cpu_by_role().items():
+            for role, sec in cpu_by_role().items():
                 REGISTRY.set_gauge("bcos_profile_cpu_seconds",
                                    round(sec, 4), labels={"role": role})
         except Exception:  # noqa: BLE001
@@ -457,6 +499,7 @@ class SamplingProfiler:
         me = threading.current_thread()
         interval = 1.0 / max(1.0, hz)
         deadline = time.monotonic() + seconds
+        labels = _stage_labels()
         while time.monotonic() < deadline:
             if stop is not None and stop.is_set():
                 return
@@ -468,7 +511,7 @@ class SamplingProfiler:
                     continue
                 name = th.name if th is not None else "?"
                 fold.add(_fold_frame(frame, classify(name),
-                                     _THREAD_STAGE.get(ident)))
+                                     labels.get(ident, ())))
             time.sleep(interval)
 
     def capture(self, seconds: float, hz: Optional[float] = None) -> str:
@@ -559,21 +602,6 @@ class SamplingProfiler:
                 overflow += ep.overflow
         return _folded_text(merged, overflow)
 
-    def cpu_by_role(self) -> dict[str, float]:
-        with self._lock:
-            return self._cpu_by_role_locked()
-
-    def _cpu_by_role_locked(self) -> dict[str, float]:
-        """Caller holds self._lock (it is non-reentrant); _cpu_by_key is
-        mutated under the lock by attribution()/reset() on other threads,
-        so an unlocked iteration could see the dict resize mid-walk."""
-        out: dict[str, float] = {}
-        for (role, _stg, _leaf), sec in self._cpu_by_key.items():
-            out[role] = out.get(role, 0.0) + sec
-        if self._cpu_self > 0:
-            out["profiler"] = out.get("profiler", 0.0) + self._cpu_self
-        return out
-
     def attribution(self) -> dict:
         """CPU attribution snapshot for chain_bench --profile-attrib:
         per-(role, stage, function) GIL-held CPU seconds plus the honest
@@ -633,6 +661,7 @@ class SamplingProfiler:
 
     def stats(self) -> dict:
         """Cheap snapshot for getSystemStatus / the /status document."""
+        by_role = cpu_by_role()  # one clock read a thread, outside the lock
         with self._lock:
             distinct = sum(len(ep.counts) for ep in self._epochs)
             overflow = sum(ep.overflow for ep in self._epochs)
@@ -652,9 +681,7 @@ class SamplingProfiler:
                 else 0.0,
                 "cpu_total_seconds": round(self._cpu_total, 3),
                 "cpu_attributed_seconds": round(self._cpu_attributed, 3),
-                "cpu_by_role": {r: round(s, 3)
-                                for r, s in
-                                self._cpu_by_role_locked().items()},
+                "cpu_by_role": {r: round(s, 3) for r, s in by_role.items()},
                 "top_gil_holders": [
                     {"role": k[0], "stage": k[1] or None, "func": k[2],
                      "cpu_seconds": round(v, 3)} for k, v in top],

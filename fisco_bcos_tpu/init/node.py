@@ -605,7 +605,10 @@ class Node:
             "groups": reg.groups() if reg is not None else [cfg.group_id],
             "trace": {**otrace.TRACER.stats(),
                       "stages": stage_table.snapshot(),
-                      "counters": stage_table.counters()},
+                      "counters": stage_table.counters(),
+                      # the process's CPU seconds by thread role: one node
+                      # a process in deployments, so the node's
+                      "threads": _prof.cpu_by_role()},
             "profile": _prof.PROFILER.stats(),
             "overload": self.overload.stats()
             if self.overload is not None else None,

@@ -110,7 +110,8 @@ class DmcExecutor:
             finally:
                 locks.release_all(token)
 
-        pool = ThreadPoolExecutor(max_workers=self.max_workers)
+        pool = ThreadPoolExecutor(max_workers=self.max_workers,
+                                  thread_name_prefix="dmc-worker")
         try:
             for wave in waves:
                 # group by shard; shards run concurrently, shard-serial inside

@@ -45,7 +45,6 @@ from collections import OrderedDict
 from typing import Callable, Optional, Sequence
 
 from ..analysis import lockcheck as lc
-from ..analysis.profiler import stage as _prof_stage
 from ..executor.executor import TransactionExecutor
 from ..ledger.ledger import Ledger
 from ..protocol import Block, BlockHeader, ParentInfo, Receipt, Transaction
@@ -225,11 +224,7 @@ class Scheduler:
         with self._exec_lock:
             self._exec_busy = True
             try:
-                # profiler stage mark (analysis/profiler.py): samples of
-                # whatever thread drives execution (sealer, PBFT worker,
-                # sync) carry stage=execute — two dict writes per block
-                with _prof_stage("execute"):
-                    return self._execute_locked(block, sealer_list, fill)
+                return self._execute_locked(block, sealer_list, fill)
             finally:
                 self._exec_busy = False
                 fill.cancel()  # a refused block filled nothing
@@ -290,27 +285,33 @@ class Scheduler:
         with blk.stage("execute", t0=fill.stop()) as executing:
             receipts = self._execute_stage(txs, state, backend, header)
 
-        # finalise header: parent info + roots
+        # finalise header: parent info + roots, each part a stage of its
+        # own inside `roots`; the header hash is what is left of it
         with blk.stage("roots", t0=executing.t1) as rooting:
             header.parent_info = [ParentInfo(header.number - 1, parent_hash)]
-            header.txs_root = block.calculate_txs_root(self.suite)
+            with blk.stage("txs_root"):
+                header.txs_root = block.calculate_txs_root(self.suite)
             block.receipts = receipts
-            header.receipts_root = block.calculate_receipts_root(self.suite)
-            self.ledger.prewrite_block(block, state)
-            changes = state.changeset()
-            # per-CHANGESET root, deliberately NOT cumulative: identical
-            # whether the parent's changeset is durable or still speculative
-            if self.state_index:
-                root, leaf_index = \
-                    self.executor.state_root_with_leaves(changes)
-                header.state_root = root
-                # staged AFTER the root so the row never feeds its own
-                # tree; re-export picks it up for the same 2PC commit
-                self.ledger.write_state_index(state, header.number,
-                                              leaf_index)
+            with blk.stage("receipts_root"):
+                header.receipts_root = block.calculate_receipts_root(
+                    self.suite)
+            with blk.stage("prewrite"):
+                self.ledger.prewrite_block(block, state)
+            with blk.stage("state_root"):
                 changes = state.changeset()
-            else:
-                header.state_root = self.executor.state_root(changes)
+                # per-CHANGESET root, deliberately NOT cumulative: identical
+                # whether the parent's changeset is durable or speculative
+                if self.state_index:
+                    root, leaf_index = \
+                        self.executor.state_root_with_leaves(changes)
+                    header.state_root = root
+                    # staged AFTER the root so the row never feeds its own
+                    # tree; re-export picks it up for the same 2PC commit
+                    self.ledger.write_state_index(state, header.number,
+                                                  leaf_index)
+                    changes = state.changeset()
+                else:
+                    header.state_root = self.executor.state_root(changes)
             header.gas_used = sum(r.gas_used for r in receipts)
             header.invalidate()
             if sealer_list is not None:
@@ -524,8 +525,7 @@ class Scheduler:
         with self._lock:
             guard = self._executed.get(hh)
         try:
-            with _prof_stage("commit"):
-                return self._commit_block_inner(header, hh)
+            return self._commit_block_inner(header, hh)
         except BaseException:
             # an exception ESCAPING the commit (injected fault, observer
             # bug) must not strand the result half-committed: without this

@@ -186,9 +186,7 @@ class Sealer(Worker):
         # seal against the height this proposal will OCCUPY: with
         # pipelining, `number` can run ahead of the committed height, and
         # a tx expiring between them would burn its seal slot for nothing
-        from ..analysis.profiler import stage as _prof_stage
-        with _prof_stage("seal"):
-            txs, hashes = self.txpool.seal(limit, for_number=number)
+        txs, hashes = self.txpool.seal(limit, for_number=number)
         if not txs:
             # pending txs exist but none sealable right now (inflight in
             # another proposal / expired at this height) — unseal, commit

@@ -356,9 +356,8 @@ class IngestLane:
         # drained set; a frame that does not parse, or whose block_limit
         # passed while it sat in the queue, is answered by the pool's
         # precheck before any crypto
-        from ..analysis.profiler import stage as _prof_stage
         t0 = time.perf_counter()
-        with _prof_stage("ingest.admit"), self.stages.stage("admit"):
+        with self.stages.stage("admit"):
             cols = decode_columns([e.wire for e in batch])
             cols.traces = {i: e.ctx for i, e in enumerate(batch)
                            if e.ctx is not None}

@@ -37,14 +37,19 @@ propagation — while staying stdlib-only:
     session sees the stage on the device's clock. The per-block holder
     (`BlockStages`) also carries the block's bound span context, so
     sealer/consensus/scheduler stamp one block without threading it
-    through every signature.
+    through every signature. A stage that starts and stops on one thread
+    also reads that thread's CPU clock at both ends (work against wait:
+    the aggregate's `cpu_seconds` over `cpu_wall_seconds`) and, while it
+    is open, labels the thread for the sampling profiler
+    (`THREAD_STAGES`), so the flamegraph speaks the stage table's names.
 
 Cost contract: with no context attached and sampling off, the
 instrumented hot paths pay one branch (plus, where slow-capture applies,
 one monotonic clock read); span dicts are only materialised for sampled
 or slow spans. Stages are stamped per cohort and per block, never per
 transaction: two clock reads, one locked add, one histogram observation
-and an inert TraceMe each.
+and an inert TraceMe each, and two thread CPU clock reads where the
+stage stays on its thread.
 """
 
 from __future__ import annotations
@@ -468,7 +473,16 @@ STAGES = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "gossip",
           # layer translates it first), then made durable and applied
           "storage_prepare", "storage_commit",
           # inside `execute`: the DAG planner, conflict keys included
-          "dag_plan")
+          "dag_plan",
+          # inside `roots`: the transactions' root, the receipts' encoding
+          # and root, the ledger's rows of the block, the state root and
+          # its index rows; what is left of `roots` is the header hash
+          "txs_root", "receipts_root", "prewrite", "state_root")
+# the stages that start on one thread and stop on another, or may: waits
+# by nature (a queue's, a round's, the client's turn). They read no thread
+# CPU clock and label no thread
+CROSS_THREAD = frozenset(("rpc_no_request", "lane_wait", "round_wait",
+                          "seal_wait", "consensus_pre", "consensus_wait"))
 # what the edge counts beside its stages, per cohort and never per stamp:
 # receipts a `sendTransaction` batch was answered, and those of them taken
 # from the committed block's shared fragments; batches of `sendTransaction`
@@ -511,12 +525,37 @@ def _annotate(name: str):
     return ann
 
 
+# {thread ident: the names of the stages open on that thread, innermost
+# last}: the sampling profiler's stage label (analysis/profiler.py). Only
+# stages outside `CROSS_THREAD` enter it. A value is a tuple replaced
+# whole, so a sampler on another thread never reads one half-written
+THREAD_STAGES: dict[int, tuple] = {}
+
+
+def _label(ident: int, name: str) -> None:
+    THREAD_STAGES[ident] = THREAD_STAGES.get(ident, ()) + (name,)
+
+
+def _unlabel(ident: int, name: str) -> None:
+    names = list(THREAD_STAGES.get(ident, ()))
+    if name in names:  # the innermost of that name: stages nest
+        del names[len(names) - 1 - names[::-1].index(name)]
+    if names:
+        THREAD_STAGES[ident] = tuple(names)
+    else:
+        THREAD_STAGES.pop(ident, None)
+
+
 class Stage:
     """One open stage. `stop()` closes it into the three sinks; as a
     context manager the scope is the stage. `t1` is the stop time, for
-    the stage that starts where this one ended."""
+    the stage that starts where this one ended. A stage outside
+    `CROSS_THREAD` reads its thread's CPU clock when it is made and,
+    stopped on that thread, adds the CPU and the wall time between the
+    two reads to its row; stopped elsewhere it adds neither."""
 
-    __slots__ = ("_table", "_block", "name", "t0", "t1", "_ann")
+    __slots__ = ("_table", "_block", "name", "t0", "t1", "_ann", "_ident",
+                 "_w0", "_cpu0")
 
     def __init__(self, table: "StageTable", name: str,
                  t0: Optional[float] = None, block=None):
@@ -525,7 +564,17 @@ class Stage:
         self.name = name
         self.t1 = None
         self._ann = _annotate(name)
-        self.t0 = time.monotonic() if t0 is None else t0
+        if name in CROSS_THREAD:
+            self._ident = None
+            self.t0 = time.monotonic() if t0 is None else t0
+            return
+        self._ident = threading.get_ident()
+        _label(self._ident, name)
+        # the wall clock first, the CPU clock inside it: the CPU read
+        # never spans more than the wall read
+        self._w0 = time.monotonic()
+        self._cpu0 = time.thread_time()
+        self.t0 = self._w0 if t0 is None else t0
 
     def stop(self, ctx: Optional[SpanContext] = None,
              attrs: Optional[dict] = None,
@@ -537,7 +586,13 @@ class Stage:
         if table is None:
             return self.t1
         self._table = None
-        self.t1 = time.monotonic() if t1 is None else t1
+        cpu = wall = None
+        if self._ident == threading.get_ident():
+            cpu = time.thread_time() - self._cpu0
+        now = time.monotonic()
+        if cpu is not None:
+            wall = now - self._w0
+        self.t1 = now if t1 is None else t1
         self._end_annotation()
         blk = self._block
         if blk is not None:
@@ -546,7 +601,7 @@ class Stage:
                      **(attrs or {})}
         elif ctx is None:
             ctx = current()  # the stopping thread's, where it scopes one
-        table._observe(self.name, self.t0, self.t1, ctx, attrs)
+        table._observe(self.name, self.t0, self.t1, ctx, attrs, cpu, wall)
         return self.t1
 
     def cancel(self) -> None:
@@ -556,9 +611,14 @@ class Stage:
         self._end_annotation()
 
     def _end_annotation(self) -> None:
+        """Close what an open stage holds: its TraceMe and its thread's
+        label (taken off from whichever thread ends the stage)."""
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
+        if self._ident is not None:
+            _unlabel(self._ident, self.name)
+            self._ident = None
 
     def __enter__(self):
         return self
@@ -603,17 +663,17 @@ class BlockStages:
 
 
 class StageTable:
-    """A node's always-on stage aggregate, {name: [count, seconds]}, and
-    its per-block holders. Keyed by the node's trace label (`stages`):
-    the tracer is process-wide, and in-process clusters must not add
-    their nodes together."""
+    """A node's always-on stage aggregate, {name: [count, seconds,
+    cpu_seconds, cpu_wall_seconds]}, and its per-block holders. Keyed by
+    the node's trace label (`stages`): the tracer is process-wide, and
+    in-process clusters must not add their nodes together."""
 
     KEEP_BLOCKS = 64
 
     def __init__(self, owner: str = ""):
         self.owner = owner
         self._lock = threading.Lock()
-        self._agg: dict[str, list] = {n: [0, 0.0] for n in STAGES}
+        self._agg: dict[str, list] = {n: [0, 0.0, 0.0, 0.0] for n in STAGES}
         self._counts: dict[str, int] = dict.fromkeys(COUNTERS, 0)
         self._blocks: dict[int, BlockStages] = {}
 
@@ -637,12 +697,17 @@ class StageTable:
             self._blocks.pop(number, None)
 
     def _observe(self, name: str, t0: float, t1: float,
-                 ctx: Optional[SpanContext], attrs: Optional[dict]) -> None:
+                 ctx: Optional[SpanContext], attrs: Optional[dict],
+                 cpu: Optional[float] = None,
+                 wall: Optional[float] = None) -> None:
         dt = max(0.0, t1 - t0)
         with self._lock:
-            row = self._agg.setdefault(name, [0, 0.0])
+            row = self._agg.setdefault(name, [0, 0.0, 0.0, 0.0])
             row[0] += 1
             row[1] += dt
+            if cpu is not None:
+                row[2] += cpu
+                row[3] += wall
         # the unlabeled registry on purpose: every stage lives in ONE
         # series family, or the dashboard's cross-stage shares skew
         REGISTRY.observe(STAGE_HISTOGRAM, dt,
@@ -654,10 +719,14 @@ class StageTable:
             TRACER.observe_slow(f"stage.{name}", dt, attrs=attrs)
 
     def snapshot(self, names: Optional[tuple] = None) -> dict:
-        """{name: {"count", "seconds"}}: every stage of `STAGES` from the
-        start, so a reader's delta never meets a missing key."""
+        """{name: {"count", "seconds", "cpu_seconds", "cpu_wall_seconds"}}:
+        every stage of `STAGES` from the start, so a reader's delta never
+        meets a missing key. The last two are summed over the stops on the
+        stage's own thread alone: their ratio is the share of the stage's
+        time its thread was on a core."""
         with self._lock:
-            return {n: {"count": r[0], "seconds": r[1]}
+            return {n: {"count": r[0], "seconds": r[1],
+                        "cpu_seconds": r[2], "cpu_wall_seconds": r[3]}
                     for n, r in self._agg.items()
                     if names is None or n in names}
 
@@ -672,7 +741,7 @@ class StageTable:
 
     def reset(self) -> None:
         with self._lock:
-            self._agg = {n: [0, 0.0] for n in STAGES}
+            self._agg = {n: [0, 0.0, 0.0, 0.0] for n in STAGES}
             self._counts = dict.fromkeys(COUNTERS, 0)
             self._blocks.clear()
 

@@ -1,7 +1,7 @@
 """Continuous-profiling plane (analysis/profiler.py + tools/perf_gate.py).
 
 Covers the ISSUE-15 test checklist: disarmed-cost structure (no sampler
-thread, plain-branch stage markers), folded-stack correctness against a
+thread, otrace's stage labels), folded-stack correctness against a
 synthetic known-shape workload, per-thread role classification, CPU
 attribution, burst-on-slow-span on a live node, the /profile route on
 both the RPC edge and the [monitor] ops server, ring boundedness, the
@@ -19,6 +19,7 @@ import time
 import pytest
 
 from fisco_bcos_tpu.analysis import hostweather, profiler
+from fisco_bcos_tpu.utils import otrace
 
 
 # -- structure / disarmed contract ----------------------------------------
@@ -37,33 +38,146 @@ def test_disarmed_has_no_sampler_thread():
 
 
 def test_stage_marker_scopes_and_restores():
+    """The sampler's stage label is the otrace stage the thread is inside,
+    under the stage table's own name."""
+    table = otrace.StageTable("labels")
     ident = threading.get_ident()
     assert profiler.current_stage(ident) is None
-    with profiler.stage("execute"):
+    with table.stage("execute"):
         assert profiler.current_stage(ident) == "execute"
-        with profiler.stage("commit"):
-            assert profiler.current_stage(ident) == "commit"
+        with table.stage("roots"):
+            assert profiler.current_stage(ident) == "roots"
         assert profiler.current_stage(ident) == "execute"
     # fully unwound: no residue in the stage map (bounded by live scopes)
     assert profiler.current_stage(ident) is None
-    assert ident not in profiler._THREAD_STAGE
+    assert ident not in otrace.THREAD_STAGES
 
 
-def test_role_classification():
-    assert profiler.classify("tx-ingest") == "ingest"
-    assert profiler.classify("sched-commit") == "commit"
-    assert profiler.classify("sched-notify") == "commit"
-    assert profiler.classify("pbft") == "pbft"
-    assert profiler.classify("pbft-exec_0") == "pbft"
-    assert profiler.classify("sealer") == "seal"
-    assert profiler.classify("crypto-lane") == "lane"
-    assert profiler.classify("crypto-lane-w_1") == "lane"
-    assert profiler.classify("storage-compact") == "compaction"
-    assert profiler.classify("rpc-worker-3") == "edge"
-    assert profiler.classify("ops-http") == "edge"
-    assert profiler.classify("gw-ab12") == "net"
-    assert profiler.classify("MainThread") == "main"
-    assert profiler.classify("never-heard-of-it") == "other"
+def test_stage_marker_never_left_stale():
+    """A stage that crosses threads labels nothing; one stopped out of
+    order, cancelled, or stopped on another thread leaves no label."""
+    table = otrace.StageTable("stale")
+    ident = threading.get_ident()
+    waiting = table.stage("round_wait")
+    assert profiler.current_stage(ident) is None
+    outer, inner = table.stage("execute"), table.stage("roots")
+    outer.stop()  # out of order: the inner one is still open
+    assert profiler.current_stage(ident) == "roots"
+    inner.stop()
+    assert profiler.current_stage(ident) is None
+    table.stage("fill").cancel()
+    assert profiler.current_stage(ident) is None
+    elsewhere = table.stage("commit")
+    for st in (elsewhere, waiting):
+        t = threading.Thread(target=st.stop)
+        t.start()
+        t.join(5)
+        assert not t.is_alive()
+    assert ident not in otrace.THREAD_STAGES
+    snap = table.snapshot()
+    assert snap["commit"]["count"] == snap["round_wait"]["count"] == 1
+
+
+@pytest.mark.parametrize("name,role", [
+    ("tx-ingest", "ingest"), ("sched-commit", "commit"),
+    ("sched-notify", "notify"), ("pbft", "pbft"),
+    ("pbft-exec_0", "execute"), ("dmc-worker_0", "execute"),
+    ("exec-pump-0", "execute"), ("sealer", "seal"),
+    ("crypto-lane", "crypto"), ("crypto-lane-w_1", "crypto"),
+    ("nativeec_2", "crypto"), ("storage-compact", "compaction"),
+    ("block-sync-dl", "sync"), ("snapshot", "sync"),
+    ("rpc-worker-3", "edge"), ("ops-http", "edge"), ("sub-fanout", "edge"),
+    ("tx-sync", "net"), ("gw-ab12", "net"), ("p2p-read-ab12", "net"),
+    ("health-probe", "control"), ("profile-sampler", "profiler"),
+    ("MainThread", "main"), ("never-heard-of-it", "other")])
+def test_role_classification(name, role):
+    assert profiler.classify(name) == role
+    assert role in profiler.ROLES
+
+
+def test_every_thread_a_node_spawns_has_a_role():
+    """No thread of a serving node falls to `other`: the role map is the
+    operator's view of where the process's CPU goes."""
+    from fisco_bcos_tpu.init.node import Node, NodeConfig
+
+    before = {t.name for t in threading.enumerate()}
+    node = Node(NodeConfig(crypto_backend="host", min_seal_time=0.0,
+                           rpc_port=0, ws_port=0, metrics_port=0))
+    node.start()
+    try:
+        _commit_one(node, 7)
+        names = {t.name for t in threading.enumerate()} - before
+    finally:
+        node.stop()
+    assert {"tx-ingest", "sched-notify", "sealer", "jsonrpc-http"} <= names
+    assert not [n for n in names if profiler.classify(n) == "other"], names
+
+
+def _burn(seconds: float) -> None:
+    t_end = time.thread_time() + seconds
+    while time.thread_time() < t_end:
+        pass
+
+
+def test_cpu_by_role_reads_each_threads_own_clock():
+    """A `sched-notify` thread that burns ~50 ms shows under `notify` at
+    once, no sampler armed; the roles sum to the process's CPU."""
+    before = profiler.cpu_by_role()
+    assert tuple(before) == profiler.ROLES
+    done = threading.Event()
+    t = threading.Thread(target=lambda: (_burn(0.05), done.wait(10)),
+                         name="sched-notify", daemon=True)
+    t.start()
+    try:
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            during = profiler.cpu_by_role()
+            if during["notify"] - before["notify"] >= 0.04:
+                break
+            time.sleep(0.01)
+        total = time.process_time()
+        assert during["notify"] - before["notify"] >= 0.04, during
+        assert abs(sum(during.values()) - total) <= 0.02 * total
+        assert min(during.values()) >= 0.0
+    finally:
+        done.set()
+        t.join(5)
+    assert not t.is_alive()
+    # a thread that has exited leaves its CPU in `native`, not lost
+    after = profiler.cpu_by_role()
+    assert after["native"] >= during["native"] + 0.03
+
+
+@pytest.mark.parametrize("thread,stages,head", [
+    ("sched-notify", ("prime",), "notify;stage.prime;"),
+    ("pbft-exec_0", ("roots", "state_root"),
+     "execute;stage.roots;stage.state_root;")])
+def test_sampler_labels_a_thread_with_its_otrace_stage(thread, stages, head):
+    """The flamegraph's stage split is the stage table's names, the open
+    stages outermost first."""
+    table = otrace.StageTable("sampled")
+    p = profiler.SamplingProfiler()
+    stop = threading.Event()
+
+    def staged(names=stages):
+        if not names:
+            return _known_shape_root(stop)
+        with table.stage(names[0]):
+            staged(names[1:])
+
+    t = threading.Thread(target=staged, name=thread, daemon=True)
+    t.start()
+    try:
+        p.configure(hz=150, ring=1024)
+        _wait(lambda: head in p.folded())
+        folded = p.folded()
+        p.configure(hz=0)
+    finally:
+        stop.set()
+        t.join(5)
+    assert not t.is_alive()
+    assert any(ln.startswith(head) and "_known_shape_leaf"
+               in ln for ln in folded.splitlines()), folded[:800]
 
 
 def test_ring_bounded():
@@ -166,8 +280,6 @@ def test_cpu_attribution_names_the_burner():
 
 # -- burst mode ------------------------------------------------------------
 def test_burst_on_slow_span_and_trace_linking():
-    from fisco_bcos_tpu.utils import otrace
-
     p = profiler.PROFILER
     old = (p.hz, p.ring, p.burst_hz, p.burst_s)
     tr_stats = otrace.TRACER.stats()
@@ -259,8 +371,6 @@ def test_profile_route_on_rpc_edge_and_monitor_server(solo_node):
 
 def test_burst_linked_via_get_trace_on_live_node(solo_node):
     node = solo_node
-    from fisco_bcos_tpu.utils import otrace
-
     root = otrace.TRACER.new_root()
     tid = root.trace_id.hex()
     with otrace.ctx_scope(root):

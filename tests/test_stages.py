@@ -6,6 +6,7 @@ crypto seam's host seconds per device call."""
 import glob
 import re
 import sys
+import threading
 import time
 
 import pytest
@@ -25,6 +26,8 @@ BLOCK_STAGES = ("consensus_pre", "fill", "execute", "roots",
 # `seal_wait` lie inside others)
 CHAIN = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "round_wait",
          *BLOCK_STAGES, "rpc_respond")
+# inside `roots`, once a block each
+ROOTS_PARTS = ("txs_root", "receipts_root", "prewrite", "state_root")
 
 
 @pytest.fixture(scope="module")
@@ -78,7 +81,8 @@ def test_every_stage_is_there_from_the_start():
     snap = table.snapshot()
     assert set(snap) == set(otrace.STAGES) >= set(CHAIN)
     assert all(re.fullmatch(r"[a-z_]+", k) for k in snap), sorted(snap)
-    assert all(v == {"count": 0, "seconds": 0.0} for v in snap.values())
+    assert all(v == {"count": 0, "seconds": 0.0, "cpu_seconds": 0.0,
+                     "cpu_wall_seconds": 0.0} for v in snap.values())
 
 
 def test_one_stamp_per_stage_per_block(chain):
@@ -117,6 +121,61 @@ def test_one_stamp_per_stage_per_block(chain):
         assert delta["crypto"][1] <= delta["admit"][1]
     # the client's turn: from the second batch on, the edge saw the gap
     assert seen["rpc_no_request"]["count"] >= 2
+
+
+def test_roots_is_stamped_in_its_parts(chain):
+    """`roots` holds four stages of its own, each once a block: what is
+    left of it is the header hash."""
+    node = chain[0]
+    before = _stages(node)
+    _send_batch(node, "parts", 16)
+    after = _settled(node, before)
+    d = {n: {k: after[n][k] - before[n][k] for k in after[n]}
+         for n in ROOTS_PARTS + ("roots",)}
+    assert d["roots"]["count"] >= 1
+    for n in ROOTS_PARTS:
+        assert d[n]["count"] == d["roots"]["count"], (n, d)
+        assert 0.0 < d[n]["seconds"]
+        # a part runs on the thread that opened it: its CPU is read
+        assert 0.0 < d[n]["cpu_wall_seconds"]
+    assert sum(d[n]["seconds"] for n in ROOTS_PARTS) <= d["roots"]["seconds"]
+    # a wait that crosses threads reads no CPU clock, on any node
+    for n in otrace.CROSS_THREAD:
+        assert after[n]["cpu_seconds"] == after[n]["cpu_wall_seconds"] == 0.0
+
+
+def _burn(seconds: float) -> None:
+    t_end = time.thread_time() + seconds
+    while time.thread_time() < t_end:
+        pass
+
+
+def test_stage_cpu_on_its_own_thread():
+    """Work against wait: a busy stage reads its thread on a core nearly
+    all its time, a sleeping one nearly none; a stop on another thread
+    adds to neither CPU field."""
+    table = otrace.StageTable("cpu")
+    with table.stage("execute"):
+        _burn(0.05)
+    with table.stage("prime"):
+        time.sleep(0.05)
+    moved = table.stage("commit")
+    t = threading.Thread(target=moved.stop)
+    t.start()
+    t.join(5)
+    assert not t.is_alive()
+    snap = table.snapshot()
+    for name in ("execute", "prime"):
+        row = snap[name]
+        assert row["count"] == 1
+        assert 0.0 <= row["cpu_seconds"] <= row["cpu_wall_seconds"]
+        assert row["cpu_wall_seconds"] == pytest.approx(row["seconds"])
+    busy, idle = snap["execute"], snap["prime"]
+    assert busy["cpu_seconds"] >= 0.05
+    assert busy["cpu_seconds"] / busy["cpu_wall_seconds"] > 0.8, busy
+    assert idle["cpu_seconds"] / idle["cpu_wall_seconds"] < 0.2, idle
+    assert snap["commit"] == {"count": 1, "seconds": snap["commit"]["seconds"],
+                              "cpu_seconds": 0.0, "cpu_wall_seconds": 0.0}
 
 
 def test_replicas_stamp_their_own_tables(chain):
