@@ -143,6 +143,11 @@ def _chunks(n: int) -> list[tuple[int, int]]:
     return [(o, min(CHUNK, n - o)) for o in range(0, n, CHUNK)]
 
 
+def _split_rows(buf: bytes, width: int) -> list[bytes]:
+    """Rows of `width` bytes out of one buffer: one slice a row."""
+    return [buf[o:o + width] for o in range(0, len(buf), width)]
+
+
 def _pad_rows(a: np.ndarray, n: int) -> np.ndarray:
     if a.shape[0] == n:
         return a
@@ -259,7 +264,7 @@ class CryptoSuite:
             self.curve = ec.SECP256K1
             self.params = refimpl.SECP256K1
             self.hash_name = "keccak256"
-            self._dev_hash = (keccak.keccak256_varlen, keccak.pad_message_np,
+            self._dev_hash = (keccak.keccak256_varlen, keccak.pad_tail,
                               keccak.nblocks_of, keccak.RATE_BYTES)
             self._host_hash = nativehash.host_hash("keccak256")
             self._host_hash_batch = nativehash.host_hash_batch("keccak256")
@@ -268,7 +273,7 @@ class CryptoSuite:
             self.curve = ec.SM2P256V1
             self.params = refimpl.SM2P256V1
             self.hash_name = "sm3"
-            self._dev_hash = (sm3.sm3_varlen, sm3.pad_message_np,
+            self._dev_hash = (sm3.sm3_varlen, sm3.pad_tail,
                               sm3.nblocks_of, sm3.BLOCK_BYTES)
             self._host_hash = nativehash.host_hash("sm3")
             self._host_hash_batch = nativehash.host_hash_batch("sm3")
@@ -436,29 +441,34 @@ class CryptoSuite:
             self._count_host("hash", n)
             return self._host_hash_batch(msgs)
         t_in = time.monotonic()
-        kernel, pad, nblocks_of, block = self._dev_hash
-        nblk = [nblocks_of(len(m)) for m in msgs]
-        small = [i for i, k in enumerate(nblk) if k <= HASH_MAX_BLOCKS]
-        big = [i for i, k in enumerate(nblk) if k > HASH_MAX_BLOCKS]
-        out: list = [None] * n
+        kernel, tail, nblocks_of, block = self._dev_hash
+        nblk = nblocks_of(np.fromiter(map(len, msgs), np.int64, n))
+        fits = nblk <= HASH_MAX_BLOCKS
+        big = np.flatnonzero(~fits).tolist()
         if big:
+            small = np.flatnonzero(fits).tolist()
             self._count_host("hash", len(big))
-            for i, d in zip(big, self._host_hash_batch(
-                    [msgs[i] for i in big])):
-                out[i] = d
-        for o, ln in _chunks(len(small)):
-            idx = small[o:o + ln]
+            host = self._host_hash_batch([msgs[i] for i in big])
+            msgs = [msgs[i] for i in small]
+            nblk = nblk[fits]
+        digests: list[bytes] = []
+        for o, ln in _chunks(len(msgs)):
             bucket = _bucket(ln)
             blocks, nvalid = keccak.pack_batch_np(
-                [msgs[i] for i in idx], pad, block, bucket,
-                _pow2(max(nblk[i] for i in idx)))
-            digests = self._on_device(
+                msgs[o:o + ln], tail, nblocks_of, block, bucket,
+                _pow2(int(nblk[o:o + ln].max())))
+            digests += self._on_device(
                 "hash", ln, bucket, t_in,
                 lambda: np.asarray(kernel(blocks, nvalid)),
-                lambda rows: [bytes(row) for row in rows[:ln]])
-            for i, d in zip(idx, digests):
-                out[i] = d
+                lambda rows: _split_rows(rows[:ln].tobytes(), DIGEST))
             t_in = time.monotonic()
+        if not big:
+            return digests
+        out: list = [None] * n
+        for i, d in zip(small, digests):
+            out[i] = d
+        for i, d in zip(big, host):
+            out[i] = d
         return out
 
     def poseidon_batch(self, lefts: Sequence[bytes],

@@ -217,18 +217,38 @@ def keccak256_varlen(blocks_u8: jax.Array, nvalid: jax.Array) -> jax.Array:
     return _keccak256_varlen_impl(blocks_u8, nvalid, blocks_u8.shape[-2])
 
 
-def pack_batch_np(msgs, pad_fn, block_bytes: int, batch: int, nblocks: int):
-    """Host-side packing shared by the Keccak and SM3 batch APIs: pad each
-    message (`pad_fn`), lay the batch out as [batch, nblocks, block_bytes]
-    uint8 + per-message block counts. `batch`/`nblocks` are the CALLER's
-    buckets (>= len(msgs) / the longest message): every distinct pair is
-    one compiled program, so the caller keeps the set small."""
-    blocks = np.zeros((batch, nblocks, block_bytes), dtype=np.uint8)
+def pad_tail(n: int, nblocks: int) -> bytes:
+    """What follows a message of n bytes in its row of `nblocks` rate
+    blocks: Keccak's pad (0x01, zeros, 0x80 closing the message's last
+    block; one 0x81 where they meet), then zero blocks. The message and its
+    tail are `pad_message_np(msg)` and `nblocks - nblocks_of(n)` zero
+    blocks, byte for byte."""
+    k = nblocks_of(n) * RATE_BYTES - n
+    pad = b"\x81" if k == 1 else b"\x01" + bytes(k - 2) + b"\x80"
+    return pad + bytes((nblocks - nblocks_of(n)) * RATE_BYTES)
+
+
+def pack_batch_np(msgs, tail_fn, nblocks_fn, block_bytes: int, batch: int,
+                  nblocks: int):
+    """Host-side packing shared by the Keccak and SM3 batch APIs: the batch
+    as [batch, nblocks, block_bytes] uint8 + per-message block counts. One
+    `b"".join` of every message beside its `tail_fn(len, nblocks)` (pad and
+    zero blocks, made once per length) and zero rows up to `batch`, viewed
+    as one array; the counts are `nblocks_fn` of the lengths, in one numpy
+    step. `batch`/`nblocks` are the CALLER's buckets (>= len(msgs) / the
+    longest message): every distinct pair is one compiled program, so the
+    caller keeps the set small."""
+    n = len(msgs)
+    lens = list(map(len, msgs))
+    tails = {k: tail_fn(k, nblocks) for k in set(lens)}
+    parts = [b""] * (2 * n + 1)  # m0, tail0, m1, tail1, ..., zero rows
+    parts[0:2 * n:2] = msgs
+    parts[1:2 * n:2] = map(tails.__getitem__, lens)
+    parts[-1] = bytes((batch - n) * nblocks * block_bytes)
+    blocks = np.frombuffer(b"".join(parts), np.uint8).reshape(
+        batch, nblocks, block_bytes)
     nvalid = np.zeros((batch,), dtype=np.int32)
-    for i, m in enumerate(msgs):
-        p = pad_fn(m)
-        blocks[i, : p.shape[0]] = p
-        nvalid[i] = p.shape[0]
+    nvalid[:n] = nblocks_fn(np.fromiter(lens, np.int64, n))
     return blocks, nvalid
 
 
@@ -244,6 +264,6 @@ def keccak256_batch_np(msgs: list[bytes], batch: int | None = None,
     [len(msgs), 32] uint8."""
     batch = batch or len(msgs)
     nblocks = nblocks or max(nblocks_of(len(m)) for m in msgs)
-    blocks, nvalid = pack_batch_np(msgs, pad_message_np, RATE_BYTES,
+    blocks, nvalid = pack_batch_np(msgs, pad_tail, nblocks_of, RATE_BYTES,
                                    batch, nblocks)
     return np.asarray(keccak256_varlen(blocks, nvalid))[: len(msgs)]

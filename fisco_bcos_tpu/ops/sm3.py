@@ -154,6 +154,15 @@ def nblocks_of(n: int) -> int:
     return (n + 8) // BLOCK_BYTES + 1
 
 
+def pad_tail(n: int, nblocks: int) -> bytes:
+    """What follows a message of n bytes in its row of `nblocks` blocks:
+    SM3's pad (0x80, zeros, the 64-bit big-endian bit length), then zero
+    blocks; with the message, `pad_message_np(msg)` and zero blocks."""
+    k = nblocks_of(n) * BLOCK_BYTES - n
+    return (b"\x80" + bytes(k - 9) + (8 * n).to_bytes(8, "big")
+            + bytes((nblocks - nblocks_of(n)) * BLOCK_BYTES))
+
+
 def sm3_batch_np(msgs: list[bytes], batch: int | None = None,
                  nblocks: int | None = None) -> np.ndarray:
     """Host API, bucketed like keccak.keccak256_batch_np."""
@@ -161,6 +170,6 @@ def sm3_batch_np(msgs: list[bytes], batch: int | None = None,
 
     batch = batch or len(msgs)
     nblocks = nblocks or max(nblocks_of(len(m)) for m in msgs)
-    blocks, nvalid = pack_batch_np(msgs, pad_message_np, BLOCK_BYTES,
+    blocks, nvalid = pack_batch_np(msgs, pad_tail, nblocks_of, BLOCK_BYTES,
                                    batch, nblocks)
     return np.asarray(sm3_varlen(blocks, nvalid))[: len(msgs)]

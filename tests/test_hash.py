@@ -156,3 +156,81 @@ def test_native_host_hash_accepts_buffer_types():
     want = refimpl.keccak256(b"buffer-shapes")
     assert nk(bytearray(b"buffer-shapes")) == want
     assert nk(memoryview(b"buffer-shapes")) == want
+
+
+def _pad_loop(msgs, pad_fn, block_bytes, batch, nblocks):
+    """The reference layout: each message padded alone by `pad_fn` and
+    copied into its row of a zeroed batch."""
+    blocks = np.zeros((batch, nblocks, block_bytes), dtype=np.uint8)
+    nvalid = np.zeros((batch,), dtype=np.int32)
+    for i, m in enumerate(msgs):
+        p = pad_fn(m)
+        blocks[i, : p.shape[0]] = p
+        nvalid[i] = p.shape[0]
+    return blocks, nvalid
+
+
+_ALGS = {"keccak": (keccak, keccak.RATE_BYTES), "sm3": (sm3, sm3.BLOCK_BYTES)}
+
+
+def _pack_lengths(alg):
+    rate = _ALGS[alg][1]
+    lens = [0, 1, rate - 1, rate, rate + 1, 3 * rate + 1]
+    return lens + ([55, 56, 63, 64] if alg == "sm3" else [])
+
+
+_PACK_CASES = [(alg, n) for alg in _ALGS for n in _pack_lengths(alg)] + [
+    (alg, "mixed") for alg in _ALGS]
+
+
+@pytest.mark.parametrize("alg,length", _PACK_CASES,
+                         ids=[f"{a}-{n}" for a, n in _PACK_CASES])
+def test_pack_batch_matches_the_pad_loop(alg, length):
+    """The one-join pack lays a batch out byte for byte as `_pad_loop`:
+    blocks, block counts, zero blocks past each message's pad and
+    zero rows past the batch, under a bucket larger than the batch and a
+    block axis longer than the longest message."""
+    mod, rate = _ALGS[alg]
+    r = random.Random(f"{alg}-{length}")
+    lens = _pack_lengths(alg) if length == "mixed" else [length] * 3
+    msgs = [r.randbytes(n) for n in lens]
+    longest = max(mod.nblocks_of(n) for n in lens)
+    batch, nblocks = len(msgs) + 5, longest + 2
+    blocks, nvalid = keccak.pack_batch_np(msgs, mod.pad_tail, mod.nblocks_of,
+                                          rate, batch, nblocks)
+    want_blocks, want_nvalid = _pad_loop(msgs, mod.pad_message_np, rate,
+                                         batch, nblocks)
+    assert blocks.shape == (batch, nblocks, rate) and blocks.dtype == np.uint8
+    assert nvalid.dtype == np.int32
+    assert np.array_equal(blocks, want_blocks)
+    assert np.array_equal(nvalid, want_nvalid)
+    for i, m in enumerate(msgs):
+        k = mod.nblocks_of(len(m))
+        assert nvalid[i] == k
+        assert np.array_equal(blocks[i, :k], mod.pad_message_np(m))
+        assert not blocks[i, k:].any()
+    assert not blocks[len(msgs):].any() and not nvalid[len(msgs):].any()
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["keccak", "sm3"])
+def test_device_hash_batch_keeps_order_around_host_messages(sm, monkeypatch):
+    """A batch of 512 on the device door (the JAX kernels on the CPU),
+    split into chunks, with messages past HASH_MAX_BLOCKS interleaved:
+    every digest is the host hasher's, in the order asked."""
+    from fisco_bcos_tpu.crypto import suite as suite_mod
+    from fisco_bcos_tpu.crypto.suite import HASH_MAX_BLOCKS, make_suite
+
+    monkeypatch.setattr(suite_mod, "CHUNK", 200)
+    dev = make_suite(sm, backend="device", allow_cpu=True)
+    host = make_suite(sm, backend="host")
+    mod, rate = _ALGS["sm3" if sm else "keccak"]
+    r = random.Random(41 + sm)
+    big = {3, 100, 257, 400, 511}
+    msgs = [r.randbytes(rate * HASH_MAX_BLOCKS + r.randrange(300)) if i in big
+            else r.randbytes((i * 37) % (3 * rate)) for i in range(512)]
+    assert {i for i, m in enumerate(msgs)
+            if mod.nblocks_of(len(m)) > HASH_MAX_BLOCKS} == big
+    assert dev.hash_batch(msgs) == host.hash_batch(msgs)
+    ops = dev.status()["ops"]["hash"]
+    assert (ops["hostCalls"], ops["hostItems"]) == (1, len(big))
+    assert (ops["deviceCalls"], ops["deviceItems"]) == (3, 512 - len(big))

@@ -85,6 +85,41 @@ def test_every_stage_is_there_from_the_start():
                      "cpu_wall_seconds": 0.0} for v in snap.values())
 
 
+def _burn(seconds: float) -> None:
+    t_end = time.thread_time() + seconds
+    while time.thread_time() < t_end:
+        pass
+
+
+def test_stage_cpu_on_its_own_thread():
+    """Work against wait: a busy stage reads its thread on a core nearly
+    all its time, a sleeping one nearly none; a stop on another thread
+    adds to neither CPU field. It runs before the module's `chain` is up:
+    the four nodes' threads take the interpreter lock during the burn."""
+    table = otrace.StageTable("cpu")
+    with table.stage("execute"):
+        _burn(0.05)
+    with table.stage("prime"):
+        time.sleep(0.05)
+    moved = table.stage("commit")
+    t = threading.Thread(target=moved.stop)
+    t.start()
+    t.join(5)
+    assert not t.is_alive()
+    snap = table.snapshot()
+    for name in ("execute", "prime"):
+        row = snap[name]
+        assert row["count"] == 1
+        assert 0.0 <= row["cpu_seconds"] <= row["cpu_wall_seconds"]
+        assert row["cpu_wall_seconds"] == pytest.approx(row["seconds"])
+    busy, idle = snap["execute"], snap["prime"]
+    assert busy["cpu_seconds"] >= 0.05
+    assert busy["cpu_seconds"] / busy["cpu_wall_seconds"] > 0.8, busy
+    assert idle["cpu_seconds"] / idle["cpu_wall_seconds"] < 0.2, idle
+    assert snap["commit"] == {"count": 1, "seconds": snap["commit"]["seconds"],
+                              "cpu_seconds": 0.0, "cpu_wall_seconds": 0.0}
+
+
 def test_one_stamp_per_stage_per_block(chain):
     node = chain[0]
     seen = _stages(node)
@@ -142,40 +177,6 @@ def test_roots_is_stamped_in_its_parts(chain):
     # a wait that crosses threads reads no CPU clock, on any node
     for n in otrace.CROSS_THREAD:
         assert after[n]["cpu_seconds"] == after[n]["cpu_wall_seconds"] == 0.0
-
-
-def _burn(seconds: float) -> None:
-    t_end = time.thread_time() + seconds
-    while time.thread_time() < t_end:
-        pass
-
-
-def test_stage_cpu_on_its_own_thread():
-    """Work against wait: a busy stage reads its thread on a core nearly
-    all its time, a sleeping one nearly none; a stop on another thread
-    adds to neither CPU field."""
-    table = otrace.StageTable("cpu")
-    with table.stage("execute"):
-        _burn(0.05)
-    with table.stage("prime"):
-        time.sleep(0.05)
-    moved = table.stage("commit")
-    t = threading.Thread(target=moved.stop)
-    t.start()
-    t.join(5)
-    assert not t.is_alive()
-    snap = table.snapshot()
-    for name in ("execute", "prime"):
-        row = snap[name]
-        assert row["count"] == 1
-        assert 0.0 <= row["cpu_seconds"] <= row["cpu_wall_seconds"]
-        assert row["cpu_wall_seconds"] == pytest.approx(row["seconds"])
-    busy, idle = snap["execute"], snap["prime"]
-    assert busy["cpu_seconds"] >= 0.05
-    assert busy["cpu_seconds"] / busy["cpu_wall_seconds"] > 0.8, busy
-    assert idle["cpu_seconds"] / idle["cpu_wall_seconds"] < 0.2, idle
-    assert snap["commit"] == {"count": 1, "seconds": snap["commit"]["seconds"],
-                              "cpu_seconds": 0.0, "cpu_wall_seconds": 0.0}
 
 
 def test_replicas_stamp_their_own_tables(chain):
