@@ -30,6 +30,7 @@ from .precompiled import (
     CallContext,
     Precompile,
     PrecompileError,
+    SMALLBANK_ADDRESS,
     account_status,
     ACCOUNT_NORMAL,
     check_deploy_auth,
@@ -551,8 +552,9 @@ class TransactionExecutor:
         waves cost the `air4-parallelok` cell 637-687 ms of `execute` a
         block against 164 ms serial. A precompile holds the GIL for its
         whole body. Counted once a block into the node's stage table:
-        `dag_plan` (the planner, conflict keys included) and dag_blocks /
-        dag_waves / dag_txs."""
+        `dag_plan` (the planner, conflict keys included), dag_blocks /
+        dag_waves / dag_txs, and the block's SmallBank calls and those of
+        them refused (smallbank_calls / smallbank_refused)."""
         t0 = time.monotonic()
         # snapshot the feature-gate version from block-START state, before
         # any tx (possibly a governance raise) dirties the overlay
@@ -566,8 +568,12 @@ class TransactionExecutor:
             for i in wave:
                 receipts[i] = self.execute_transaction(
                     txs[i], state, block_number, timestamp)
+        said = [rc.status for tx, rc in zip(txs, receipts)
+                if tx.to == SMALLBANK_ADDRESS]
         for name, n in (("dag_blocks", 1), ("dag_waves", len(waves)),
-                        ("dag_txs", len(txs))):
+                        ("dag_txs", len(txs)),
+                        ("smallbank_calls", len(said)),
+                        ("smallbank_refused", sum(map(bool, said)))):
             self.stages.count(name, n)
         metric("executor.dag", n=len(txs), waves=len(waves),
                ms=int((time.monotonic() - t0) * 1000))
@@ -597,7 +603,10 @@ class TransactionExecutor:
         (zk/proof.py + Ledger.state_proof): persisting it alongside the
         block lets `getProof` serve changeset-inclusion proofs anchored
         at this root without re-reading (or retaining) the values — the
-        digests here are a free by-product of the root computation."""
+        digests here are a free by-product of the root computation.
+        Counts the leaves into the stage table (`state_leaves`), once a
+        block."""
+        self.stages.count("state_leaves", len(changes))
         if not changes:
             return b"\x00" * 32, []
         items = sorted(changes.items(), key=lambda kv: (kv[0][0], kv[0][1]))

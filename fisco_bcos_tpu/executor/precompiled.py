@@ -182,6 +182,134 @@ class BalancePrecompile(Precompile):
 
 
 # ---------------------------------------------------------------------------
+# SmallBank (H-Store SmallBank, Alomari et al., ICDE 2008; BlockBench's
+# `smallbank` contract; the reference's SmallBankPrecompiled under
+# precompiled/extension/). A customer is two rows, savings and checking,
+# signed 64-bit amounts in cents. Methods carry BlockBench's names:
+#
+#   getBalance(n)        Balance          -> savings + checking, no change
+#   updateBalance(n, v)  DepositChecking  refused if v < 0; checking += v
+#   updateSaving(n, v)   TransactSavings  refused if savings + v < 0
+#   sendPayment(a, b, v) SendPayment      refused if checking(a) < v
+#   writeCheck(n, v)     WriteCheck       checking -= v, and the penalty
+#                                         too where savings + checking < v
+#   amalgamate(a, b)     Amalgamate       a's both rows -> checking(b)
+#
+# plus `getAccount(n)` -> (savings, checking), a read the source lacks. A
+# refusal is REVERT and changes no row; so is a customer not prefunded, or
+# a two-customer call naming one customer twice.
+# ---------------------------------------------------------------------------
+
+SMALLBANK_ADDRESS = addr(0x1013)
+T_SB_SAVINGS = "c_sb_savings"
+T_SB_CHECKING = "c_sb_checking"
+SMALLBANK_PENALTY = 100  # cents WriteCheck takes beside an overdraft
+
+
+class SmallBankPrecompile(Precompile):
+    name = "smallbank"
+
+    _CUSTOMERS = {"getBalance": 1, "getAccount": 1, "updateBalance": 1,
+                  "updateSaving": 1, "writeCheck": 1, "sendPayment": 2,
+                  "amalgamate": 2}
+
+    def methods(self):
+        return {
+            "getBalance": self._get_balance,
+            "getAccount": self._get_account,
+            "updateBalance": self._update_balance,
+            "updateSaving": self._update_saving,
+            "sendPayment": self._send_payment,
+            "writeCheck": self._write_check,
+            "amalgamate": self._amalgamate,
+        }
+
+    @staticmethod
+    def encode_amount(v: int) -> bytes:
+        return v.to_bytes(8, "big", signed=True)
+
+    def conflict_keys(self, input_: bytes) -> Optional[list]:
+        """Both rows of every customer the call names, reads included:
+        a Balance's answer depends on its order against the writes."""
+        try:
+            r = Reader(input_)
+            n = self._CUSTOMERS.get(r.text())
+            if n is not None:
+                return [t.encode() + c for c in (r.blob() for _ in range(n))
+                        for t in (T_SB_SAVINGS, T_SB_CHECKING)]
+        except Exception:
+            pass
+        return None
+
+    def _account(self, ctx: CallContext, customer: bytes) -> list[int]:
+        """[savings, checking]; a customer not prefunded is refused."""
+        s = ctx.state.get(T_SB_SAVINGS, customer)
+        c = ctx.state.get(T_SB_CHECKING, customer)
+        if s is None or c is None:
+            self._refuse("no such customer")
+        return [int.from_bytes(s, "big", signed=True),
+                int.from_bytes(c, "big", signed=True)]
+
+    def _put(self, ctx: CallContext, customer: bytes, savings: int,
+             checking: int) -> None:
+        ctx.state.set(T_SB_SAVINGS, customer, self.encode_amount(savings))
+        ctx.state.set(T_SB_CHECKING, customer, self.encode_amount(checking))
+
+    def _pair(self, ctx: CallContext, r: Reader) -> tuple:
+        a, b = r.blob(), r.blob()
+        x, y = self._account(ctx, a), self._account(ctx, b)
+        if a == b:
+            self._refuse("one customer named twice")
+        return a, x, b, y
+
+    @staticmethod
+    def _refuse(why: str):
+        raise PrecompileError(f"smallbank: {why}", TransactionStatus.REVERT)
+
+    def _get_balance(self, ctx: CallContext, r: Reader, w: Writer) -> None:
+        w.i64(sum(self._account(ctx, r.blob())))
+
+    def _get_account(self, ctx: CallContext, r: Reader, w: Writer) -> None:
+        s, c = self._account(ctx, r.blob())
+        w.i64(s).i64(c)
+
+    def _update_balance(self, ctx: CallContext, r: Reader, w: Writer) -> None:
+        n = r.blob()
+        s, c = self._account(ctx, n)
+        v = r.i64()
+        if v < 0:
+            self._refuse("negative deposit")
+        self._put(ctx, n, s, c + v)
+
+    def _update_saving(self, ctx: CallContext, r: Reader, w: Writer) -> None:
+        n = r.blob()
+        s, c = self._account(ctx, n)
+        v = r.i64()
+        if s + v < 0:
+            self._refuse("savings would go negative")
+        self._put(ctx, n, s + v, c)
+
+    def _send_payment(self, ctx: CallContext, r: Reader, w: Writer) -> None:
+        a, (sa, ca), b, (sb, cb) = self._pair(ctx, r)
+        v = r.i64()
+        if ca < v:
+            self._refuse("checking short of the payment")
+        self._put(ctx, a, sa, ca - v)
+        self._put(ctx, b, sb, cb + v)
+
+    def _write_check(self, ctx: CallContext, r: Reader, w: Writer) -> None:
+        n = r.blob()
+        s, c = self._account(ctx, n)
+        v = r.i64()
+        self._put(ctx, n, s, c - v - (SMALLBANK_PENALTY if s + c < v else 0))
+
+    def _amalgamate(self, ctx: CallContext, r: Reader, w: Writer) -> None:
+        a, (sa, ca), b, (sb, cb) = self._pair(ctx, r)
+        self._put(ctx, a, 0, 0)
+        self._put(ctx, b, sb, cb + sa + ca)
+
+
+# ---------------------------------------------------------------------------
 # Cross-group (cross-shard) atomic transfers — the coordinator precompile.
 #
 # A transfer id (client-chosen, unique) moves `amount` from `src` on THIS
@@ -1360,6 +1488,7 @@ PRECOMPILED_REGISTRY: dict[bytes, Precompile] = {
     BALANCE_ADDRESS: BalancePrecompile(),
     XSHARD_ADDRESS: XShardPrecompile(),
     DAG_TRANSFER_ADDRESS: BalancePrecompile(),  # same semantics, bench alias
+    SMALLBANK_ADDRESS: SmallBankPrecompile(),
     KV_TABLE_ADDRESS: KVTablePrecompile(),
     TABLE_ADDRESS: TablePrecompile(),
     TABLE_MANAGER_ADDRESS: TableManagerPrecompile(),

@@ -489,12 +489,14 @@ CROSS_THREAD = frozenset(("rpc_no_request", "lane_wait", "round_wait",
 # received, and those the lane took as one piece (rpc/server.py). The
 # executor's, once a block: blocks run through the DAG path, their waves and
 # transactions (executor.py), and `dag_pooled_txs`, transactions run in a
-# thread-pooled wave, which reads 0 since every wave runs serially; and once
-# a frame, EVM frames and those of them the native interpreter ran (evm.py
-# `_run`)
+# thread-pooled wave, which reads 0 since every wave runs serially; the
+# block's SmallBank calls and those of them refused; the changeset rows the
+# state root hashed (`state_root_with_leaves`); and once a frame, EVM frames
+# and those of them the native interpreter ran (evm.py `_run`)
 COUNTERS = ("cohort_receipts", "cohort_receipts_shared", "cohorts",
             "cohorts_whole", "dag_blocks", "dag_waves", "dag_txs",
-            "dag_pooled_txs", "evm_frames", "evm_native_frames")
+            "dag_pooled_txs", "smallbank_calls", "smallbank_refused",
+            "state_leaves", "evm_frames", "evm_native_frames")
 STAGE_HISTOGRAM = "bcos_tx_stage_seconds"
 # two series of the histogram are older than the stage names
 _HISTOGRAM_LABEL = {"lane_wait": "ingest", "seal_wait": "queueing"}
