@@ -138,9 +138,28 @@ def _senders_size(senders: list) -> int:
     return sum(len(s) if s else 1 for s in senders) + 48
 
 
+# a receipt fragment's bytes, field by field in `_receipt_json`'s order:
+# every value is an int, a 0x hex or "", nothing needs escaping, and the
+# join is what `encode_compact` gives for the same dict
+_RECEIPT_RAW = ('{"version":%d,"transactionHash":"%s","blockNumber":%d,'
+                '"status":%d,"gasUsed":"%s","contractAddress":"%s",'
+                '"output":"%s","message":"","logEntries":[%s]}')
+_LOG_RAW = '{"address":"%s","topics":[%s],"data":"%s"}'
+
+
+def _receipt_raw(doc: dict) -> bytes:
+    logs = ",".join([_LOG_RAW % (e["address"], ",".join(
+        ['"%s"' % t for t in e["topics"]]), e["data"])
+        for e in doc["logEntries"]])
+    return (_RECEIPT_RAW % (
+        doc["version"], doc["transactionHash"], doc["blockNumber"],
+        doc["status"], doc["gasUsed"], doc["contractAddress"],
+        doc["output"], logs)).encode()
+
+
 def _receipt_fragments(block: Optional[Block]) -> dict:
     """{tx hash: RawResult} for a committed block's receipts; {} for no
-    block."""
+    block. The bytes come from the template, not from an encoder."""
     out = {}
     if block is not None:
         for h, rc in zip(block.tx_hashes, block.receipts):
@@ -148,7 +167,7 @@ def _receipt_fragments(block: Optional[Block]) -> dict:
             # not a stored field: the ledger's copy, which every other
             # reader renders, carries none
             doc["message"] = ""
-            out[h] = RawResult(doc)
+            out[h] = RawResult(doc, _receipt_raw(doc))
     return out
 
 
@@ -251,12 +270,29 @@ class _EdgeStages:
                 self._idle = self._stages.stage("rpc_no_request")
 
 
+_SPLICE_RESULT = b', "result": '
+
+
+class _Answered(dict):
+    """A response the cohort's one pass answered (JsonRpcImpl.
+    _answer_cohort): handle()'s literal response dict around a shared
+    receipt fragment, with its id's JSON, so the envelope writer takes
+    its pieces into the batch's one join."""
+
+    __slots__ = ("id_json",)
+
+    def __init__(self, rid, result: RawResult):
+        super().__init__(jsonrpc="2.0", id=rid, result=result)
+        self.id_json = b"%d" % rid if type(rid) is int \
+            else json.dumps(rid).encode()
+
+
 def _encode_one(resp) -> bytes:
     if isinstance(resp, dict) and len(resp) == 3 and "error" not in resp:
         raw = getattr(resp.get("result"), "raw", None)
         if raw is not None:
             return (_SPLICE_HEAD + json.dumps(resp.get("id")).encode()
-                    + b', "result": ' + raw + b"}")
+                    + _SPLICE_RESULT + raw + b"}")
     return json.dumps(resp).encode()
 
 
@@ -264,12 +300,24 @@ def encode_jsonrpc(resp) -> bytes:
     """JSON-RPC response (dict / batch list / None) -> body bytes, with
     cached RawResult fragments spliced in by buffer join. Both transports
     (HTTP edge handler, WS dispatch) render through here — a cached read
-    hit performs ZERO `json.dumps` of the fragment."""
+    hit performs ZERO `json.dumps` of the fragment. A batch's body is one
+    join, the one-pass answers' pieces in it as they are."""
     if resp is None:
         return b""
-    if isinstance(resp, list):
-        return b"[" + b", ".join(_encode_one(r) for r in resp) + b"]"
-    return _encode_one(resp)
+    if not isinstance(resp, list):
+        return _encode_one(resp)
+    parts = [b"["]
+    for r in resp:
+        if type(r) is _Answered:
+            parts += (_SPLICE_HEAD, r.id_json, _SPLICE_RESULT,
+                      r["result"].raw, b"}", b", ")
+        else:
+            parts += (_encode_one(r), b", ")
+    if resp:
+        parts[-1] = b"]"  # the last separator
+    else:
+        parts.append(b"]")
+    return b"".join(parts)
 
 
 def handle_payload_with(impl, payload, max_batch: int = 256):
@@ -288,15 +336,22 @@ def handle_payload_with(impl, payload, max_batch: int = 256):
                               "message": f"batch too large (> {max_batch} "
                                          "entries)"}}
         cohort = getattr(impl, "cohort", None)
-        with cohort(payload) if cohort else contextlib.nullcontext():
-            return _handle_batch(impl, payload) or None
+        with cohort(payload) if cohort else contextlib.nullcontext() \
+                as plan:
+            return _handle_batch(impl, payload, plan) or None
     return _handle_entry(impl, payload)
 
 
-def _handle_batch(impl, payload: list) -> list:
+def _handle_batch(impl, payload: list, plan: Optional[dict] = None) -> list:
+    """The entries in order. `plan` ({index: (tx hex, timeout)}, from
+    `JsonRpcImpl.cohort`) names the cohort members one pass may answer:
+    where the walk meets the first, the pass answers them together, and
+    each takes its answer in its own place; whatever the pass leaves goes
+    through `_handle_entry` like any other entry."""
     out = []
+    answers = None
     deadline = time.monotonic() + BATCH_BUDGET_SECONDS
-    for entry in payload:
+    for i, entry in enumerate(payload):
         if time.monotonic() > deadline:
             # budget exhausted: answer the remaining entries instead of
             # executing them — this worker must come back to the pool
@@ -310,10 +365,47 @@ def _handle_batch(impl, payload: list) -> list:
                         "error": {"code": -32000,
                                   "message": "batch budget exhausted"}})
             continue
+        if plan and i in plan:
+            if answers is None:
+                answers = impl._answer_cohort(payload, plan)
+            resp = answers.get(i)
+            if resp is not None:
+                out.append(resp)
+                continue
         resp = _handle_entry(impl, entry)
         if resp is not None:
             out.append(resp)
     return out
+
+
+def _one_pass_plan(payload: list, tasks: dict, group: str) -> dict:
+    """{index: (tx hex, timeout)}: the cohort members (`tasks`, by frame)
+    of `group` whose answer is their receipt as the committed block's
+    shared table holds it — a request with an id, wait, no proof and
+    parameters `send_transaction` takes without a complaint — each the
+    first entry of the batch to name its frame, as the one that would
+    take the frame's admission one by one."""
+    plan = {}
+    seen = set()
+    for i, e in enumerate(payload):
+        if not (isinstance(e, dict)
+                and e.get("method") == "sendTransaction"):
+            continue
+        p = e.get("params")
+        tx_hex = p[2] if isinstance(p, list) and len(p) > 2 else \
+            p.get("tx_hex") if isinstance(p, dict) else None
+        if not isinstance(tx_hex, str) or tx_hex in seen:
+            continue
+        seen.add(tx_hex)
+        if (tx_hex in tasks and isinstance(p, list) and p[0] == group
+                and "traceparent" not in e and e.get("jsonrpc") == "2.0"
+                and "id" in e and len(p) <= 6
+                and not (len(p) > 3 and p[3])
+                and (len(p) <= 4 or p[4])
+                and (len(p) <= 5 or isinstance(p[5], (int, float)))):
+            plan[i] = (tx_hex, max(0.0, min(
+                float(p[5]) if len(p) > 5 else 30.0, MAX_WAIT_SECONDS)))
+    return plan
 
 
 def _handle_entry(impl, entry):
@@ -337,7 +429,8 @@ class JsonRpcImpl:
         self.max_batch = getattr(getattr(node, "config", None),
                                  "rpc_max_batch", 256)
         # .cohort: a batch's admitted txs; .frags: the committed block its
-        # worker answers from; .answered / .shared: the batch's counts
+        # worker answers from; .answered / .shared / .onepass: the batch's
+        # counts
         self._tl = threading.local()
         self._stages = otrace.stages(getattr(node, "trace_label", ""))
         self.edge_stages = _EdgeStages(self._stages)
@@ -397,7 +490,8 @@ class JsonRpcImpl:
         ever form a batch the device path takes (device_min_batch = 512).
         Entries carrying their own `traceparent`, malformed ones and other
         groups' stay on the one-by-one path, which also reports whatever
-        is wrong with them."""
+        is wrong with them. Yields the plan of the batch's one pass
+        (`_one_pass_plan`, `_answer_cohort`)."""
         lane = getattr(self.node, "ingest", None)
         frames: dict[str, bytes] = {}
         if lane is not None:
@@ -441,14 +535,77 @@ class JsonRpcImpl:
             self._stages.count("cohorts", 1)
             self._stages.count("cohorts_whole", int(whole))
         tl = self._tl
-        tl.cohort, tl.frags, tl.answered, tl.shared = tasks, None, 0, 0
+        tl.cohort, tl.frags = tasks, None
+        tl.answered = tl.shared = tl.onepass = 0
         try:
-            yield
+            # made while the lane admits the frames
+            yield _one_pass_plan(payload, tasks, self.node.config.group_id) \
+                if tasks else {}
         finally:
             if tl.answered:
                 self._stages.count("cohort_receipts", tl.answered)
                 self._stages.count("cohort_receipts_shared", tl.shared)
+                self._stages.count("cohort_receipts_onepass", tl.onepass)
             tl.cohort = tl.frags = None
+
+    def _answer_cohort(self, payload: list, plan: dict) -> dict:
+        """The batch's one pass: {index: response} for the members of
+        `plan` that are admitted and whose receipts a committed block's
+        shared table holds. It waits on their admissions, which resolve
+        together from one lane dispatch; for the first receipt, where the
+        one-by-one path waits for it too (`first_receipt_in`); and again
+        only for a hash that no table met so far holds, which names the
+        next block. A member that is refused, dropped, timed out or has no
+        shared fragment keeps its admission task, and its own entry
+        answers it through `handle` as ever."""
+        tl = self._tl
+        tasks = tl.cohort
+        health = getattr(self.node, "health", None)
+        if not tasks or (health is not None and health.writes_shed()):
+            return {}
+        from ..protocol import TransactionStatus
+        admitted = (TransactionStatus.OK, TransactionStatus.ALREADY_IN_TXPOOL,
+                    TransactionStatus.ALREADY_KNOWN)
+        with otrace.TRACER.span("rpc.sendTransaction", attrs={
+                "group": self.node.config.group_id, "cohort": len(plan)}):
+            t0 = time.monotonic()
+            members = []
+            for i, (tx_hex, timeout) in plan.items():
+                task = tasks.get(tx_hex)
+                if task is None:
+                    continue
+                try:
+                    res = task.result(max(0.0, t0 + timeout
+                                          - time.monotonic()))
+                except Exception:  # noqa: BLE001 — its entry reports it
+                    continue
+                if res.status in admitted:
+                    members.append((i, tx_hex, res.tx_hash, t0 + timeout))
+            ready = dict(tl.frags.peek("receipts")) \
+                if tl.frags is not None else {}
+            answers = {}
+            for i, tx_hex, h, deadline in members:
+                out = ready.get(h)
+                if out is None:
+                    try:
+                        rc = self.node.txpool.wait_for_receipt(
+                            h, max(0.0, deadline - time.monotonic()))
+                    except Exception:  # noqa: BLE001 — TxDropped, or
+                        continue  # whatever its entry then reports
+                    if rc is None:
+                        continue
+                    self.edge_stages.first_receipt_in()
+                    out = self._shared_receipt(h, rc.block_number,
+                                               build=True)
+                    if out is None:
+                        continue
+                    ready.update(tl.frags.peek("receipts"))
+                del tasks[tx_hex]
+                answers[i] = _Answered(payload[i]["id"], out)
+        tl.answered += len(answers)
+        tl.shared += len(answers)
+        tl.onepass += len(answers)
+        return answers
 
     def handle(self, request: dict) -> dict:
         rid = request.get("id")

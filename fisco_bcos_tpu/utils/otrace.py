@@ -484,19 +484,21 @@ STAGES = ("rpc_no_request", "rpc_decode", "lane_wait", "admit", "gossip",
 CROSS_THREAD = frozenset(("rpc_no_request", "lane_wait", "round_wait",
                           "seal_wait", "consensus_pre", "consensus_wait"))
 # what the edge counts beside its stages, per cohort and never per stamp:
-# receipts a `sendTransaction` batch was answered, and those of them taken
-# from the committed block's shared fragments; batches of `sendTransaction`
-# received, and those the lane took as one piece (rpc/server.py). The
-# executor's, once a block: blocks run through the DAG path, their waves and
-# transactions (executor.py), and `dag_pooled_txs`, transactions run in a
-# thread-pooled wave, which reads 0 since every wave runs serially; the
-# block's SmallBank calls and those of them refused; the changeset rows the
-# state root hashed (`state_root_with_leaves`); and once a frame, EVM frames
-# and those of them the native interpreter ran (evm.py `_run`)
-COUNTERS = ("cohort_receipts", "cohort_receipts_shared", "cohorts",
-            "cohorts_whole", "dag_blocks", "dag_waves", "dag_txs",
-            "dag_pooled_txs", "smallbank_calls", "smallbank_refused",
-            "state_leaves", "evm_frames", "evm_native_frames")
+# receipts a `sendTransaction` batch was answered, those of them taken
+# from the committed block's shared fragments and those the cohort's one
+# pass answered; batches of `sendTransaction` received, and those the lane
+# took as one piece (rpc/server.py). The executor's, once a block: blocks
+# run through the DAG path, their waves and transactions (executor.py), and
+# `dag_pooled_txs`, transactions run in a thread-pooled wave, which reads 0
+# since every wave runs serially; the block's SmallBank calls and those of
+# them refused; the changeset rows the state root hashed
+# (`state_root_with_leaves`); and once a frame, EVM frames and those of them
+# the native interpreter ran (evm.py `_run`)
+COUNTERS = ("cohort_receipts", "cohort_receipts_shared",
+            "cohort_receipts_onepass", "cohorts", "cohorts_whole",
+            "dag_blocks", "dag_waves", "dag_txs", "dag_pooled_txs",
+            "smallbank_calls", "smallbank_refused", "state_leaves",
+            "evm_frames", "evm_native_frames")
 STAGE_HISTOGRAM = "bcos_tx_stage_seconds"
 # two series of the histogram are older than the stage names
 _HISTOGRAM_LABEL = {"lane_wait": "ingest", "seal_wait": "queueing"}

@@ -82,3 +82,48 @@ def test_both_specs_read_a_number_from_one_committed_block(sm, status_ratio):
         assert status_ratio(ev, _spec("respond_shared_share")) is None
     finally:
         node.stop()
+
+
+SIX_CELLS = CELLS + ["air4-transfer-blk10k.batch10k-serial",
+                     "air4-transfer-disk.batch1k-serial",
+                     "air4-parallelok.batch1k-serial",
+                     "air4-smallbank.batch1k-serial"]
+
+
+def test_onepass_share_is_listed_for_the_six_cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    assert [w["name"] for w in doc["workloads"]][:6] == SIX_CELLS
+    entry = next(m for m in doc["per_layer"]
+                 if m["name"] == "respond_onepass_share")
+    assert entry == {
+        "name": "respond_onepass_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "RPC edge",
+        "moves": "receipt_p50_ms", "workloads": SIX_CELLS}
+    spec = _spec("respond_onepass_share")
+    assert spec["reader"] == "status_ratio" and spec["node"] == 0
+    assert spec["scale"] == 100
+    assert spec["numerator"] == ["trace.counters.cohort_receipts_onepass"]
+    assert spec["denominator"] == ["trace.counters.cohort_receipts"]
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["secp", "sm"])
+def test_onepass_share_reads_a_number_from_one_committed_block(
+        sm, status_ratio):
+    node, kp, _impl = cohort_node(sm)
+    try:
+        before = node.rpc.impl.get_system_status("group0")
+        send_cohort(node, cohort_txs(node, kp, "onepass-metric"))
+        after = node.rpc.impl.get_system_status("group0")
+        ev = {"status": {"before": {"0": before}, "after": {"0": after}}}
+        spec = _spec("respond_onepass_share")
+        assert status_ratio(ev, spec) == 100.0
+        assert after["trace"]["counters"]["cohort_receipts_onepass"] \
+            - before["trace"]["counters"]["cohort_receipts_onepass"] \
+            == COHORT
+        # a program without the counter (the parent) reads as nothing
+        for doc in (before, after):
+            del doc["trace"]["counters"]["cohort_receipts_onepass"]
+        assert status_ratio(ev, spec) is None
+    finally:
+        node.stop()

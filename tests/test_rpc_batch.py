@@ -742,3 +742,147 @@ def test_cohort_answers_equal_the_ledgers_render(sm, how, monkeypatch):
             assert in_response[1] <= COHORT, tally.encode
     finally:
         node.stop()
+
+
+# ---------------------------------------------------------------------------
+# a committed cohort is answered in one pass, in the per-entry path's bytes
+# ---------------------------------------------------------------------------
+
+def one_pass_payload(node, kp) -> list:
+    """A batch around a 64-tx cohort: a query first, a notification, a
+    malformed hex, another group's entry, a second copy of a cohort frame,
+    a require_proof entry and a transaction admission refuses."""
+    txs = cohort_txs(node, kp, "onepass")
+
+    def send(i, tx, group="group0", proof=False):
+        wire = "0x" + tx.encode().hex() if isinstance(tx, Transaction) \
+            else tx
+        entry = {"jsonrpc": "2.0", "method": "sendTransaction",
+                 "params": [group, "", wire, proof, True]}
+        if i is not None:
+            entry["id"] = i
+        return entry
+
+    def extra(nonce, **kw):
+        return Transaction(to=pc.BALANCE_ADDRESS, nonce=nonce,
+                           block_limit=400, input=pc.encode_call(
+                               "register", lambda w: w.blob(
+                                   nonce.encode()).u64(1)),
+                           **kw).sign(node.suite, kp)
+
+    payload = [{"jsonrpc": "2.0", "id": "head", "method": "getBlockNumber",
+                "params": ["group0", ""]},
+               send(None, extra("onepass-notify"))]
+    for i, tx in enumerate(txs):
+        payload.append(send(i, tx))
+        if i == 5:
+            payload.append(send(1005, tx))  # the second copy
+        elif i == 10:
+            payload.append(send(1010, "0xzz"))
+        elif i == 20:
+            payload.append(send(1020, extra("onepass-other"), "group9"))
+        elif i == 30:
+            payload.append(send(1030, extra("onepass-proof"), proof=True))
+        elif i == 40:
+            payload.append(send(1040, extra("onepass-refused",
+                                            chain_id="other-chain")))
+    return payload
+
+
+def post_body(node, payload, ws: bool) -> bytes:
+    if not ws:
+        return _post_raw(node, json.dumps(payload).encode())
+    conn = ws_connect(node.config.rpc_host, node.ws.port)
+    try:
+        conn.send_text(json.dumps(payload))
+        return conn.recv()[1]
+    finally:
+        conn.close()
+
+
+def edge_counts(node) -> dict:
+    counters = node.system_status()["trace"]["counters"]
+    return {k: counters[k] for k in ("cohort_receipts",
+                                     "cohort_receipts_shared",
+                                     "cohort_receipts_onepass")}
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["secp", "sm"])
+@pytest.mark.parametrize("ws", [False, True], ids=["http", "ws"])
+def test_cohort_one_pass_answers_the_per_entry_bytes(sm, ws, monkeypatch):
+    """Two chains alike take the same mixed batch, whose cohort seals
+    over two blocks (40 transactions a block): one answers it in one
+    pass, the other with the pass made to answer nothing, so every entry
+    goes through `handle`. The bodies are the same bytes; the pass
+    answered the 64 plain members and left the notification's and the
+    proof's member to their entries, which count as before."""
+    nodes = [cohort_node(sm, tx_count_limit=40) for _ in range(2)]
+    try:
+        payload = one_pass_payload(nodes[0][0], nodes[0][1])
+        bodies, counts = [], []
+        for k, (node, _kp, _impl) in enumerate(nodes):
+            if k == 1:
+                monkeypatch.setattr(rpc_server.JsonRpcImpl,
+                                    "_answer_cohort",
+                                    lambda self, payload, plan: {})
+            before = edge_counts(node)
+            bodies.append(post_body(node, payload, ws))
+            after = edge_counts(node)
+            counts.append({k: after[k] - before[k] for k in after})
+        out = json.loads(bodies[0])
+        assert [r["id"] for r in out] == [e["id"] for e in payload
+                                          if "id" in e]
+        assert out[0]["result"] == 1
+        errors = {r["id"]: r["error"]["code"] for r in out if "error" in r}
+        assert set(errors) == {1010, 1020, 1040}, errors
+        receipts = [r["result"] for r in out
+                    if isinstance(r["id"], int) and r["id"] < COHORT]
+        assert {rc["blockNumber"] for rc in receipts} == {2, 3}
+        assert bodies[0] == bodies[1]
+        assert counts == [
+            {"cohort_receipts": COHORT + 2,
+             "cohort_receipts_shared": COHORT + 2,
+             "cohort_receipts_onepass": COHORT},
+            {"cohort_receipts": COHORT + 2,
+             "cohort_receipts_shared": COHORT + 2,
+             "cohort_receipts_onepass": 0}]
+    finally:
+        for node, _kp, _impl in nodes:
+            node.stop()
+
+
+@pytest.mark.parametrize("sm", [False, True], ids=["secp", "sm"])
+def test_receipt_template_bytes_equal_the_encoders(sm):
+    """The shared table's receipt bytes come from a template: for receipts
+    with and without logs, a non-zero status, a contract address and an
+    empty output they are `encode_compact` of `_receipt_json` with the
+    message the stored receipt does not keep."""
+    from fisco_bcos_tpu.crypto.suite import CryptoSuite
+    from fisco_bcos_tpu.protocol import Block, LogEntry, TransactionStatus
+    from fisco_bcos_tpu.rpc.cache import encode_compact
+
+    suite = CryptoSuite("sm" if sm else "ecdsa", backend="host")
+    digest = lambda tag: suite.hash(tag.encode())  # noqa: E731
+    receipts = [
+        Receipt(block_number=7, gas_used=21000),
+        Receipt(block_number=7, gas_used=123456789, output=b"\x00\x01",
+                logs=[LogEntry(address=digest("a")[:20],
+                               topics=[digest("t1"), digest("t2")],
+                               data=b"payload"),
+                      LogEntry(address=digest("b")[:20])]),
+        Receipt(block_number=7, status=int(TransactionStatus.REVERT),
+                message="insufficient balance", output=b"\x08\xc3\x79"),
+        Receipt(version=1, block_number=7, gas_used=0,
+                contract_address=digest("created")[:20],
+                logs=[LogEntry(address=digest("c")[:20],
+                               topics=[digest("t3")], data=b"")]),
+    ]
+    hashes = [digest(f"tx-{i}") for i in range(len(receipts))]
+    frags = rpc_server._receipt_fragments(
+        Block(receipts=receipts, tx_hashes=hashes))
+    assert list(frags) == hashes
+    for rc, h in zip(receipts, hashes):
+        want = dict(rpc_server._receipt_json(rc, h), message="")
+        assert frags[h] == want
+        assert frags[h].raw == encode_compact(want).encode()
+    assert rpc_server._receipt_fragments(None) == {}
